@@ -92,8 +92,8 @@ def run():
     for (B, H, Dh, psz, max_pages, fill) in [(8, 8, 64, 16, 16, 0.5),
                                              (16, 8, 64, 32, 8, 0.25)]:
         n_pages = B * max_pages + 1
-        kp = jax.random.normal(key, (n_pages, psz, H, Dh))
-        vp = jax.random.normal(key, (n_pages, psz, H, Dh))
+        kp = jax.random.normal(key, (n_pages, H, psz, Dh))
+        vp = jax.random.normal(key, (n_pages, H, psz, Dh))
         q = jax.random.normal(key, (B, H, Dh))
         bt = jnp.asarray(
             1 + np.arange(B * max_pages).reshape(B, max_pages), jnp.int32)
@@ -118,8 +118,8 @@ def run():
                                             (8, 16, 4, 64, 16, 8)]:
         rep = H // Hkv
         n_pages = B * max_pages + 1
-        kp = jax.random.normal(key, (n_pages, psz, Hkv, Dh))
-        vp = jax.random.normal(key, (n_pages, psz, Hkv, Dh))
+        kp = jax.random.normal(key, (n_pages, Hkv, psz, Dh))
+        vp = jax.random.normal(key, (n_pages, Hkv, psz, Dh))
         q = jax.random.normal(key, (B, H, Dh))
         bt = jnp.asarray(
             1 + np.arange(B * max_pages).reshape(B, max_pages), jnp.int32)
@@ -130,9 +130,9 @@ def run():
         def gqa_grouped_ref(q, kp, vp, bt, lens):
             # read each KV head once; queries grouped (B, Hkv, rep, Dh)
             Bq, Hq, D = q.shape
-            P, ps = bt.shape[1], kp.shape[1]
-            kf = kp[bt].reshape(Bq, P * ps, Hkv, D)
-            vf = vp[bt].reshape(Bq, P * ps, Hkv, D)
+            P, ps = bt.shape[1], kp.shape[2]
+            kf = kp[bt].swapaxes(2, 3).reshape(Bq, P * ps, Hkv, D)
+            vf = vp[bt].swapaxes(2, 3).reshape(Bq, P * ps, Hkv, D)
             qg = q.reshape(Bq, Hkv, Hq // Hkv, D)
             s = jnp.einsum("bgrd,bsgd->bgrs", qg, kf) * D ** -0.5
             mask = jnp.arange(P * ps)[None, :] < lens[:, None]
@@ -164,9 +164,9 @@ def run():
 
             def f(q, kp, vp, bt, lens):
                 Bq, Hq, D = q.shape
-                ps = kp.shape[1]
-                kf = kp[bt].reshape(Bq, nblk, mp * ps, Hkv, D)
-                vf = vp[bt].reshape(Bq, nblk, mp * ps, Hkv, D)
+                ps = kp.shape[2]
+                kf = kp[bt].swapaxes(2, 3).reshape(Bq, nblk, mp * ps, Hkv, D)
+                vf = vp[bt].swapaxes(2, 3).reshape(Bq, nblk, mp * ps, Hkv, D)
                 qg = q.reshape(Bq, Hkv, Hq // Hkv, D)
 
                 def body(carry, xs):
@@ -217,8 +217,8 @@ def run():
     for (plen, psz, H, Dh, chunk_pages) in [(256, 16, 8, 64, 4),
                                             (512, 32, 8, 64, 4)]:
         n_pages = plen // psz + 1
-        pools = {"k": jax.random.normal(key, (n_pages, psz, H, Dh)),
-                 "v": jax.random.normal(key, (n_pages, psz, H, Dh))}
+        pools = {"k": jax.random.normal(key, (n_pages, H, psz, Dh)),
+                 "v": jax.random.normal(key, (n_pages, H, psz, Dh))}
         bt = jnp.asarray(1 + np.arange(plen // psz), jnp.int32)[None]
         C = chunk_pages * psz
         f = jax.jit(lambda q, pools, bt, off: paged_prefill_attention(

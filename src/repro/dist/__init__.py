@@ -2,7 +2,6 @@
 collectives, and sequence-sharded decode attention.
 
 Layout:
-    compat.py      — jax version shims (shard_map location / kwarg drift)
     runtime.py     — jax.distributed bring-up (coordinator/process env vars),
                      psum barrier, device introspection, global placement
     sharding.py    — PartitionSpec trees over the ("data", "model") mesh

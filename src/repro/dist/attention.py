@@ -67,7 +67,8 @@ def sharded_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                    v_scale=None, *, use_pallas=True):
     """Decode attention over a sequence-sharded PAGED cache (shard_map body).
 
-    Each device owns a page pool holding its slice of every sequence plus
+    Each device owns a head-major page pool (N, Hkv, psz, Dh) holding its
+    slice of every sequence plus
     the matching per-shard block tables (B, P_local) and LOCAL lengths (B,)
     — the paged analogue of ``sharded_decode_attention``. The Pallas kernel
     (``kernels.paged_decode`` with ``normalize=False``) emits the exact

@@ -39,7 +39,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro.dist.compat import shard_map
+from jax import shard_map
 
 __all__ = ["initialize", "is_distributed", "process_index", "process_count",
            "local_device_count", "global_device_count", "device_summary",

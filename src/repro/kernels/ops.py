@@ -1,13 +1,16 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; elsewhere (this CPU container) they
-execute in ``interpret=True`` mode for correctness, or fall back to the
-pure-jnp reference when a shape violates the tiling constraints.
+The kernels compile for the device on every backend but the CPU, where they
+execute in ``interpret=True`` mode (the test suite's correctness path). A
+shape that violates a kernel's tiling constraints falls back to the pure-jnp
+reference; on a TPU that fallback warns once per kernel and shape, with the
+reason, so it cannot pass for the kernel.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 
 import jax
 
@@ -87,10 +90,23 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _tileable_matmul(M, K, N, bits, group):
-    vpw = 32 // bits
-    return (K % group == 0 and K % vpw == 0 and M % 8 == 0 and N % 128 == 0
-            and group % vpw in (0,) or group >= vpw)
+def _interpret() -> bool:
+    """Pallas interpret mode: only where the backend is the CPU."""
+    return jax.default_backend() == "cpu"
+
+
+_FALLBACKS_WARNED: set = set()
+
+
+def _fallback(kernel: str, shape, reason: str) -> None:
+    """Record that ``kernel`` falls back to its jnp reference at ``shape``;
+    on a TPU, warn once per (kernel, shape) with the reason."""
+    key = (kernel, tuple(shape))
+    if on_tpu() and key not in _FALLBACKS_WARNED:
+        _FALLBACKS_WARNED.add(key)
+        warnings.warn(f"{kernel}: shape {tuple(shape)} runs the jnp "
+                      f"reference, not the Pallas kernel: {reason}",
+                      stacklevel=3)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group", "use_pallas"))
@@ -105,6 +121,10 @@ def quant_matmul(x, packed, scale, zero, *, bits: int, group: int,
     N = packed.shape[1]
     vpw = 32 // bits
     ok = (K % group == 0 and K % vpw == 0 and M % 8 == 0 and N % 128 == 0)
+    if use_pallas and not ok:
+        _fallback("quant_matmul", (M, K, N),
+                  f"needs K % group == 0, K % {vpw} == 0, M % 8 == 0 and "
+                  f"N % 128 == 0 (group={group})")
     if not (use_pallas and ok):
         return ref.quant_matmul_ref(x, packed, scale, zero, bits, group)
     bk = K
@@ -115,7 +135,7 @@ def quant_matmul(x, packed, scale, zero, *, bits: int, group: int,
     bm = 128 if M % 128 == 0 else 8
     bn = 256 if N % 256 == 0 else 128
     return quant_matmul_pallas(x, packed, scale, zero, bits=bits, group=group,
-                               bm=bm, bk=bk, bn=bn, interpret=not on_tpu())
+                               bm=bm, bk=bk, bn=bn, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("kv_len", "chunk", "use_pallas"))
@@ -124,10 +144,13 @@ def flash_decode(q, k, v, k_scale=None, v_scale=None, *, kv_len=None,
     """Fused one-token decode attention over a bf16 or int8 KV cache."""
     S = k.shape[1]
     ok = S % min(chunk, S) == 0
+    if use_pallas and not ok:
+        _fallback("flash_decode", k.shape,
+                  f"cache length {S} is not a multiple of chunk={chunk}")
     if not (use_pallas and ok):
         return ref.flash_decode_ref(q, k, v, k_scale, v_scale, kv_len)
     return flash_decode_pallas(q, k, v, k_scale, v_scale, kv_len=kv_len,
-                               chunk=chunk, interpret=not on_tpu())
+                               chunk=chunk, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("normalize", "use_pallas",
@@ -140,9 +163,10 @@ def paged_decode(q, k_pages, v_pages, block_tables, seq_lens, k_scale=None,
     """Paged one-token decode attention over a block-table page pool.
 
     The continuous-batching hot path: q (B, H, Dh) attends over the pages
-    named by ``block_tables`` (B, P) in the global (N, page_size, Hkv, Dh)
-    pool, masked to per-sequence ``seq_lens``. ``normalize=False`` returns
-    the (acc, m, l) partials for the cross-shard LSE merge.
+    named by ``block_tables`` (B, P) in the global head-major
+    (N, Hkv, page_size, Dh) pool (int8 scales (N, Hkv, page_size)), masked
+    to per-sequence ``seq_lens``. ``normalize=False`` returns the (acc, m, l)
+    partials for the cross-shard LSE merge.
 
     With ``fused_gqa`` (the default) GQA shapes (H > Hkv) route to the
     (B, Hkv, P)-grid kernel that loads each KV head's page once for its
@@ -158,16 +182,16 @@ def paged_decode(q, k_pages, v_pages, block_tables, seq_lens, k_scale=None,
         return ref.paged_decode_ref(q, k_pages, v_pages, block_tables,
                                     seq_lens, k_scale, v_scale,
                                     normalize=normalize)
-    H, Hkv = q.shape[1], k_pages.shape[2]
+    H, Hkv = q.shape[1], k_pages.shape[1]
     if fused_gqa and H > Hkv and H % Hkv == 0:
         return paged_decode_gqa_pallas(q, k_pages, v_pages, block_tables,
                                        seq_lens, k_scale, v_scale,
                                        normalize=normalize,
-                                       interpret=not on_tpu(),
+                                       interpret=_interpret(),
                                        pages_per_block=gqa_pages_per_block)
     return paged_decode_pallas(q, k_pages, v_pages, block_tables, seq_lens,
                                k_scale, v_scale, normalize=normalize,
-                               interpret=not on_tpu())
+                               interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group", "mode", "use_pallas"))
@@ -184,12 +208,14 @@ def transform_quant(w, pi, s, phi, *, bits: int, group: int, mode: str,
     """
     K, N = w.shape
     plan = tq_plan(K, N, group=group, mode=mode)
+    if use_pallas and not plan.ok:
+        _fallback(f"transform_quant[{mode}]", (K, N), plan.reason)
     if not (use_pallas and plan.ok):
         return ref.transform_quant_ref(w, pi, s, phi, bits=bits, group=group,
                                        mode=mode)
     return transform_quant_pallas(w, pi, s, phi, bits=bits, group=group,
                                   mode=mode, bg=plan.bg or 4,
-                                  bn=plan.bn or 128, interpret=not on_tpu())
+                                  bn=plan.bn or 128, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group", "use_pallas"))
@@ -200,10 +226,13 @@ def group_quant(w, *, bits: int, group: int, use_pallas: bool = True):
     """
     K, N = w.shape
     ok = (K % group == 0 and N % 128 == 0)
+    if use_pallas and not ok:
+        _fallback("group_quant", (K, N),
+                  f"needs K % group == 0 and N % 128 == 0 (group={group})")
     if not (use_pallas and ok):
         return ref.group_quant_ref(w, bits, group)
     n_groups = K // group
     bg = 4 if n_groups % 4 == 0 else (2 if n_groups % 2 == 0 else 1)
     bn = 256 if N % 256 == 0 else 128
     return group_quant_pallas(w, bits=bits, group=group, bg=bg, bn=bn,
-                              interpret=not on_tpu())
+                              interpret=_interpret())

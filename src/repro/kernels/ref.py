@@ -32,10 +32,11 @@ def paged_decode_ref(q, k_pages, v_pages, block_tables, seq_lens,
                      k_scale=None, v_scale=None, normalize=True):
     """Dense paged-attention oracle: gather pages, then plain softmax.
 
-    q (B, H, Dh); k/v_pages (N, page_size, Hkv, Dh) [+ scales
-    (N, page_size, Hkv)]; block_tables (B, P) int32; seq_lens (B,) int32.
+    q (B, H, Dh); k/v_pages (N, Hkv, page_size, Dh) [+ scales
+    (N, Hkv, page_size)]; block_tables (B, P) int32; seq_lens (B,) int32.
     Returns (B, H, Dh), or the (acc, m, l) log-sum-exp partials when
-    ``normalize=False`` (the dist merge contract).
+    ``normalize=False`` (the dist merge contract). Contractions run at
+    ``Precision.HIGHEST`` so the oracle is full f32 on every backend.
 
     The ONE oracle for both paged-decode grids: the per-query-head kernel
     and the fused-GQA (B, Hkv, P) variant compute the same math, so
@@ -44,29 +45,31 @@ def paged_decode_ref(q, k_pages, v_pages, block_tables, seq_lens,
     fused grid eliminates).
     """
     B, H, Dh = q.shape
-    _, page_size, Hkv, _ = k_pages.shape
+    _, Hkv, page_size, _ = k_pages.shape
     P = block_tables.shape[1]
-    kf = k_pages[block_tables].astype(jnp.float32)     # (B, P, psz, Hkv, Dh)
+    kf = k_pages[block_tables].astype(jnp.float32)     # (B, P, Hkv, psz, Dh)
     vf = v_pages[block_tables].astype(jnp.float32)
     if k_scale is not None:
         kf = kf * k_scale[block_tables][..., None].astype(jnp.float32)
         vf = vf * v_scale[block_tables][..., None].astype(jnp.float32)
-    kf = kf.reshape(B, P * page_size, Hkv, Dh)
-    vf = vf.reshape(B, P * page_size, Hkv, Dh)
+    kf = kf.transpose(0, 1, 3, 2, 4).reshape(B, P * page_size, Hkv, Dh)
+    vf = vf.transpose(0, 1, 3, 2, 4).reshape(B, P * page_size, Hkv, Dh)
     if Hkv < H:  # GQA: repeat KV heads to the query head count
         kf = jnp.repeat(kf, H // Hkv, axis=2)
         vf = jnp.repeat(vf, H // Hkv, axis=2)
-    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), kf) * Dh ** -0.5
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), kf,
+                   precision=hi) * Dh ** -0.5
     mask = jnp.arange(P * page_size)[None, :] < seq_lens[:, None]
     s = jnp.where(mask[:, None, :], s, -1e30)
     if not normalize:
         m = jnp.max(s, axis=-1)
         p = jnp.exp(s - m[..., None])
         l = jnp.sum(p, axis=-1)
-        acc = jnp.einsum("bhs,bshd->bhd", p, vf)
+        acc = jnp.einsum("bhs,bshd->bhd", p, vf, precision=hi)
         return acc, m, l
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhs,bshd->bhd", p, vf)
+    return jnp.einsum("bhs,bshd->bhd", p, vf, precision=hi)
 
 
 def dequant_ref(packed, scale, zero, bits: int, group_size: int, k: int):

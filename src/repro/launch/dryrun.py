@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import/initialization (device count locks on first init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import/initialization (device count locks on first
+# init). The dry-run compiles on 512 fake CPU devices and never takes an
+# accelerator; its --all children inherit both settings.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -25,7 +28,6 @@ import traceback
 import jax
 
 from repro.configs import get_config, list_archs
-from repro.dist import compat
 from repro.dist.sharding import (ShardingRules, param_specs, opt_state_specs,
                                  cache_specs, data_spec, to_shardings)
 from repro.launch.mesh import make_production_mesh
@@ -116,7 +118,7 @@ def _compile_once(cfg: ModelConfig, shape: str, mesh, rules, *, want_text=False,
         lowered = jax.jit(step, in_shardings=in_sh).lower(*structs)
         compiled = lowered.compile()
     dt = time.monotonic() - t0
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     txt = compiled.as_text() if want_text else None
     coll = collective_bytes(compiled.as_text())

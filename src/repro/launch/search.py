@@ -15,13 +15,16 @@ asserts both families are present. With ``--measure-mem`` the row carries
 under ``search_unit_install/`` or ``search_stack_install/`` per
 ``--install``, so CI can assert the O(unit) memory model at K=8.
 
-Configs are run in their ``.reduced()`` form: this driver is the
-CPU-container benchmark/smoke entry; the full-size configs are exercised
-structurally by ``repro.launch.dryrun``.
+Configs run in their ``.reduced()`` form unless ``--full`` asks for the
+published widths; ``--layers N`` then cuts the depth to N blocks (a printed
+``reduced:`` line records the cut) so the search's resident state — fp and
+base models, FFN base and fake-quant stacks, calibration logits — fits one
+device. Weights are random, made from ``--seed``.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import pathlib
 import time
@@ -36,6 +39,7 @@ from repro.core.quant import QuantConfig
 from repro.core.search import SearchConfig
 from repro.data.calib import calibration_tokens
 from repro.dist.sharding import ShardingRules, data_spec
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import init_params
 
@@ -58,7 +62,19 @@ def _merge_rows(out: pathlib.Path, row: dict):
     out.write_text(json.dumps(rows, indent=1))
 
 
-def run_search_bench(arch: str = "opt-tiny", *, steps: int = 40,
+def search_config(arch: str, *, full: bool = False, n_layers: int = None):
+    """``arch`` at its published widths (``full``) or ``.reduced()``, with
+    the depth cut to ``n_layers`` blocks when given (the cut is printed)."""
+    cfg = get_config(arch) if full else get_config(arch).reduced()
+    if n_layers is not None and n_layers != cfg.n_layers:
+        print(f"reduced: n_layers {cfg.n_layers} -> {n_layers} "
+              f"({arch}, widths unchanged)", flush=True)
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    return cfg
+
+
+def run_search_bench(arch: str = "opt-tiny", *, full: bool = False,
+                     n_layers: int = None, steps: int = 40,
                      population: int = 4, islands: int = 1,
                      temperature: float = 0.0, anneal: str = "geometric",
                      migrate_every: int = 25, fused: bool = False,
@@ -68,8 +84,10 @@ def run_search_bench(arch: str = "opt-tiny", *, steps: int = 40,
                      bits: int = 2, group: int = 32, n_seqs: int = 4,
                      seq_len: int = 128, seed: int = 0,
                      out: pathlib.Path = None,
-                     metrics_out: str = obs.DEFAULT_METRICS_PATH) -> dict:
-    cfg = get_config(arch).reduced()
+                     metrics_out: str = obs.DEFAULT_METRICS_PATH):
+    """Run one search through ``quantize_model``; returns the bench row
+    (also merged into ``out``) and the ``SearchResult``."""
+    cfg = search_config(arch, full=full, n_layers=n_layers)
     params = init_params(jax.random.PRNGKey(seed), cfg)
 
     mesh = make_local_mesh()
@@ -116,6 +134,7 @@ def run_search_bench(arch: str = "opt-tiny", *, steps: int = 40,
     row = {
         "name": (f"{family}/{arch}s{steps}p{population}i{islands}"
                  f"b{bits}g{group}" + ("fused" if fused else "")
+                 + (f"-full{cfg.n_layers}L" if full else "")
                  + (f"-{objective}" if objective != "ce" else "")),
         "us_per_call": round(dt * 1e6 / max(proposals, 1), 1),
         "derived": (f"proposals_per_sec={proposals / max(dt, 1e-9):.2f} "
@@ -136,12 +155,16 @@ def run_search_bench(arch: str = "opt-tiny", *, steps: int = 40,
     _merge_rows(out, row)
     if metrics_out:
         obs.write_snapshot(path=metrics_out)
-    return row
+    return row, sr
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="opt-tiny")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths (default: .reduced())")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N blocks (printed as reduced:)")
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--population", type=int, default=4)
     ap.add_argument("--islands", type=int, default=1)
@@ -179,7 +202,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.trace_out:
         obs.set_trace_sink(args.trace_out)
-    run_search_bench(args.arch, steps=args.steps, population=args.population,
+    setup_compile_cache()
+    run_search_bench(args.arch, full=args.full, n_layers=args.layers,
+                     steps=args.steps, population=args.population,
                      islands=args.islands, temperature=args.temperature,
                      anneal=args.anneal, migrate_every=args.migrate_every,
                      fused=args.fused, mapped=args.mapped,

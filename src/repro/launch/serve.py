@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch opt-tiny --bits 2
 
+The config runs in its ``.reduced()`` form unless ``--full`` asks for the
+published widths and depth (weights are random, made from ``--seed``).
+
 Default flow (``PagedServer``): requests stream through
 ``repro.serving.ContinuousBatcher`` — per-request prefill scatters K/V into a
 fixed-size page pool, one jitted decode step advances every live sequence at
@@ -33,6 +36,7 @@ import numpy as np
 from repro import obs
 from repro.configs import get_config
 from repro.core.quant import QuantConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import init_params, prefill
 from repro.quantized.qmodel import pack_model, packed_bytes, dense_bytes
@@ -166,9 +170,47 @@ class PagedServer:
         }
 
 
+def serving_model(arch: str, *, full: bool = False, bits: int = 2,
+                  group: int = 32, seed: int = 0):
+    """(cfg, params_q): ``arch`` at its published size (``full``) or its
+    ``.reduced()`` form, random weights from ``seed`` packed at ``bits`` with
+    quant groups of ``group``. The fp weights are dropped before returning."""
+    cfg = get_config(arch) if full else get_config(arch).reduced()
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    return cfg, pack_model(params, QuantConfig(bits=bits, group_size=group))
+
+
+def shared_prefix_requests(cfg, n: int, *, page_size: int, tenants: int = 1,
+                           max_new: int = 16, temperature: float = 0.0,
+                           top_k: int = 0, seed: int = 0) -> List[Request]:
+    """``n`` requests behind a shared two-page system prompt (so the prefix
+    cache is visible in every run); ``tenants > 1`` adds a one-page
+    per-tenant template on top (the many-tenant trace shape)."""
+    rng = np.random.default_rng(seed)
+    sys_prompt = rng.integers(0, cfg.vocab_size,
+                              size=2 * page_size).astype(np.int32)
+    tenant_tpl = {t: rng.integers(0, cfg.vocab_size,
+                                  size=page_size).astype(np.int32)
+                  for t in range(tenants)}
+    reqs = []
+    for i in range(n):
+        t = i % tenants
+        tail = rng.integers(0, cfg.vocab_size,
+                            size=rng.integers(4, 12)).astype(np.int32)
+        reqs.append(Request(
+            prompt=np.concatenate([sys_prompt, tenant_tpl[t], tail]),
+            max_new=max_new, temperature=temperature, top_k=top_k, seed=i,
+            tenant=f"tenant{t}", priority=t % 3))
+    return reqs
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="opt-tiny")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths and depth (default: .reduced())")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and the request trace")
     ap.add_argument("--bits", type=int, default=2)
     ap.add_argument("--group", type=int, default=32)
     ap.add_argument("--batch", type=int, default=4)
@@ -204,35 +246,18 @@ def main():
     args = ap.parse_args()
     if args.trace_out:
         obs.set_trace_sink(args.trace_out)
+    setup_compile_cache()
 
-    cfg = get_config(args.arch).reduced()
-    key = jax.random.PRNGKey(0)
-    params = init_params(key, cfg)
-    qcfg = QuantConfig(bits=args.bits, group_size=args.group)
-    params_q = pack_model(params, qcfg)
+    cfg, params_q = serving_model(args.arch, full=args.full, bits=args.bits,
+                                  group=args.group, seed=args.seed)
     pb, db = packed_bytes(params_q), dense_bytes(params_q)
     print(f"[serve] packed={pb/1e6:.2f}MB vs fp16={db/1e6:.2f}MB "
           f"({db/pb:.1f}x smaller)")
 
-    rng = np.random.default_rng(0)
-    # a shared system prompt (two pages) in front of every request makes the
-    # prefix cache visible in the default run; --tenants > 1 adds a shorter
-    # per-tenant template on top (the many-tenant trace shape)
-    sys_prompt = rng.integers(0, cfg.vocab_size,
-                              size=2 * args.page_size).astype(np.int32)
-    tenant_tpl = {t: rng.integers(0, cfg.vocab_size,
-                                  size=args.page_size).astype(np.int32)
-                  for t in range(args.tenants)}
-    reqs = []
-    for i in range(args.requests):
-        t = i % args.tenants
-        tail = rng.integers(0, cfg.vocab_size,
-                            size=rng.integers(4, 12)).astype(np.int32)
-        reqs.append(Request(
-            prompt=np.concatenate([sys_prompt, tenant_tpl[t], tail]),
-            max_new=args.max_new, temperature=args.temperature,
-            top_k=args.top_k, seed=i, tenant=f"tenant{t}",
-            priority=t % 3))
+    reqs = shared_prefix_requests(
+        cfg, args.requests, page_size=args.page_size, tenants=args.tenants,
+        max_new=args.max_new, temperature=args.temperature, top_k=args.top_k,
+        seed=args.seed)
     if args.legacy:
         server = BatchedServer(params_q, cfg, batch_size=args.batch,
                                max_len=args.max_len)

@@ -20,6 +20,7 @@ from repro.configs import get_config
 from repro.data.pipeline import DataConfig, make_pipeline
 from repro.dist.fault import StepWatchdog, run_resilient
 from repro.ckpt.checkpoint import CheckpointManager, latest_step
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import init_params
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -91,6 +92,7 @@ def main():
     ap.add_argument("--save-every", type=int, default=50)
     ap.add_argument("--full", action="store_true", help="full-size config (not reduced)")
     args = ap.parse_args()
+    setup_compile_cache()
     train(args.arch, args.steps, args.batch, args.seq, args.lr, args.ckpt_dir,
           args.save_every, reduced=not args.full)
 

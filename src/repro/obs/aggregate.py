@@ -48,7 +48,7 @@ def _gather_rows(rows):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from repro.dist.compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     key = (tuple(d.id for d in devs), rows.shape[1])

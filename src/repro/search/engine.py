@@ -212,60 +212,67 @@ def _run_engine(
                                 vocab_size=cfg.vocab_size, n_match=n_match,
                                 ce_weight=scfg.ce_weight)
 
+    # Every array a step program reads besides the chain state — the base
+    # model, the calibration slice, its FP reference and the objective's
+    # prepared state — travels as an explicit argument, the island's
+    # "context". Closed over, jit would bake these into the program as
+    # constants (gigabytes at published widths), and a mapped island could
+    # not read its own device's copy.
+    def make_ctx(tokens, lfp, hfp):
+        return {"params": params_base, "calib": tokens, "logits_fp": lfp,
+                "hidden_fp": hfp, "fwd_kw": fwd_kw,
+                "state": objv.prepare(make_env(tokens, lfp, hfp))}
+
+    ctx_global = make_ctx(calib_tokens, logits_fp, hidden_fp)
     env_global = make_env(calib_tokens, logits_fp, hidden_fp)
     if shard_calib:
         slices = shard_calibration(calib_tokens, n_islands)
         bounds = np.cumsum([0] + [int(s.shape[0]) for s in slices])
-        envs = [make_env(slices[i],
+        ctxs = [make_ctx(slices[i],
                          logits_fp[bounds[i]:bounds[i + 1]],
                          (hidden_fp[:, bounds[i]:bounds[i + 1]]
                           if n_match else None))
                 for i in range(n_islands)]
     else:
-        envs = [env_global] * n_islands
+        ctxs = [ctx_global] * n_islands
 
-    def make_eval(env):
-        state = objv.prepare(env)
+    def eval_stack_fn(ctx, fq):
+        env = make_env(ctx["calib"], ctx["logits_fp"], ctx["hidden_fp"])
+        params_q = adapter.install(ctx["params"], fq)
+        logits, hidden = forward(params_q, cfg, env.calib,
+                                 collect_hidden=True, **ctx["fwd_kw"])
+        return objv.evaluate(logits, hidden, ctx["state"], env)
 
-        def eval_stack_fn(fq):
-            params_q = adapter.install(params_base, fq)
-            logits, hidden = forward(params_q, cfg, env.calib,
-                                     collect_hidden=True, **fwd_kw)
-            return objv.evaluate(logits, hidden, state, env)
-
-        return eval_stack_fn
-
-    eval_global = make_eval(env_global)
-    p0, a0 = map(float, jax.jit(eval_global)(fq0))
+    eval_jit = jax.jit(eval_stack_fn)
+    p0, a0 = map(float, eval_jit(ctx_global, fq0))
     alpha = float(objv.resolve_mix(p0, a0, env_global))
     loss0 = p0 + alpha * a0
 
-    def quant_candidate(t_new, u):
+    def quant_candidate(ctx, t_new, u):
+        base = adapter.base_stack(ctx["params"])
         if fused:
             return adapter.transform_quant_unit(base, t_new, u, qcfg)
         unit = adapter.transform_unit(base, t_new, u)
         return adapter.quant_unit(unit, qcfg)
 
-    # ---- per-island step programs ----------------------------------------
+    # ---- step programs (shared by every island; the context is an arg) ---
     # legacy single path: EXACTLY the pre-engine step — one proposal,
     # unbatched evaluation in ONE jitted program. This is the bit-for-bit
     # anchor; any K>1 / fused / tabu request takes the staged v2 pipeline.
     staged = (K > 1) or fused or (tabu_cap > 0)
     peak = {"bytes": 0, "batch_bytes": 0}
 
-    def make_single_step(eval_stack_fn):
-        def step_body_single(key, transforms, fq_stack, u):
-            k_prop, _ = jax.random.split(key)
-            t_u = _tree_slice(transforms, u)
-            t_new = proposer(k_prop, inv.FFNTransform(*t_u), scfg.proposal)
-            unit = adapter.transform_unit(base, t_new, u)
-            unit_fq = adapter.quant_unit(unit, qcfg)
-            fq_new = _tree_update(fq_stack, u, unit_fq)
-            p, a = eval_stack_fn(fq_new)
-            loss = p + alpha * a
-            return loss, p, a, fq_new, t_new
-
-        return jax.jit(step_body_single)
+    def step_body_single(ctx, key, transforms, fq_stack, u):
+        k_prop, _ = jax.random.split(key)
+        t_u = _tree_slice(transforms, u)
+        t_new = proposer(k_prop, inv.FFNTransform(*t_u), scfg.proposal)
+        unit = adapter.transform_unit(adapter.base_stack(ctx["params"]),
+                                      t_new, u)
+        unit_fq = adapter.quant_unit(unit, qcfg)
+        fq_new = _tree_update(fq_stack, u, unit_fq)
+        p, a = eval_stack_fn(ctx, fq_new)
+        loss = p + alpha * a
+        return loss, p, a, fq_new, t_new
 
     # staged v2 pipeline: propose / build / eval / pick are SEPARATE jitted
     # stages so the K-candidate buffer is a real set of device arrays between
@@ -279,93 +286,77 @@ def _run_engine(
 
     propose_fn = jax.jit(propose_body)
 
-    def build_units_body(cands, u):
-        units = [quant_candidate(inv.FFNTransform(*_tree_slice(cands, i)), u)
-                 for i in range(K)]
+    def build_units_body(ctx, cands, u):
+        units = [quant_candidate(
+            ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
+            for i in range(K)]
         return stack_unit_batch(units)
 
-    def build_stacks_body(cands, fq_stack, u):
-        units = [quant_candidate(inv.FFNTransform(*_tree_slice(cands, i)), u)
-                 for i in range(K)]
+    def build_stacks_body(ctx, cands, fq_stack, u):
+        units = [quant_candidate(
+            ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
+            for i in range(K)]
         return stack_trees([tree_install_unit(fq_stack, u, un)
                             for un in units])
 
-    build_units_fn = jax.jit(build_units_body)
-    build_stacks_fn = jax.jit(build_stacks_body)
+    if install_mode == "unit":
+        def eval_body(ctx, batch, fq_stack, u):
+            def body(unit_fq):
+                return eval_stack_fn(
+                    ctx, tree_install_unit(fq_stack, u, unit_fq))
+            return jax.lax.map(body, batch)
 
-    def make_staged_step(eval_stack_fn):
+        def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
+            loss = p_vec + alpha * a_vec
+            i = jnp.argmin(loss)
+            fq_new = tree_install_unit(fq_stack, u, take_tree(batch, i))
+            return (loss[i], p_vec[i], a_vec[i], fq_new,
+                    inv.FFNTransform(*_tree_slice(cands, i)), loss)
+    else:
+        def eval_body(ctx, batch, fq_stack, u):
+            del fq_stack, u
+            return jax.vmap(lambda fq: eval_stack_fn(ctx, fq))(batch)
+
+        def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
+            del fq_stack, u
+            loss = p_vec + alpha * a_vec
+            i = jnp.argmin(loss)
+            return (loss[i], p_vec[i], a_vec[i], take_tree(batch, i),
+                    inv.FFNTransform(*_tree_slice(cands, i)), loss)
+
+    build_fn = jax.jit(build_units_body if install_mode == "unit"
+                       else build_stacks_body)
+    eval_fn = jax.jit(eval_body)
+    pick_fn = jax.jit(pick_body)
+
+    def staged_step(ctx, key, transforms, fq_stack, u):
+        cands = propose_fn(key, transforms, u)
         if install_mode == "unit":
-            def eval_body(batch, fq_stack, u):
-                def body(unit_fq):
-                    return eval_stack_fn(
-                        tree_install_unit(fq_stack, u, unit_fq))
-                return jax.lax.map(body, batch)
-
-            def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
-                loss = p_vec + alpha * a_vec
-                i = jnp.argmin(loss)
-                fq_new = tree_install_unit(fq_stack, u, take_tree(batch, i))
-                return (loss[i], p_vec[i], a_vec[i], fq_new,
-                        inv.FFNTransform(*_tree_slice(cands, i)), loss)
+            batch = build_fn(ctx, cands, u)
         else:
-            def eval_body(batch, fq_stack, u):
-                del fq_stack, u
-                return jax.vmap(eval_stack_fn)(batch)
+            batch = build_fn(ctx, cands, fq_stack, u)
+        if measure:
+            jax.block_until_ready(batch)
+            peak["bytes"] = max(peak["bytes"], _live_bytes())
+            peak["batch_bytes"] = max(peak["batch_bytes"], tree_bytes(batch))
+        p_vec, a_vec = eval_fn(ctx, batch, fq_stack, u)
+        out = pick_fn(cands, batch, fq_stack, u, p_vec, a_vec)
+        return out[:5] + (out[5], p_vec, a_vec)
 
-            def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
-                del fq_stack, u
-                loss = p_vec + alpha * a_vec
-                i = jnp.argmin(loss)
-                return (loss[i], p_vec[i], a_vec[i], take_tree(batch, i),
-                        inv.FFNTransform(*_tree_slice(cands, i)), loss)
+    # host-callable step: (ctx, key, transforms, fq_stack, u) ->
+    # (loss, primary, aux, fq_new, t_new[, loss_vec, p_vec, a_vec])
+    step_fn = staged_step if staged else jax.jit(step_body_single)
 
-        eval_fn = jax.jit(eval_body)
-        pick_fn = jax.jit(pick_body)
-
-        def step(key, transforms, fq_stack, u):
-            cands = propose_fn(key, transforms, u)
-            if install_mode == "unit":
-                batch = build_units_fn(cands, u)
-            else:
-                batch = build_stacks_fn(cands, fq_stack, u)
-            if measure:
-                jax.block_until_ready(batch)
-                peak["bytes"] = max(peak["bytes"], _live_bytes())
-                peak["batch_bytes"] = max(peak["batch_bytes"],
-                                          tree_bytes(batch))
-            p_vec, a_vec = eval_fn(batch, fq_stack, u)
-            out = pick_fn(cands, batch, fq_stack, u, p_vec, a_vec)
-            return out[:5] + (out[5], p_vec, a_vec)
-
-        return step
-
-    def make_step_fn(eval_stack_fn):
-        """Host-callable step: (key, transforms, fq_stack, u) ->
-        (loss, primary, aux, fq_new, t_new[, cands, loss_vec])."""
-        if staged:
-            return make_staged_step(eval_stack_fn)
-        single = make_single_step(eval_stack_fn)
-
-        def step(key, transforms, fq_stack, u):
-            return single(key, transforms, fq_stack, u)
-
-        return step
-
-    eval_fns = ([make_eval(e) for e in envs] if shard_calib
-                else [eval_global] * n_islands)
     if shard_calib:
-        step_fns = [make_step_fn(f) for f in eval_fns]
         # per-island step-0 baselines on each island's OWN slice (1 island
         # == the full batch == bitwise the replicated baseline)
         loss0s, p0s, a0s = [], [], []
-        for f in eval_fns:
-            pi0, ai0 = map(float, jax.jit(f)(fq0))
+        for ctx in ctxs:
+            pi0, ai0 = map(float, eval_jit(ctx, fq0))
             p0s.append(pi0)
             a0s.append(ai0)
             loss0s.append(pi0 + alpha * ai0)
     else:
-        shared = make_step_fn(eval_global)
-        step_fns = [shared] * n_islands
         loss0s = [loss0] * n_islands
         p0s = [p0] * n_islands
         a0s = [a0] * n_islands
@@ -384,7 +375,7 @@ def _run_engine(
 
     if mapped:
         return _run_mapped_islands(
-            SearchResult, adapter, scfg, params_base, step_fns, schedule,
+            SearchResult, adapter, scfg, params_base, step_fn, ctxs, schedule,
             stats, transforms0, fq0, loss0s, p0s, a0s, n_islands,
             migrate_every, metrics, objv.name)
 
@@ -406,10 +397,10 @@ def _run_engine(
     # on a full-K tabu hit the device eval is skipped; if the Metropolis rule
     # then ACCEPTS a cached (previously rejected, T>0) move, only its unit is
     # rebuilt and installed — one quant, no calibration forward
-    def rebuild_body(cands, fq_stack, u, i):
+    def rebuild_body(ctx, cands, fq_stack, u, i):
         t_new = inv.FFNTransform(*_tree_slice(cands, i))
         fq_new = tree_install_unit(fq_stack, u,
-                                   quant_candidate(t_new, u))
+                                   quant_candidate(ctx, t_new, u))
         return fq_new, t_new
 
     rebuild_fn = jax.jit(rebuild_body)
@@ -450,8 +441,8 @@ def _run_engine(
                             loss, p, a = cached[ci]
                             fq_new = t_new = None
                         else:
-                            out = step_fns[isl.index](
-                                sub, isl.transforms, isl.fq_stack, u)
+                            out = step_fn(ctxs[isl.index], sub,
+                                          isl.transforms, isl.fq_stack, u)
                             loss, p, a, fq_new, t_new = out[:5]
                             loss = float(loss)   # the device sync
                             if mem is not None:
@@ -474,7 +465,8 @@ def _run_engine(
                     if accepted:
                         if skipped:
                             fq_new, t_new = rebuild_fn(
-                                cands, isl.fq_stack, u, jnp.int32(ci))
+                                ctxs[isl.index], cands, isl.fq_stack, u,
+                                jnp.int32(ci))
                         # strictly-worse moves only (delta == 0 is lateral,
                         # not uphill), counted as a Python int — not an
                         # accumulated numpy bool
@@ -544,20 +536,21 @@ def _run_engine(
 # mapped mode: one island per shard of the ("data",) mesh
 # ---------------------------------------------------------------------------
 
-def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fns,
-                        schedule, stats, transforms0, fq0, loss0s, p0s, a0s,
-                        n_islands, migrate_every, metrics, obj_name):
+def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fn,
+                        ctxs, schedule, stats, transforms0, fq0, loss0s, p0s,
+                        a0s, n_islands, migrate_every, metrics, obj_name):
     """The mapped island loop: one island per shard of the ("data",) mesh.
 
     Split of responsibilities, chosen so "bit-for-bit equal to sequential"
     is a property of the construction rather than a hope about the compiler:
 
     - the per-island STEP (propose → transform → fake-quant → forward → loss)
-      runs the SAME per-island step program the sequential engine runs (the
-      legacy single-jit body, or the staged v2 propose/build/eval/pick
-      stages), with the island's state committed to its shard's device — XLA
-      generates identical code for identical programs, so the per-step
-      scalars come out bit-identical island by island. (Running the step
+      runs the SAME step program the sequential engine runs (the legacy
+      single-jit body, or the staged v2 propose/build/eval/pick stages),
+      with the island's state AND its context (base model, calibration
+      slice, FP reference) committed to its shard's device — XLA generates
+      identical code for identical programs, so the per-step scalars come
+      out bit-identical island by island. (Running the step
       *inside* shard_map instead was measurably NOT bit-stable: the
       surrounding slice/gather graph perturbs how XLA fuses the loss
       reductions, and ``optimization_barrier`` does not fence it off.)
@@ -572,8 +565,8 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fns,
       logic and histories are computed identically everywhere, and each
       process steps only the islands whose shard devices it owns.
 
-    Under ``shard_calib=True`` each island's step program closes over its
-    own calibration slice (``step_fns[i]``), so the migration race compares
+    Under ``shard_calib=True`` each island's context carries its own
+    calibration slice (``ctxs[i]``), so the migration race compares
     per-slice objective estimates — the only cross-island objective traffic.
 
     Under a multi-process ``jax.distributed`` runtime the same loop runs
@@ -584,7 +577,7 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fns,
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.dist import runtime
-    from repro.dist.compat import shard_map
+    from jax import shard_map
     from repro.dist.collectives import elite_broadcast
     from repro.search.islands import gather_island_states, scatter_island_states
 
@@ -605,6 +598,7 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fns,
     # cross-host stacked layout only materializes for migrations/fetch)
     t_loc = {i: jax.device_put(transforms0, d) for i, d in local.items()}
     fq_loc = {i: jax.device_put(fq0, d) for i, d in local.items()}
+    ctx_loc = {i: jax.device_put(ctxs[i], d) for i, d in local.items()}
     bt_loc = dict(t_loc)
     bfq_loc = dict(fq_loc)
 
@@ -653,8 +647,8 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fns,
             u_dev = {}
             for i, d in local.items():   # dispatch all, then fetch (async)
                 u_dev[i] = jax.device_put(jnp.int32(us[i]), d)
-                outs[i] = step_fns[i](jax.device_put(subs[i], d), t_loc[i],
-                                      fq_loc[i], u_dev[i])
+                outs[i] = step_fn(ctx_loc[i], jax.device_put(subs[i], d),
+                                  t_loc[i], fq_loc[i], u_dev[i])
             scal = np.zeros((n_islands, 3), np.float32)
             for i, out in outs.items():
                 scal[i] = [float(out[0]), float(out[1]), float(out[2])]

@@ -29,7 +29,8 @@ from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.models.model import dequant_tree, embed_tokens, lm_head_logits
 
-__all__ = ["make_paged_decode_step", "paged_attention_block",
+__all__ = ["make_paged_decode_step", "make_paged_logits_step",
+           "paged_attention_block",
            "paged_block_body", "sample_logits", "sample_logits_per_seq",
            "sample_step_keys", "request_key"]
 
@@ -94,8 +95,9 @@ def sample_logits_per_seq(logits, keys, temperature, top_k):
 
 
 def _write_token(pool, phys, slot, val):
-    """pool (N, psz, ...) <- val (B, ...) at (phys[b], slot[b]) per slot b."""
-    return pool.at[phys, slot].set(val.astype(pool.dtype))
+    """pool (N, Hkv, psz, ...) <- val (B, Hkv, ...) at page ``phys[b]``, slot
+    ``slot[b]`` of every head, per batch slot b."""
+    return pool.at[phys, :, slot].set(val.astype(pool.dtype))
 
 
 def paged_block_body(pl, cfg: ModelConfig, carry, pool_slice, attn_sublayer):
@@ -123,14 +125,15 @@ def paged_attention_block(p, cfg: ModelConfig, x, pools, block_tables,
                           gqa_pages_per_block: int = 1):
     """Attention sublayer over the paged cache (one layer's pool slices).
 
-    x: (B, 1, D) normed input; pools: {"k"/"v": (N, psz, Hkv, hd)[, scales]}.
+    x: (B, 1, D) normed input; pools: {"k"/"v": (N, Hkv, psz, hd)[, scales
+    (N, Hkv, psz)]}.
     Returns (attn_out (B, 1, D), updated pools). ``gqa_pages_per_block``
     batches the fused-GQA kernel's inner softmax over page blocks (1 keeps
     the single-page grid bit-for-bit).
     """
     positions = seq_lens[:, None]                       # (B, 1) write position
     q, k, v = L.attn_qkv(p, cfg, x, positions)
-    psz = pools["k"].shape[1]
+    psz = pools["k"].shape[2]
     phys = jnp.take_along_axis(block_tables, (seq_lens // psz)[:, None],
                                axis=1)[:, 0]            # (B,) physical page
     slot = seq_lens % psz
@@ -149,6 +152,38 @@ def paged_attention_block(p, cfg: ModelConfig, x, pools, block_tables,
                        use_pallas=use_pallas,
                        gqa_pages_per_block=gqa_pages_per_block)
     return L.attn_out(p, out[:, None].astype(q.dtype), cfg), new
+
+
+def make_paged_logits_step(cfg: ModelConfig, *, use_pallas: bool = True,
+                           gqa_pages_per_block: int = 1):
+    """(params_q, tokens (B,1), pools, block_tables (B,P), seq_lens (B,))
+    -> (logits (B, 1, V) vocab-masked, updated pools): the layer stack every
+    decode step below runs before it selects a token."""
+    if cfg.block_pattern not in ("dense", "moe"):
+        raise ValueError(f"paged decode requires attention blocks, "
+                         f"got {cfg.block_pattern!r}")
+    if cfg.is_enc_dec:
+        raise ValueError("paged decode does not cover cross-attention caches")
+
+    def logits_step(params_q, tokens, pools, block_tables, seq_lens):
+        positions = seq_lens[:, None]
+        h = embed_tokens(params_q, cfg, tokens, positions)
+
+        def attn(p, x, pool_slice):
+            return paged_attention_block(
+                p, cfg, x, pool_slice, block_tables, seq_lens,
+                use_pallas=use_pallas,
+                gqa_pages_per_block=gqa_pages_per_block)
+
+        def body(carry, xs):
+            pl, pool_slice = xs
+            return paged_block_body(pl, cfg, carry, pool_slice, attn)
+
+        h, new_pools = jax.lax.scan(body, h, (params_q["blocks"], pools),
+                                    unroll=cfg.unroll_layers)
+        return lm_head_logits(params_q, cfg, h, mask_vocab=True), new_pools
+
+    return logits_step
 
 
 def make_paged_decode_step(cfg: ModelConfig, *, use_pallas: bool = True,
@@ -174,29 +209,8 @@ def make_paged_decode_step(cfg: ModelConfig, *, use_pallas: bool = True,
     ``sample_logits_per_seq``, so a single program serves any per-slot mix
     of greedy and sampled requests (the continuous batcher's path).
     """
-    if cfg.block_pattern not in ("dense", "moe"):
-        raise ValueError(f"paged decode requires attention blocks, "
-                         f"got {cfg.block_pattern!r}")
-    if cfg.is_enc_dec:
-        raise ValueError("paged decode does not cover cross-attention caches")
-
-    def logits_step(params_q, tokens, pools, block_tables, seq_lens):
-        positions = seq_lens[:, None]
-        h = embed_tokens(params_q, cfg, tokens, positions)
-
-        def attn(p, x, pool_slice):
-            return paged_attention_block(
-                p, cfg, x, pool_slice, block_tables, seq_lens,
-                use_pallas=use_pallas,
-                gqa_pages_per_block=gqa_pages_per_block)
-
-        def body(carry, xs):
-            pl, pool_slice = xs
-            return paged_block_body(pl, cfg, carry, pool_slice, attn)
-
-        h, new_pools = jax.lax.scan(body, h, (params_q["blocks"], pools),
-                                    unroll=cfg.unroll_layers)
-        return lm_head_logits(params_q, cfg, h, mask_vocab=True), new_pools
+    logits_step = make_paged_logits_step(
+        cfg, use_pallas=use_pallas, gqa_pages_per_block=gqa_pages_per_block)
 
     if per_request:
         def per_request_step(params_q, tokens, pools, block_tables, seq_lens,

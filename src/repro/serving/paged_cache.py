@@ -4,7 +4,11 @@ Instead of one contiguous ``(B, max_len, ...)`` cache buffer per batch slot
 (whose memory is ``max_len``-bound regardless of actual lengths), the cache is
 a pool of fixed-size pages shared by every sequence:
 
-    k_pages / v_pages : (n_layers, n_pages, page_size, n_kv_heads, head_dim)
+    k_pages / v_pages : (n_layers, n_pages, n_kv_heads, page_size, head_dim)
+
+The layout is head-major so that one (KV head, page) tile is a whole pair of
+minor dimensions — the block the paged-decode kernel DMAs, and the only block
+shape the TPU compiler accepts for every page size and head dim.
 
 A sequence of length ``s`` holds exactly ``ceil(s / page_size)`` page ids (the
 same ids index every layer's pool), so pool memory tracks the LIVE token count
@@ -35,7 +39,8 @@ foreign-id guard). Two structures share pages:
     (``PagedKVCache.fork_page``) so owners never mutate shared state.
 
 With ``cfg.kv_cache_dtype == "int8"`` pages store int8 codes plus per-(slot,
-head) absmax scales — the same quantized layout as the contiguous cache in
+head) absmax scales, (n_layers, n_pages, n_kv_heads, page_size) — the same
+quantization as the contiguous cache in
 ``repro.models.layers`` (scales per group of ``head_dim`` values, matching the
 group-quant scales convention of one scale per contiguous value group).
 """
@@ -256,12 +261,12 @@ class PagedKVCache:
         dt = jnp.dtype(cfg.compute_dtype)
         kv_dt = jnp.int8 if self.quantized else dt
         self.pools = {
-            "k": jnp.zeros((L, n_pages, page_size, Hkv, hd), kv_dt),
-            "v": jnp.zeros((L, n_pages, page_size, Hkv, hd), kv_dt),
+            "k": jnp.zeros((L, n_pages, Hkv, page_size, hd), kv_dt),
+            "v": jnp.zeros((L, n_pages, Hkv, page_size, hd), kv_dt),
         }
         if self.quantized:
-            self.pools["k_scale"] = jnp.zeros((L, n_pages, page_size, Hkv), dt)
-            self.pools["v_scale"] = jnp.zeros((L, n_pages, page_size, Hkv), dt)
+            self.pools["k_scale"] = jnp.zeros((L, n_pages, Hkv, page_size), dt)
+            self.pools["v_scale"] = jnp.zeros((L, n_pages, Hkv, page_size), dt)
 
     # -- geometry ----------------------------------------------------------
 
@@ -273,9 +278,9 @@ class PagedKVCache:
 
     def dense_equiv_bytes(self, batch: int, max_len: int) -> int:
         """What a contiguous (B, max_len) cache would cost at the same dtype."""
-        per_tok = sum(
-            int(np.prod(a.shape[3:]) * a.dtype.itemsize) * a.shape[0]
-            for a in self.pools.values())
+        slots = self.n_pages * self.page_size
+        per_tok = sum(a.size // slots * a.dtype.itemsize
+                      for a in self.pools.values())
         return batch * max_len * per_tok
 
     # -- block tables ------------------------------------------------------
@@ -297,7 +302,7 @@ class PagedKVCache:
         ids = jnp.asarray(page_ids, jnp.int32)
         out = {}
         for key, pool in self.pools.items():
-            rows = pool[:, ids]                          # (L, n, psz, ...)
+            rows = jnp.swapaxes(pool[:, ids], 2, 3)      # (L, n, psz, Hkv, ...)
             rows = rows.reshape((rows.shape[0], -1) + rows.shape[3:])
             out[key] = rows[:, :length]
         return out
@@ -339,5 +344,6 @@ class PagedKVCache:
                     f"prefill cache depth {src.shape[1]} < {n} pages")
             src = src[:, : n * self.page_size]
             src = src.reshape((src.shape[0], n, self.page_size) + src.shape[2:])
+            src = jnp.swapaxes(src, 2, 3)                # head-major pages
             self.pools[key] = self.pools[key].at[:, ids].set(
                 src.astype(self.pools[key].dtype))

@@ -47,16 +47,16 @@ def paged_prefill_attention(q, pools, block_tables, offset):
     """Causal attention of a prefill chunk over the page pool.
 
     q: (B, C, H, Dh) chunk queries at absolute positions ``offset + i``;
-    pools: one layer's slices {"k"/"v": (N, psz, Hkv, Dh)[, "k_scale"/...]};
+    pools: one layer's slices {"k"/"v": (N, Hkv, psz, Dh)[, "k_scale"/...]};
     block_tables: (B, P) physical page ids; offset: scalar int32 (page
     aligned). Keys live in the pool ONLY — each scan step gathers a single
-    (B, psz, Hkv, Dh) page, keeps the online-softmax (m, l, acc) carry, and
+    (B, Hkv, psz, Dh) page, keeps the online-softmax (m, l, acc) carry, and
     masks by ``key_pos <= query_pos`` so dead/null/garbage page slots never
     contribute. Returns (B, C, H, Dh) f32.
     """
     B, C, H, Dh = q.shape
     kp, vp = pools["k"], pools["v"]
-    psz, Hkv = kp.shape[1], kp.shape[2]
+    Hkv, psz = kp.shape[1], kp.shape[2]
     rep = H // Hkv
     P = block_tables.shape[1]
     ks, vs = pools.get("k_scale"), pools.get("v_scale")
@@ -67,22 +67,22 @@ def paged_prefill_attention(q, pools, block_tables, offset):
     def body(p, carry):
         m, l, acc = carry
         pg = block_tables[:, p]                          # (B,) physical page
-        kb = kp[pg].astype(jnp.float32)                  # (B, psz, Hkv, Dh)
+        kb = kp[pg].astype(jnp.float32)                  # (B, Hkv, psz, Dh)
         vb = vp[pg].astype(jnp.float32)
         if ks is not None:
             kb = kb * ks[pg][..., None].astype(jnp.float32)
             vb = vb * vs[pg][..., None].astype(jnp.float32)
         if rep > 1:
-            kb = jnp.repeat(kb, rep, axis=2)
-            vb = jnp.repeat(vb, rep, axis=2)
-        s = jnp.einsum("bchd,bkhd->bhck", qf, kb)        # (B, H, C, psz)
+            kb = jnp.repeat(kb, rep, axis=1)
+            vb = jnp.repeat(vb, rep, axis=1)
+        s = jnp.einsum("bchd,bhkd->bhck", qf, kb)        # (B, H, C, psz)
         k_pos = p * psz + jnp.arange(psz)
         mask = k_pos[None, :] <= q_pos[:, None]          # (C, psz) causal
         s = jnp.where(mask[None, None], s, NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         prob = jnp.where(mask[None, None], jnp.exp(s - m_new[..., None]), 0.0)
         corr = jnp.exp(m - m_new)
-        acc = acc * corr[..., None] + jnp.einsum("bhck,bkhd->bhcd", prob, vb)
+        acc = acc * corr[..., None] + jnp.einsum("bhck,bhkd->bhcd", prob, vb)
         l = l * corr + jnp.sum(prob, axis=-1)
         return (m_new, l, acc)
 
@@ -101,10 +101,11 @@ def paged_prefill_attention(q, pools, block_tables, offset):
 
 
 def _write_chunk(pool, page_ids, val):
-    """pool (N, psz, ...) <- val (B=1, C, ...) across the chunk's pages."""
+    """pool (N, Hkv, psz, ...) <- val (B=1, C, Hkv, ...) across the chunk's
+    pages."""
     n = page_ids.shape[0]
-    psz = pool.shape[1]
-    src = val[0].reshape((n, psz) + val.shape[2:])
+    psz = pool.shape[2]
+    src = jnp.swapaxes(val[0].reshape((n, psz) + val.shape[2:]), 1, 2)
     return pool.at[page_ids].set(src.astype(pool.dtype))
 
 
@@ -116,7 +117,7 @@ def paged_prefill_block(p, cfg: ModelConfig, x, pools, block_tables, offset):
     C//psz]`` then attends over the pool. Returns (attn_out, new pools).
     """
     B, C, _ = x.shape
-    psz = pools["k"].shape[1]
+    psz = pools["k"].shape[2]
     positions = offset + jnp.arange(C)[None]             # (1, C)
     q, k, v = L.attn_qkv(p, cfg, x, positions)
     ids = jax.lax.dynamic_slice(block_tables[0], (offset // psz,),

@@ -69,7 +69,7 @@ def test_elite_broadcast_selects_owner_tree():
     import functools
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.dist.collectives import elite_broadcast
-    from repro.dist.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("data",))
 

@@ -258,3 +258,27 @@ def test_transform_quant_ref_fallback_on_untileable_shapes():
     for o, wt in zip(out, want):
         np.testing.assert_allclose(np.asarray(o), np.asarray(wt),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_reference_fallback_warns_once_per_shape_on_tpu(monkeypatch):
+    """On a TPU a shape guard that routes to the jnp reference must say so —
+    once per kernel and shape, with the reason — so a fallback cannot pass
+    for the kernel; on the CPU it stays silent."""
+    import warnings
+
+    from repro.kernels import ops
+
+    w = jax.random.normal(jax.random.PRNGKey(3), (96, 40))   # N % 128 != 0
+    monkeypatch.setattr(ops, "_FALLBACKS_WARNED", set())
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        ops._fallback("group_quant", w.shape, "cpu: silent")
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.warns(UserWarning, match=r"group_quant.*N % 128 == 0"):
+        fq, _, _ = ops.group_quant(w, bits=2, group=32)
+    np.testing.assert_allclose(np.asarray(fq),
+                               np.asarray(group_quant_ref(w, 2, 32)[0]),
+                               rtol=1e-6, atol=1e-6)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        ops._fallback("group_quant", w.shape, "already reported")

@@ -28,8 +28,8 @@ from repro.serving import (ContinuousBatcher, NULL_PAGE, PageAllocator,
 def _random_paged(key, B, H, Hkv, Dh, page_size, n_pages, max_pages, int8=False):
     ks = jax.random.split(jax.random.PRNGKey(key), 5)
     q = jax.random.normal(ks[0], (B, H, Dh))
-    kp = jax.random.normal(ks[1], (n_pages, page_size, Hkv, Dh))
-    vp = jax.random.normal(ks[2], (n_pages, page_size, Hkv, Dh))
+    kp = jax.random.normal(ks[1], (n_pages, Hkv, page_size, Dh))
+    vp = jax.random.normal(ks[2], (n_pages, Hkv, page_size, Dh))
     # distinct physical pages per sequence (disjoint live tables), padded
     # with the null page like the batcher does
     perm = jax.random.permutation(ks[3], n_pages - 1) + 1
@@ -50,13 +50,18 @@ def _random_paged(key, B, H, Hkv, Dh, page_size, n_pages, max_pages, int8=False)
 def _dense_oracle(q, kp, vp, bt, lens, ks, vs):
     """Gather pages into a contiguous cache, then the flash_decode oracle."""
     B, H, Dh = q.shape
-    psz, Hkv = kp.shape[1], kp.shape[2]
+    Hkv, psz = kp.shape[1], kp.shape[2]
     P = bt.shape[1]
-    k = kp[bt].reshape(B, P * psz, Hkv, Dh).astype(jnp.float32)
-    v = vp[bt].reshape(B, P * psz, Hkv, Dh).astype(jnp.float32)
+
+    def tokens(pages):     # (B, P, Hkv, psz, ...) -> (B, P * psz, Hkv, ...)
+        t = jnp.swapaxes(pages, 2, 3)
+        return t.reshape((B, P * psz, Hkv) + t.shape[4:])
+
+    k = tokens(kp[bt]).astype(jnp.float32)
+    v = tokens(vp[bt]).astype(jnp.float32)
     if ks is not None:
-        k = k * ks[bt].reshape(B, P * psz, Hkv)[..., None]
-        v = v * vs[bt].reshape(B, P * psz, Hkv)[..., None]
+        k = k * tokens(ks[bt])[..., None]
+        v = v * tokens(vs[bt])[..., None]
     if Hkv < H:
         k = jnp.repeat(k, H // Hkv, axis=2)
         v = jnp.repeat(v, H // Hkv, axis=2)
@@ -129,7 +134,7 @@ def test_paged_decode_poisoned_dead_pages():
     want = paged_decode(q, kp, vp, bt, lens)
     # poison the null page and every slot past each sequence's length
     kp2, vp2 = kp.at[NULL_PAGE].set(500.0), vp.at[NULL_PAGE].set(500.0)
-    psz = kp.shape[1]
+    psz = kp.shape[2]
     P = bt.shape[1]
     for b in range(q.shape[0]):
         used = int(lens[b])
@@ -137,8 +142,8 @@ def test_paged_decode_poisoned_dead_pages():
             for s in range(psz):
                 if p * psz + s >= used:
                     pg = int(bt[b, p])
-                    kp2 = kp2.at[pg, s].set(500.0)
-                    vp2 = vp2.at[pg, s].set(500.0)
+                    kp2 = kp2.at[pg, :, s].set(500.0)
+                    vp2 = vp2.at[pg, :, s].set(500.0)
     out = paged_decode(q, kp2, vp2, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

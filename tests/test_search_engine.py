@@ -8,7 +8,6 @@ a verbatim transcription of the pre-engine loop and the histories are
 compared exactly, not approximately.
 """
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +41,13 @@ QCFG = QuantConfig(bits=2, group_size=32)
 
 
 def _legacy_run_search(params_fp, params_base, cfg, qcfg, calib_tokens, scfg):
-    """Verbatim transcription of the pre-engine core/search.py hill climb."""
+    """Verbatim transcription of the pre-engine core/search.py hill climb.
+
+    The one change from the original: the base model, calibration batch and
+    FP reference reach the jitted programs as arguments (``ctx``), as they do
+    in the engine — closed over, jit bakes them into the program as
+    constants, which no published-width model survives.
+    """
     adapter = make_adapter(cfg)
     n_match = min(scfg.n_match_layers, cfg.n_layers)
     base = adapter.base_stack(params_base)
@@ -56,9 +61,10 @@ def _legacy_run_search(params_fp, params_base, cfg, qcfg, calib_tokens, scfg):
                                    collect_hidden=True)
     hidden_fp = jax.lax.stop_gradient(hidden_fp[:n_match]) if n_match else None
     logits_fp = jax.lax.stop_gradient(logits_fp)
+    ctx = (params_base, calib_tokens, logits_fp, hidden_fp)
 
-    @functools.partial(jax.jit, static_argnames=())
-    def eval_stack(fq):
+    def eval_stack(ctx, fq):
+        params_base, calib_tokens, logits_fp, hidden_fp = ctx
         params_q = adapter.install(params_base, fq)
         logits, hidden = forward(params_q, cfg, calib_tokens,
                                  collect_hidden=True)
@@ -70,19 +76,19 @@ def _legacy_run_search(params_fp, params_base, cfg, qcfg, calib_tokens, scfg):
                if n_match else jnp.float32(0.0))
         return ce, mse
 
-    ce0, mse0 = map(float, eval_stack(fq_stack))
+    ce0, mse0 = map(float, jax.jit(eval_stack)(ctx, fq_stack))
     alpha = obj.resolve_alpha(ce0, mse0, scfg.ce_weight) if n_match else 0.0
     best = ce0 + alpha * float(mse0)
 
     @jax.jit
-    def step_fn(key, transforms, fq_stack, u):
+    def step_fn(ctx, key, transforms, fq_stack, u):
         k_prop, _ = jax.random.split(key)
         t_u = _tree_slice(transforms, u)
         t_new = proposer(k_prop, inv.FFNTransform(*t_u), scfg.proposal)
-        unit = adapter.transform_unit(base, t_new, u)
+        unit = adapter.transform_unit(adapter.base_stack(ctx[0]), t_new, u)
         unit_fq = adapter.quant_unit(unit, qcfg)
         fq_new = _tree_update(fq_stack, u, unit_fq)
-        ce, mse = eval_stack(fq_new)
+        ce, mse = eval_stack(ctx, fq_new)
         loss = ce + alpha * mse
         return loss, ce, mse, fq_new, t_new
 
@@ -93,7 +99,8 @@ def _legacy_run_search(params_fp, params_base, cfg, qcfg, calib_tokens, scfg):
     for step in range(1, scfg.steps + 1):
         key, sub = jax.random.split(key)
         u = jnp.int32(rng.integers(adapter.n_units))
-        loss, ce, mse, fq_new, t_new = step_fn(sub, transforms, fq_stack, u)
+        loss, ce, mse, fq_new, t_new = step_fn(ctx, sub, transforms,
+                                               fq_stack, u)
         loss = float(loss)
         accepted = loss < best
         if accepted:
@@ -257,7 +264,7 @@ def test_candidate_keys_k1_matches_legacy_split():
 
 def test_elite_over_mesh_local():
     """Elite selection through the dist collective on the local mesh."""
-    from repro.dist.compat import shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_local_mesh
     from repro.search.islands import elite_over_mesh
     import jax.sharding as shd

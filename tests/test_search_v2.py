@@ -140,6 +140,47 @@ def test_engine_k3_install_modes_close(tiny_opt):
         np.testing.assert_allclose(hu[1:4], hs[1:4], rtol=0, atol=1e-5)
 
 
+@pytest.mark.parametrize("population,tabu", [(1, 0), (3, 0), (2, 8)])
+def test_engine_programs_take_weights_as_arguments(tiny_opt, monkeypatch,
+                                                   population, tabu):
+    """Every jitted engine program receives the model, calibration batch and
+    FP reference as arguments. Closed over, jit bakes them into the program
+    as constants: gigabytes at published widths, and a mapped island could
+    not read its own device's copy. No constant may be as large as the
+    smallest weight matrix (64 x 64 f32 = 16 KiB)."""
+    import re
+
+    import repro.search.engine as engine
+
+    texts = []
+
+    class JitSpy:
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def jit(fn, **kw):
+            jitted = jax.jit(fn, **kw)
+            seen = []
+
+            def call(*args):
+                if not seen:
+                    seen.append(True)
+                    texts.append(jitted.lower(*args).as_text())
+                return jitted(*args)
+            return call
+
+    monkeypatch.setattr(engine, "jax", JitSpy())
+    params, cfg, calib = tiny_opt
+    search_run(params, params, cfg, QCFG, calib,
+               SearchConfig(steps=3, n_match_layers=2, log_every=0,
+                            population=population, tabu=tabu))
+    assert len(texts) >= (2 if population == 1 else 4)
+    blobs = [m for t in texts for m in re.findall(r'dense<"0x([0-9A-F]*)"', t)]
+    largest = max((len(b) // 2 for b in blobs), default=0)
+    assert largest < 64 * 64 * 4, f"{largest}-byte constant in a program"
+
+
 def test_engine_rejects_unknown_install(tiny_opt):
     params, cfg, calib = tiny_opt
     with pytest.raises(ValueError, match="install"):

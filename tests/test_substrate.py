@@ -167,7 +167,7 @@ def test_remesh_restore(tmp_path):
 def test_compressed_psum_single_axis():
     from jax.sharding import PartitionSpec as P
     from repro.dist.collectives import compressed_psum
-    from repro.dist.compat import shard_map  # jax moved/renamed shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_local_mesh
     import functools
 
@@ -249,7 +249,7 @@ def test_sharded_decode_attention_shard_map():
     """End-to-end through shard_map on the local mesh."""
     from jax.sharding import PartitionSpec as P
     from repro.dist.attention import sharded_decode_attention
-    from repro.dist.compat import shard_map  # jax moved/renamed shard_map
+    from jax import shard_map
     from repro.kernels.ref import flash_decode_ref
     from repro.launch.mesh import make_local_mesh
     import functools
