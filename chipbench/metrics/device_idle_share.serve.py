@@ -1,0 +1,7 @@
+"""Share of the serving's traced sub-window in which no operation ran on the
+chip (1 - union of device operation intervals / window)."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    return None if not tr else 100.0 * tr["idle_share"]
