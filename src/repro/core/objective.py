@@ -74,6 +74,15 @@ def activation_mse(hidden_q, hidden_fp, n_match: int):
     return jnp.mean(jnp.square(hq - hf))
 
 
+def _act_mse(hidden, env) -> jnp.ndarray:
+    """The objectives' activation-MSE term (0 with matching off), under the
+    ``objective.act_mse`` scope that names its device operations."""
+    if not env.n_match:
+        return jnp.float32(0.0)
+    with jax.named_scope("objective.act_mse"):
+        return activation_mse(hidden, env.hidden_fp, env.n_match)
+
+
 def resolve_alpha(ce0: float, mse0: float, ce_weight: float = 10.0) -> float:
     """Paper §4.1: α chosen so CE is ``ce_weight``× more important than the
     activation MSE at the start of the search."""
@@ -134,10 +143,9 @@ class CEObjective(Objective):
     name = "ce"
 
     def evaluate(self, logits, hidden, state, env: ObjectiveEnv):
-        primary = calib_ce(logits, env.calib, env.vocab_size)
-        aux = (activation_mse(hidden, env.hidden_fp, env.n_match)
-               if env.n_match else jnp.float32(0.0))
-        return primary, aux
+        with jax.named_scope("objective.ce"):
+            primary = calib_ce(logits, env.calib, env.vocab_size)
+        return primary, _act_mse(hidden, env)
 
     def resolve_mix(self, primary0, aux0, env):
         return resolve_alpha(primary0, aux0, env.ce_weight) \
@@ -150,10 +158,9 @@ class KLObjective(CEObjective):
     name = "kl"
 
     def evaluate(self, logits, hidden, state, env: ObjectiveEnv):
-        primary = calib_kl(logits, env.logits_fp, env.vocab_size)
-        aux = (activation_mse(hidden, env.hidden_fp, env.n_match)
-               if env.n_match else jnp.float32(0.0))
-        return primary, aux
+        with jax.named_scope("objective.ce"):
+            primary = calib_kl(logits, env.logits_fp, env.vocab_size)
+        return primary, _act_mse(hidden, env)
 
 
 def _swd_1d(x_sorted, y):
@@ -210,8 +217,9 @@ class SWDActMatchObjective(Objective):
             feats = lg.reshape(1, -1, lg.shape[-1])
         proj = feats @ state["dirs"]                          # (L, N, n_proj)
         swd = jax.vmap(_swd_1d)(state["ref_sorted"], proj).mean()
-        aux = (calib_ce(logits, env.calib, env.vocab_size)
-               if self.ce_anchor else jnp.float32(0.0))
+        with jax.named_scope("objective.ce"):
+            aux = (calib_ce(logits, env.calib, env.vocab_size)
+                   if self.ce_anchor else jnp.float32(0.0))
         return swd, aux
 
     def resolve_mix(self, primary0, aux0, env):
@@ -248,19 +256,18 @@ class SaliencyCEObjective(Objective):
         return {"w": jax.lax.stop_gradient(w)}
 
     def evaluate(self, logits, hidden, state, env: ObjectiveEnv):
-        lg = logits[:, :-1]
-        labels = env.calib[:, 1:]
-        V = lg.shape[-1]
-        if V > env.vocab_size:
-            mask = jnp.arange(V) < env.vocab_size
-            neg = jnp.finfo(jnp.float32).min / 2
-            lg = jnp.where(mask[None, None, :], lg, neg)
-        logp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        primary = jnp.mean(nll * state["w"])
-        aux = (activation_mse(hidden, env.hidden_fp, env.n_match)
-               if env.n_match else jnp.float32(0.0))
-        return primary, aux
+        with jax.named_scope("objective.ce"):
+            lg = logits[:, :-1]
+            labels = env.calib[:, 1:]
+            V = lg.shape[-1]
+            if V > env.vocab_size:
+                mask = jnp.arange(V) < env.vocab_size
+                neg = jnp.finfo(jnp.float32).min / 2
+                lg = jnp.where(mask[None, None, :], lg, neg)
+            logp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            primary = jnp.mean(nll * state["w"])
+        return primary, _act_mse(hidden, env)
 
     def resolve_mix(self, primary0, aux0, env):
         return resolve_alpha(primary0, aux0, env.ce_weight) \

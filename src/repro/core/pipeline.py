@@ -10,6 +10,10 @@ Contract between stages (DESIGN.md §1):
     (attention projections), which stay frozen during the search;
   * InvarExplore then hill-climbs fq(T(θ_base)) per unit (Algorithm 1);
   * without the search, the FFN weights are simply fake-quantized.
+
+Spans: ``pipeline.quantize`` covers the whole call, ``pipeline.base`` the
+base method and the freeze of the other weights (enqueued device work, not
+synced); the search adds its own (``repro.search.engine``).
 """
 from __future__ import annotations
 
@@ -17,6 +21,7 @@ import dataclasses
 from typing import Optional
 
 
+from repro import obs
 from repro.core.quant import QuantConfig, fake_quant
 from repro.core.rtn import map_quantizable
 from repro.core.awq import awq_process_dense
@@ -56,7 +61,26 @@ def quantize_model(
 ) -> PTQResult:
     if method != "rtn" and calib_tokens is None:
         raise ValueError(f"method {method!r} needs calib_tokens")
+    with obs.trace_span("pipeline.quantize", method=method,
+                        search=search is not None):
+        with obs.trace_span("pipeline.base", method=method):
+            params_base = _base_weights(params_fp, cfg, qcfg, method,
+                                        calib_tokens)
 
+        # 3) InvarExplore search or plain FFN fake-quant
+        if search is not None:
+            result = run_invar_search(params_fp, params_base, cfg, qcfg,
+                                      calib_tokens, search,
+                                      forward_kwargs=forward_kwargs)
+            return PTQResult(result.params_q, method + "+invarexplore",
+                             result)
+
+        params_q = map_quantizable(
+            params_base, lambda w, p: fake_quant(w, qcfg), only=_is_ffn)
+        return PTQResult(params_q, method, None)
+
+
+def _base_weights(params_fp, cfg, qcfg, method, calib_tokens):
     # 1) base-method processing (continuous-domain FFN weights)
     if method == "rtn":
         params_base = params_fp
@@ -70,16 +94,5 @@ def quantize_model(
         raise ValueError(f"unknown method {method!r}")
 
     # 2) freeze non-FFN quantizable weights at their fake-quant values
-    params_base = map_quantizable(
+    return map_quantizable(
         params_base, lambda w, p: fake_quant(w, qcfg), only=lambda p: not _is_ffn(p))
-
-    # 3) InvarExplore search or plain FFN fake-quant
-    if search is not None:
-        result = run_invar_search(params_fp, params_base, cfg, qcfg,
-                                  calib_tokens, search,
-                                  forward_kwargs=forward_kwargs)
-        return PTQResult(result.params_q, method + "+invarexplore", result)
-
-    params_q = map_quantizable(
-        params_base, lambda w, p: fake_quant(w, qcfg), only=_is_ffn)
-    return PTQResult(params_q, method, None)

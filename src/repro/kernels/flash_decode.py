@@ -100,5 +100,6 @@ def flash_decode_pallas(q, k, v, k_scale=None, v_scale=None, *, kv_len=None,
             jax.ShapeDtypeStruct((B, H), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(q, k, v, k_scale, v_scale)
     return out

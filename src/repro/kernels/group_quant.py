@@ -65,4 +65,5 @@ def group_quant_pallas(w, *, bits: int, group: int, bg: int = 4, bn: int = 256,
             jax.ShapeDtypeStruct((n_groups, N), jnp.float32),
         ],
         interpret=interpret,
+        name="group_quant",
     )(w)

@@ -275,6 +275,7 @@ def _paged_call(q4, k_pages, v_pages, block_tables, seq_lens, k_scale,
             jax.ShapeDtypeStruct((B, G, r, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_decode",
     )(block_tables, seq_lens, *operands)
     H = G * r
     out = out.reshape(B, H, Dh)

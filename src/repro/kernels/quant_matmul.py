@@ -75,4 +75,5 @@ def quant_matmul_pallas(x, packed, scale, zero, *, bits: int, group: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret,
+        name="quant_matmul",
     )(x, packed, scale, zero)

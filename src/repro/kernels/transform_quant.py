@@ -156,4 +156,5 @@ def transform_quant_pallas(w, pi, s, phi, *, bits: int, group: int, mode: str,
             jax.ShapeDtypeStruct((n_groups, N), jnp.float32),
         ],
         interpret=interpret,
+        name="transform_quant",
     )(pi2, s2, phi2, w)

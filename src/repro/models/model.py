@@ -106,19 +106,18 @@ def _dense_body(pl, cfg: ModelConfig, h, positions, cache=None, cache_index=0,
                 enc_h=None, causal=True):
     pl = dequant_tree(pl, jnp.dtype(cfg.compute_dtype))
     a_in = L.apply_norm(h, pl["ln1"], cfg.norm)
-    a, new_cache = L.self_attention(pl["attn"], cfg, a_in, positions, causal=causal,
-                                    cache=cache, cache_index=cache_index)
+    with jax.named_scope("attn"):
+        a, new_cache = L.self_attention(pl["attn"], cfg, a_in, positions, causal=causal,
+                                        cache=cache, cache_index=cache_index)
     h = h + a
     if "xattn" in pl and enc_h is not None:
         x_in = L.apply_norm(h, pl["ln_x"], cfg.norm)
         kv = L.cross_kv(pl["xattn"], cfg, enc_h)
         h = h + L.cross_attention(pl["xattn"], cfg, x_in, kv)
     m_in = L.apply_norm(h, pl["ln2"], cfg.norm)
-    if "moe" in pl:
-        h = h + L.moe_ffn(pl["moe"], cfg, m_in)
-    else:
-        h = h + L.mlp(pl["mlp"], cfg, m_in)
-    return h, new_cache
+    with jax.named_scope("ffn"):
+        f = L.moe_ffn(pl["moe"], cfg, m_in) if "moe" in pl else L.mlp(pl["mlp"], cfg, m_in)
+    return h + f, new_cache
 
 
 def _dense_body_cached_cross(pl, cfg, h, positions, cache, cache_index, cross_kv):
@@ -175,6 +174,7 @@ def _maybe_remat(fn, cfg: ModelConfig):
 # Forward (training / full-sequence)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(params, cfg: ModelConfig, tokens, positions):
     h = jnp.take(params["embed"]["tok"], tokens, axis=0)
     if cfg.pos_emb == "learned":
@@ -182,6 +182,7 @@ def embed_tokens(params, cfg: ModelConfig, tokens, positions):
     return h.astype(jnp.dtype(cfg.compute_dtype))
 
 
+@jax.named_scope("lm_head")
 def lm_head_logits(params, cfg: ModelConfig, h, *, mask_vocab: bool = False):
     """Final norm + (tied or dedicated, possibly QTensor) LM head.
 
@@ -229,22 +230,25 @@ def forward(params, cfg: ModelConfig, tokens, *, enc_embeds=None, vision_embeds=
     positions = jnp.arange(h.shape[1])
     enc_h = _run_encoder(params, cfg, enc_embeds) if cfg.is_enc_dec else None
 
-    if cfg.block_pattern in ("dense", "moe"):
-        def body(carry, pl):
-            out, _ = _dense_body(pl, cfg, carry, positions, enc_h=enc_h)
-            return out, out if collect_hidden else None
-        h, hidden = jax.lax.scan(_maybe_remat(body, cfg), h, params["blocks"],
-                                 unroll=cfg.unroll_layers)
-    elif cfg.block_pattern == "ssm":
-        def body(carry, pl):
-            out, _ = _ssm_body(pl, cfg, carry)
-            return out, out if collect_hidden else None
-        h, hidden = jax.lax.scan(_maybe_remat(body, cfg), h, params["blocks"],
-                                 unroll=cfg.unroll_layers)
-    elif cfg.block_pattern == "hybrid":
-        h, hidden = _hybrid_forward(params, cfg, h, positions, collect_hidden)
-    else:
-        raise ValueError(cfg.block_pattern)
+    # the scan writes each block's output into the stacked hidden taps at
+    # this scope: ``blocks/while/body/dynamic_update_slice``
+    with jax.named_scope("blocks"):
+        if cfg.block_pattern in ("dense", "moe"):
+            def body(carry, pl):
+                out, _ = _dense_body(pl, cfg, carry, positions, enc_h=enc_h)
+                return out, out if collect_hidden else None
+            h, hidden = jax.lax.scan(_maybe_remat(body, cfg), h, params["blocks"],
+                                     unroll=cfg.unroll_layers)
+        elif cfg.block_pattern == "ssm":
+            def body(carry, pl):
+                out, _ = _ssm_body(pl, cfg, carry)
+                return out, out if collect_hidden else None
+            h, hidden = jax.lax.scan(_maybe_remat(body, cfg), h, params["blocks"],
+                                     unroll=cfg.unroll_layers)
+        elif cfg.block_pattern == "hybrid":
+            h, hidden = _hybrid_forward(params, cfg, h, positions, collect_hidden)
+        else:
+            raise ValueError(cfg.block_pattern)
 
     logits = lm_head_logits(params, cfg, h)
     if collect_hidden:

@@ -28,16 +28,39 @@ Optional integrations:
 ``emit(name, **fields)`` writes a structured instant event (``"ph": "i"``)
 and prints a compact ``[name] k=v ...`` line — the replacement for ad-hoc
 progress ``print``s in the search engine.
+
+Span ids and parents: every span gets a process-unique ``id``; its begin
+event carries it with ``parent``, the id of the innermost span open on the
+same thread (None at the top), so a reader can rebuild the span tree.
+
+Compiles: one ``jax.monitoring`` duration listener (``install_compile_
+listener``, run once when ``repro.obs`` is imported) counts JAX's traces
+and backend compiles (a persistent-cache load counts as a compile) into
+``jax_traces_total{fn}``, ``jax_compiles_total{fn}`` and the histogram
+``jax_compile_seconds{fn}``. With a sink set, each compile also writes a
+``jax.compile`` instant event at its end: ``fn``, ``begin`` (= ts - dur_s),
+``dur_s`` and ``parent``, the span open when the compile ran.
+
+``span_log()`` returns the process's last closed spans and compiles
+(oldest first; at most ``SPAN_LOG_SIZE``), kept in memory whether or not a
+sink is set: ``{"name", "id", "parent", "ts", "dur_s"[, "attrs"]}`` with
+``ts`` the begin stamp, and ``{"name": "jax.compile", "fn", "parent",
+"ts", "dur_s"}``.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import threading
 import time
-from typing import IO, Optional, Union
+from typing import IO, List, Optional, Union
+
+from repro.obs import registry
 
 __all__ = ["trace_span", "emit", "set_trace_sink", "get_trace_sink",
-           "trace_to", "enable_xprof"]
+           "trace_to", "enable_xprof", "xprof_enabled", "current_span",
+           "span_log", "install_compile_listener"]
 
 _lock = threading.Lock()
 _sink: Optional[IO] = None
@@ -49,6 +72,29 @@ _xprof_default = False
 # shuffle spans within a trace
 _EPOCH_WALL = time.time()
 _EPOCH_MONO = time.monotonic()
+
+_ids = itertools.count(1)
+_local = threading.local()     # .open: ids of this thread's open spans
+SPAN_LOG_SIZE = 32768
+_span_log: collections.deque = collections.deque(maxlen=SPAN_LOG_SIZE)
+
+
+def _open_spans() -> list:
+    st = getattr(_local, "open", None)
+    if st is None:
+        st = _local.open = []
+    return st
+
+
+def current_span() -> Optional[int]:
+    """Id of the innermost span open on this thread (None if none is)."""
+    st = _open_spans()
+    return st[-1] if st else None
+
+
+def span_log() -> List[dict]:
+    """The last closed spans and compiles, oldest first (module doc)."""
+    return list(_span_log)
 
 
 def _now_ts() -> float:
@@ -66,6 +112,10 @@ def enable_xprof(on: bool = True) -> None:
     """Process default for the ``jax.profiler`` annotation passthrough."""
     global _xprof_default
     _xprof_default = bool(on)
+
+
+def xprof_enabled() -> bool:
+    return _xprof_default
 
 
 def _open(sink: Union[str, IO, None]):
@@ -142,7 +192,7 @@ class trace_span:
     seconds. ``elapsed()`` reads the running duration while still open."""
 
     __slots__ = ("name", "attrs", "hist", "hist_labels", "xprof", "t_wall",
-                 "_t0", "dur", "_annotation")
+                 "_t0", "dur", "_annotation", "id", "parent")
 
     def __init__(self, name: str, hist=None, hist_labels: Optional[dict] = None,
                  xprof: Optional[bool] = None, **attrs):
@@ -162,10 +212,15 @@ class trace_span:
             self._annotation = _make_annotation(self.name, self.attrs)
             if self._annotation is not None:
                 self._annotation.__enter__()
+        self.id = next(_ids)
+        st = _open_spans()
+        self.parent = st[-1] if st else None
+        st.append(self.id)
         self.t_wall = _now_ts()
         self._t0 = time.monotonic()
         if _sink is not None:
             _write({"ph": "B", "name": self.name, "ts": self.t_wall,
+                    "id": self.id, "parent": self.parent,
                     **({"attrs": self.attrs} if self.attrs else {})})
         return self
 
@@ -174,6 +229,15 @@ class trace_span:
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
             self._annotation = None
+        st = _open_spans()
+        if st and st[-1] == self.id:
+            st.pop()
+        elif self.id in st:      # closed out of order: drop it all the same
+            st.remove(self.id)
+        _span_log.append({"name": self.name, "id": self.id,
+                          "parent": self.parent, "ts": self.t_wall,
+                          "dur_s": self.dur,
+                          **({"attrs": self.attrs} if self.attrs else {})})
         if self.hist is not None:
             self.hist.observe(self.dur, **self.hist_labels)
         if _sink is not None:
@@ -207,3 +271,52 @@ def emit(name: str, _print: bool = True, **fields) -> str:
     if _print:
         print(line, flush=True)
     return line
+
+
+# ---------------------------------------------------------------------------
+# compiles
+# ---------------------------------------------------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_listening = False
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if event not in (TRACE_EVENT, COMPILE_EVENT):
+        return
+    fn = str(kw.get("fun_name", "?"))
+    if event == TRACE_EVENT:
+        registry.counter("jax_traces_total",
+                         "Functions JAX traced to a jaxpr").inc(fn=fn)
+        return
+    registry.counter(
+        "jax_compiles_total",
+        "Programs compiled or loaded from the persistent cache").inc(fn=fn)
+    registry.histogram(
+        "jax_compile_seconds",
+        "Backend compile (or cache load) time per program").observe(
+            duration, fn=fn)
+    end = _now_ts()
+    parent = current_span()
+    _span_log.append({"name": "jax.compile", "fn": fn, "parent": parent,
+                      "ts": end - duration, "dur_s": duration})
+    if _sink is not None:
+        _write({"ph": "i", "name": "jax.compile", "ts": end,
+                "begin": end - duration, "dur_s": duration, "fn": fn,
+                "parent": parent})
+
+
+def install_compile_listener() -> bool:
+    """Register the compile listener with ``jax.monitoring`` once per
+    process; False where JAX is not importable."""
+    global _listening
+    if _listening:
+        return True
+    try:
+        from jax import monitoring
+    except ImportError:
+        return False
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _listening = True
+    return True

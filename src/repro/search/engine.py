@@ -188,190 +188,195 @@ def _run_engine(
             "(candidate fingerprints are host state); mapped=True cannot "
             "combine with tabu>0")
 
-    base = adapter.base_stack(params_base)
-    proposer = getattr(adapter, "propose", None) or (
-        lambda key, t, pcfg: inv.propose(key, t, pcfg))
+    # search.prepare: everything before the first step, ending once the
+    # step-0 baseline scalars are on the host (so the span is synced)
+    with obs.trace_span("search.prepare", population=K,
+                        islands=n_islands):
+        base = adapter.base_stack(params_base)
+        proposer = getattr(adapter, "propose", None) or (
+            lambda key, t, pcfg: inv.propose(key, t, pcfg))
 
-    # identity transforms + initial fake-quant of every unit (per-unit slices
-    # hit quant_unit so the ndim>=2 "skip biases" check stays correct)
-    t0 = inv.identity_transform(adapter.f_dim)
-    transforms0 = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (adapter.n_units,) + x.shape).copy(), t0)
-    fq0 = jax.vmap(lambda b: adapter.quant_unit(b, qcfg))(base)
+        # identity transforms + initial fake-quant of every unit (per-unit slices
+        # hit quant_unit so the ndim>=2 "skip biases" check stays correct)
+        t0 = inv.identity_transform(adapter.f_dim)
+        transforms0 = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (adapter.n_units,) + x.shape).copy(), t0)
+        fq0 = jax.vmap(lambda b: adapter.quant_unit(b, qcfg))(base)
 
-    # reference forward (FP model) on the FULL calibration batch; per-island
-    # slices view into these (batch axis 0 for tokens/logits, axis 1 for the
-    # (L, B, S, D) hidden taps)
-    logits_fp, hidden_fp = forward(params_fp, cfg, calib_tokens,
-                                   collect_hidden=True, **fwd_kw)
-    hidden_fp = jax.lax.stop_gradient(hidden_fp[:n_match]) if n_match else None
-    logits_fp = jax.lax.stop_gradient(logits_fp)
+        # reference forward (FP model) on the FULL calibration batch; per-island
+        # slices view into these (batch axis 0 for tokens/logits, axis 1 for the
+        # (L, B, S, D) hidden taps)
+        logits_fp, hidden_fp = forward(params_fp, cfg, calib_tokens,
+                                       collect_hidden=True, **fwd_kw)
+        hidden_fp = jax.lax.stop_gradient(hidden_fp[:n_match]) if n_match else None
+        logits_fp = jax.lax.stop_gradient(logits_fp)
 
-    def make_env(tokens, lfp, hfp):
-        return obj.ObjectiveEnv(calib=tokens, logits_fp=lfp, hidden_fp=hfp,
-                                vocab_size=cfg.vocab_size, n_match=n_match,
-                                ce_weight=scfg.ce_weight)
+        def make_env(tokens, lfp, hfp):
+            return obj.ObjectiveEnv(calib=tokens, logits_fp=lfp, hidden_fp=hfp,
+                                    vocab_size=cfg.vocab_size, n_match=n_match,
+                                    ce_weight=scfg.ce_weight)
 
-    # Every array a step program reads besides the chain state — the base
-    # model, the calibration slice, its FP reference and the objective's
-    # prepared state — travels as an explicit argument, the island's
-    # "context". Closed over, jit would bake these into the program as
-    # constants (gigabytes at published widths), and a mapped island could
-    # not read its own device's copy.
-    def make_ctx(tokens, lfp, hfp):
-        return {"params": params_base, "calib": tokens, "logits_fp": lfp,
-                "hidden_fp": hfp, "fwd_kw": fwd_kw,
-                "state": objv.prepare(make_env(tokens, lfp, hfp))}
+        # Every array a step program reads besides the chain state — the base
+        # model, the calibration slice, its FP reference and the objective's
+        # prepared state — travels as an explicit argument, the island's
+        # "context". Closed over, jit would bake these into the program as
+        # constants (gigabytes at published widths), and a mapped island could
+        # not read its own device's copy.
+        def make_ctx(tokens, lfp, hfp):
+            return {"params": params_base, "calib": tokens, "logits_fp": lfp,
+                    "hidden_fp": hfp, "fwd_kw": fwd_kw,
+                    "state": objv.prepare(make_env(tokens, lfp, hfp))}
 
-    ctx_global = make_ctx(calib_tokens, logits_fp, hidden_fp)
-    env_global = make_env(calib_tokens, logits_fp, hidden_fp)
-    if shard_calib:
-        slices = shard_calibration(calib_tokens, n_islands)
-        bounds = np.cumsum([0] + [int(s.shape[0]) for s in slices])
-        ctxs = [make_ctx(slices[i],
-                         logits_fp[bounds[i]:bounds[i + 1]],
-                         (hidden_fp[:, bounds[i]:bounds[i + 1]]
-                          if n_match else None))
-                for i in range(n_islands)]
-    else:
-        ctxs = [ctx_global] * n_islands
-
-    def eval_stack_fn(ctx, fq):
-        env = make_env(ctx["calib"], ctx["logits_fp"], ctx["hidden_fp"])
-        params_q = adapter.install(ctx["params"], fq)
-        logits, hidden = forward(params_q, cfg, env.calib,
-                                 collect_hidden=True, **ctx["fwd_kw"])
-        return objv.evaluate(logits, hidden, ctx["state"], env)
-
-    eval_jit = jax.jit(eval_stack_fn)
-    p0, a0 = map(float, eval_jit(ctx_global, fq0))
-    alpha = float(objv.resolve_mix(p0, a0, env_global))
-    loss0 = p0 + alpha * a0
-
-    def quant_candidate(ctx, t_new, u):
-        base = adapter.base_stack(ctx["params"])
-        if fused:
-            return adapter.transform_quant_unit(base, t_new, u, qcfg)
-        unit = adapter.transform_unit(base, t_new, u)
-        return adapter.quant_unit(unit, qcfg)
-
-    # ---- step programs (shared by every island; the context is an arg) ---
-    # legacy single path: EXACTLY the pre-engine step — one proposal,
-    # unbatched evaluation in ONE jitted program. This is the bit-for-bit
-    # anchor; any K>1 / fused / tabu request takes the staged v2 pipeline.
-    staged = (K > 1) or fused or (tabu_cap > 0)
-    peak = {"bytes": 0, "batch_bytes": 0}
-
-    def step_body_single(ctx, key, transforms, fq_stack, u):
-        k_prop, _ = jax.random.split(key)
-        t_u = _tree_slice(transforms, u)
-        t_new = proposer(k_prop, inv.FFNTransform(*t_u), scfg.proposal)
-        unit = adapter.transform_unit(adapter.base_stack(ctx["params"]),
-                                      t_new, u)
-        unit_fq = adapter.quant_unit(unit, qcfg)
-        fq_new = _tree_update(fq_stack, u, unit_fq)
-        p, a = eval_stack_fn(ctx, fq_new)
-        loss = p + alpha * a
-        return loss, p, a, fq_new, t_new
-
-    # staged v2 pipeline: propose / build / eval / pick are SEPARATE jitted
-    # stages so the K-candidate buffer is a real set of device arrays between
-    # stages — ``jax.live_arrays()`` then measures the memory model honestly
-    # (stack + K × unit for install="unit", (K+1) × stack for "stack").
-    def propose_body(key, transforms, u):
-        keys = candidate_keys(key, K)
-        t_u = inv.FFNTransform(*_tree_slice(transforms, u))
-        cands = [proposer(keys[i], t_u, scfg.proposal) for i in range(K)]
-        return stack_trees(cands)
-
-    propose_fn = jax.jit(propose_body)
-
-    def build_units_body(ctx, cands, u):
-        units = [quant_candidate(
-            ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
-            for i in range(K)]
-        return stack_unit_batch(units)
-
-    def build_stacks_body(ctx, cands, fq_stack, u):
-        units = [quant_candidate(
-            ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
-            for i in range(K)]
-        return stack_trees([tree_install_unit(fq_stack, u, un)
-                            for un in units])
-
-    if install_mode == "unit":
-        def eval_body(ctx, batch, fq_stack, u):
-            def body(unit_fq):
-                return eval_stack_fn(
-                    ctx, tree_install_unit(fq_stack, u, unit_fq))
-            return jax.lax.map(body, batch)
-
-        def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
-            loss = p_vec + alpha * a_vec
-            i = jnp.argmin(loss)
-            fq_new = tree_install_unit(fq_stack, u, take_tree(batch, i))
-            return (loss[i], p_vec[i], a_vec[i], fq_new,
-                    inv.FFNTransform(*_tree_slice(cands, i)), loss)
-    else:
-        def eval_body(ctx, batch, fq_stack, u):
-            del fq_stack, u
-            return jax.vmap(lambda fq: eval_stack_fn(ctx, fq))(batch)
-
-        def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
-            del fq_stack, u
-            loss = p_vec + alpha * a_vec
-            i = jnp.argmin(loss)
-            return (loss[i], p_vec[i], a_vec[i], take_tree(batch, i),
-                    inv.FFNTransform(*_tree_slice(cands, i)), loss)
-
-    build_fn = jax.jit(build_units_body if install_mode == "unit"
-                       else build_stacks_body)
-    eval_fn = jax.jit(eval_body)
-    pick_fn = jax.jit(pick_body)
-
-    def staged_step(ctx, key, transforms, fq_stack, u):
-        cands = propose_fn(key, transforms, u)
-        if install_mode == "unit":
-            batch = build_fn(ctx, cands, u)
+        ctx_global = make_ctx(calib_tokens, logits_fp, hidden_fp)
+        env_global = make_env(calib_tokens, logits_fp, hidden_fp)
+        if shard_calib:
+            slices = shard_calibration(calib_tokens, n_islands)
+            bounds = np.cumsum([0] + [int(s.shape[0]) for s in slices])
+            ctxs = [make_ctx(slices[i],
+                             logits_fp[bounds[i]:bounds[i + 1]],
+                             (hidden_fp[:, bounds[i]:bounds[i + 1]]
+                              if n_match else None))
+                    for i in range(n_islands)]
         else:
-            batch = build_fn(ctx, cands, fq_stack, u)
-        if measure:
-            jax.block_until_ready(batch)
-            peak["bytes"] = max(peak["bytes"], _live_bytes())
-            peak["batch_bytes"] = max(peak["batch_bytes"], tree_bytes(batch))
-        p_vec, a_vec = eval_fn(ctx, batch, fq_stack, u)
-        out = pick_fn(cands, batch, fq_stack, u, p_vec, a_vec)
-        return out[:5] + (out[5], p_vec, a_vec)
+            ctxs = [ctx_global] * n_islands
 
-    # host-callable step: (ctx, key, transforms, fq_stack, u) ->
-    # (loss, primary, aux, fq_new, t_new[, loss_vec, p_vec, a_vec])
-    step_fn = staged_step if staged else jax.jit(step_body_single)
+        def eval_stack_fn(ctx, fq):
+            env = make_env(ctx["calib"], ctx["logits_fp"], ctx["hidden_fp"])
+            params_q = adapter.install(ctx["params"], fq)
+            logits, hidden = forward(params_q, cfg, env.calib,
+                                     collect_hidden=True, **ctx["fwd_kw"])
+            return objv.evaluate(logits, hidden, ctx["state"], env)
 
-    if shard_calib:
-        # per-island step-0 baselines on each island's OWN slice (1 island
-        # == the full batch == bitwise the replicated baseline)
-        loss0s, p0s, a0s = [], [], []
-        for ctx in ctxs:
-            pi0, ai0 = map(float, eval_jit(ctx, fq0))
-            p0s.append(pi0)
-            a0s.append(ai0)
-            loss0s.append(pi0 + alpha * ai0)
-    else:
-        loss0s = [loss0] * n_islands
-        p0s = [p0] * n_islands
-        a0s = [a0] * n_islands
+        eval_jit = obs.scoped(jax.jit(eval_stack_fn))
+        p0, a0 = map(float, eval_jit(ctx_global, fq0))
+        alpha = float(objv.resolve_mix(p0, a0, env_global))
+        loss0 = p0 + alpha * a0
 
-    schedule = anneal.temperature_schedule(
-        getattr(scfg, "anneal", "geometric"),
-        float(getattr(scfg, "temperature", 0.0)), scfg.steps)
+        def quant_candidate(ctx, t_new, u):
+            base = adapter.base_stack(ctx["params"])
+            if fused:
+                return adapter.transform_quant_unit(base, t_new, u, qcfg)
+            unit = adapter.transform_unit(base, t_new, u)
+            return adapter.quant_unit(unit, qcfg)
 
-    stats = {"migrations": 0, "uphill_accepts": 0,
-             "proposals": scfg.steps * K * n_islands, "fused": fused,
-             "mapped": mapped, "objective": objv.name,
-             "install": install_mode, "tabu_hits": 0,
-             "shard_calib": shard_calib}
-    metrics = _search_metrics()
-    metrics["best"].set(loss0)
+        # ---- step programs (shared by every island; the context is an arg) ---
+        # legacy single path: EXACTLY the pre-engine step — one proposal,
+        # unbatched evaluation in ONE jitted program. This is the bit-for-bit
+        # anchor; any K>1 / fused / tabu request takes the staged v2 pipeline.
+        staged = (K > 1) or fused or (tabu_cap > 0)
+        peak = {"bytes": 0, "batch_bytes": 0}
+
+        def step_body_single(ctx, key, transforms, fq_stack, u):
+            k_prop, _ = jax.random.split(key)
+            t_u = _tree_slice(transforms, u)
+            t_new = proposer(k_prop, inv.FFNTransform(*t_u), scfg.proposal)
+            unit = adapter.transform_unit(adapter.base_stack(ctx["params"]),
+                                          t_new, u)
+            unit_fq = adapter.quant_unit(unit, qcfg)
+            fq_new = _tree_update(fq_stack, u, unit_fq)
+            p, a = eval_stack_fn(ctx, fq_new)
+            loss = p + alpha * a
+            return loss, p, a, fq_new, t_new
+
+        # staged v2 pipeline: propose / build / eval / pick are SEPARATE jitted
+        # stages so the K-candidate buffer is a real set of device arrays between
+        # stages — ``jax.live_arrays()`` then measures the memory model honestly
+        # (stack + K × unit for install="unit", (K+1) × stack for "stack").
+        def propose_body(key, transforms, u):
+            keys = candidate_keys(key, K)
+            t_u = inv.FFNTransform(*_tree_slice(transforms, u))
+            cands = [proposer(keys[i], t_u, scfg.proposal) for i in range(K)]
+            return stack_trees(cands)
+
+        propose_fn = obs.scoped(jax.jit(propose_body))
+
+        def build_units_body(ctx, cands, u):
+            units = [quant_candidate(
+                ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
+                for i in range(K)]
+            return stack_unit_batch(units)
+
+        def build_stacks_body(ctx, cands, fq_stack, u):
+            units = [quant_candidate(
+                ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
+                for i in range(K)]
+            return stack_trees([tree_install_unit(fq_stack, u, un)
+                                for un in units])
+
+        if install_mode == "unit":
+            def eval_body(ctx, batch, fq_stack, u):
+                def body(unit_fq):
+                    return eval_stack_fn(
+                        ctx, tree_install_unit(fq_stack, u, unit_fq))
+                return jax.lax.map(body, batch)
+
+            def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
+                loss = p_vec + alpha * a_vec
+                i = jnp.argmin(loss)
+                fq_new = tree_install_unit(fq_stack, u, take_tree(batch, i))
+                return (loss[i], p_vec[i], a_vec[i], fq_new,
+                        inv.FFNTransform(*_tree_slice(cands, i)), loss)
+        else:
+            def eval_body(ctx, batch, fq_stack, u):
+                del fq_stack, u
+                return jax.vmap(lambda fq: eval_stack_fn(ctx, fq))(batch)
+
+            def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
+                del fq_stack, u
+                loss = p_vec + alpha * a_vec
+                i = jnp.argmin(loss)
+                return (loss[i], p_vec[i], a_vec[i], take_tree(batch, i),
+                        inv.FFNTransform(*_tree_slice(cands, i)), loss)
+
+        build_fn = obs.scoped(jax.jit(build_units_body if install_mode == "unit"
+                                      else build_stacks_body))
+        eval_fn = obs.scoped(jax.jit(eval_body))
+        pick_fn = obs.scoped(jax.jit(pick_body))
+
+        def staged_step(ctx, key, transforms, fq_stack, u):
+            cands = propose_fn(key, transforms, u)
+            if install_mode == "unit":
+                batch = build_fn(ctx, cands, u)
+            else:
+                batch = build_fn(ctx, cands, fq_stack, u)
+            if measure:
+                jax.block_until_ready(batch)
+                peak["bytes"] = max(peak["bytes"], _live_bytes())
+                peak["batch_bytes"] = max(peak["batch_bytes"], tree_bytes(batch))
+            p_vec, a_vec = eval_fn(ctx, batch, fq_stack, u)
+            out = pick_fn(cands, batch, fq_stack, u, p_vec, a_vec)
+            return out[:5] + (out[5], p_vec, a_vec)
+
+        # host-callable step: (ctx, key, transforms, fq_stack, u) ->
+        # (loss, primary, aux, fq_new, t_new[, loss_vec, p_vec, a_vec])
+        step_fn = staged_step if staged else obs.scoped(
+            jax.jit(step_body_single))
+
+        if shard_calib:
+            # per-island step-0 baselines on each island's OWN slice (1 island
+            # == the full batch == bitwise the replicated baseline)
+            loss0s, p0s, a0s = [], [], []
+            for ctx in ctxs:
+                pi0, ai0 = map(float, eval_jit(ctx, fq0))
+                p0s.append(pi0)
+                a0s.append(ai0)
+                loss0s.append(pi0 + alpha * ai0)
+        else:
+            loss0s = [loss0] * n_islands
+            p0s = [p0] * n_islands
+            a0s = [a0] * n_islands
+
+        schedule = anneal.temperature_schedule(
+            getattr(scfg, "anneal", "geometric"),
+            float(getattr(scfg, "temperature", 0.0)), scfg.steps)
+
+        stats = {"migrations": 0, "uphill_accepts": 0,
+                 "proposals": scfg.steps * K * n_islands, "fused": fused,
+                 "mapped": mapped, "objective": objv.name,
+                 "install": install_mode, "tabu_hits": 0,
+                 "shard_calib": shard_calib}
+        metrics = _search_metrics()
+        metrics["best"].set(loss0)
 
     if mapped:
         return _run_mapped_islands(
@@ -405,6 +410,11 @@ def _run_engine(
 
     rebuild_fn = jax.jit(rebuild_body)
 
+    def draw(isl):
+        """The step's proposal key and unit (the legacy draw order)."""
+        isl.key, sub = jax.random.split(isl.key)
+        return sub, jnp.int32(isl.rng.integers(adapter.n_units))
+
     with obs.trace_span("search.run", mode="sequential",
                         islands=n_islands, population=K) as run_span:
         for step in range(1, scfg.steps + 1):
@@ -413,11 +423,10 @@ def _run_engine(
                                 hist=metrics["step"]):
                 for isl in islands:
                     mem = tabus[isl.index]
-                    isl.key, sub = jax.random.split(isl.key)
-                    u = jnp.int32(isl.rng.integers(adapter.n_units))
                     skipped = False
                     cands = fps = None
                     if mem is not None:
+                        sub, u = draw(isl)
                         cands = propose_fn(sub, isl.transforms, u)
                         cand_bytes = [
                             transform_bytes(_tree_slice(cands, i))
@@ -441,57 +450,65 @@ def _run_engine(
                             loss, p, a = cached[ci]
                             fq_new = t_new = None
                         else:
-                            out = step_fn(ctxs[isl.index], sub,
-                                          isl.transforms, isl.fq_stack, u)
-                            loss, p, a, fq_new, t_new = out[:5]
-                            loss = float(loss)   # the device sync
-                            if mem is not None:
-                                # cache every candidate's device-computed
-                                # scalars for exact replay on a later hit
-                                loss_vec = np.asarray(out[5], np.float32)
-                                p_vec = np.asarray(out[6], np.float32)
-                                a_vec = np.asarray(out[7], np.float32)
-                                for i in range(K):
-                                    mem.record(fps[i], float(loss_vec[i]),
-                                               float(p_vec[i]),
-                                               float(a_vec[i]))
+                            with obs.trace_span("search.dispatch"):
+                                if mem is None:
+                                    sub, u = draw(isl)
+                                out = step_fn(ctxs[isl.index], sub,
+                                              isl.transforms, isl.fq_stack,
+                                              u)
+                                loss, p, a, fq_new, t_new = out[:5]
+                            with obs.trace_span("search.sync"):
+                                loss = float(loss)   # the device sync
+                                if mem is not None:
+                                    # cache every candidate's device-
+                                    # computed scalars for exact replay on a
+                                    # later hit
+                                    loss_vec = np.asarray(out[5], np.float32)
+                                    p_vec = np.asarray(out[6], np.float32)
+                                    a_vec = np.asarray(out[7], np.float32)
+                                    for i in range(K):
+                                        mem.record(fps[i],
+                                                   float(loss_vec[i]),
+                                                   float(p_vec[i]),
+                                                   float(a_vec[i]))
                             if measure:
                                 peak["bytes"] = max(peak["bytes"],
                                                     _live_bytes())
-                    metrics["proposals"].inc(K, objective=objv.name)
-                    delta = loss - isl.current_loss
-                    uniform = isl.rng.random() if T > 0.0 else None
-                    accepted = anneal.accept(delta, T, uniform)
-                    if accepted:
-                        if skipped:
-                            fq_new, t_new = rebuild_fn(
-                                ctxs[isl.index], cands, isl.fq_stack, u,
-                                jnp.int32(ci))
-                        # strictly-worse moves only (delta == 0 is lateral,
-                        # not uphill), counted as a Python int — not an
-                        # accumulated numpy bool
-                        if delta > 0.0:
-                            stats["uphill_accepts"] += 1
-                            metrics["uphill"].inc()
-                        metrics["accepts"].inc()
-                        isl.current_loss = loss
-                        isl.fq_stack = fq_new
-                        isl.transforms = _tree_update(isl.transforms, u,
-                                                      t_new)
-                        isl.n_accept += 1
-                        if mem is not None:
-                            idx = ci if skipped else None
-                            if idx is None:
-                                # which candidate won? match by bytes
-                                tb = transform_bytes(t_new)
-                                idx = cand_bytes.index(tb)
-                            mem.advance(cand_bytes[idx])
-                        if loss < isl.best_loss:
-                            isl.best_loss = loss
-                            isl.best_transforms = isl.transforms
-                            isl.best_fq = isl.fq_stack
-                    isl.history.append(
-                        (step, loss, float(p), float(a), accepted))
+                    with obs.trace_span("search.accept"):
+                        metrics["proposals"].inc(K, objective=objv.name)
+                        delta = loss - isl.current_loss
+                        uniform = isl.rng.random() if T > 0.0 else None
+                        accepted = anneal.accept(delta, T, uniform)
+                        if accepted:
+                            if skipped:
+                                fq_new, t_new = rebuild_fn(
+                                    ctxs[isl.index], cands, isl.fq_stack, u,
+                                    jnp.int32(ci))
+                            # strictly-worse moves only (delta == 0 is
+                            # lateral, not uphill), counted as a Python int
+                            # — not an accumulated numpy bool
+                            if delta > 0.0:
+                                stats["uphill_accepts"] += 1
+                                metrics["uphill"].inc()
+                            metrics["accepts"].inc()
+                            isl.current_loss = loss
+                            isl.fq_stack = fq_new
+                            isl.transforms = _tree_update(isl.transforms, u,
+                                                          t_new)
+                            isl.n_accept += 1
+                            if mem is not None:
+                                idx = ci if skipped else None
+                                if idx is None:
+                                    # which candidate won? match by bytes
+                                    tb = transform_bytes(t_new)
+                                    idx = cand_bytes.index(tb)
+                                mem.advance(cand_bytes[idx])
+                            if loss < isl.best_loss:
+                                isl.best_loss = loss
+                                isl.best_transforms = isl.transforms
+                                isl.best_fq = isl.fq_stack
+                        isl.history.append(
+                            (step, loss, float(p), float(a), accepted))
                 if migrate_every and n_islands > 1 \
                         and step % migrate_every == 0:
                     if tabu_cap:
@@ -645,42 +662,46 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fn,
         with obs.trace_span("search.eval", hist=metrics["eval"]):
             outs = {}
             u_dev = {}
-            for i, d in local.items():   # dispatch all, then fetch (async)
-                u_dev[i] = jax.device_put(jnp.int32(us[i]), d)
-                outs[i] = step_fn(ctx_loc[i], jax.device_put(subs[i], d),
-                                  t_loc[i], fq_loc[i], u_dev[i])
-            scal = np.zeros((n_islands, 3), np.float32)
-            for i, out in outs.items():
-                scal[i] = [float(out[0]), float(out[1]), float(out[2])]
+            with obs.trace_span("search.dispatch"):
+                for i, d in local.items():   # dispatch all, then fetch
+                    u_dev[i] = jax.device_put(jnp.int32(us[i]), d)
+                    outs[i] = step_fn(ctx_loc[i],
+                                      jax.device_put(subs[i], d),
+                                      t_loc[i], fq_loc[i], u_dev[i])
+            with obs.trace_span("search.sync"):
+                scal = np.zeros((n_islands, 3), np.float32)
+                for i, out in outs.items():
+                    scal[i] = [float(out[0]), float(out[1]), float(out[2])]
         # each host counts only its LOCAL islands, so the dist_snapshot sum
         # over hosts reconciles with the global stats["proposals"]
         metrics["proposals"].inc(len(outs) * K, objective=obj_name)
         if multiproc:
             scal = np.asarray(exchange(put_shd(scal)))
-        for i in range(n_islands):
-            loss = float(scal[i, 0])
-            delta = loss - cur[i]
-            uniform = rngs[i].random() if T > 0.0 else None
-            accepted = anneal.accept(delta, T, uniform)
-            if accepted:
-                if delta > 0.0:
-                    stats["uphill_accepts"] += 1
+        with obs.trace_span("search.accept"):
+            for i in range(n_islands):
+                loss = float(scal[i, 0])
+                delta = loss - cur[i]
+                uniform = rngs[i].random() if T > 0.0 else None
+                accepted = anneal.accept(delta, T, uniform)
+                if accepted:
+                    if delta > 0.0:
+                        stats["uphill_accepts"] += 1
+                        if i in outs:
+                            metrics["uphill"].inc()
+                    if i in outs:   # count local islands only (see proposals)
+                        metrics["accepts"].inc()
+                    cur[i] = loss
+                    n_accept[i] += 1
                     if i in outs:
-                        metrics["uphill"].inc()
-                if i in outs:   # count local islands only (see proposals)
-                    metrics["accepts"].inc()
-                cur[i] = loss
-                n_accept[i] += 1
-                if i in outs:
-                    fq_loc[i] = outs[i][3]
-                    t_loc[i] = _tree_update(t_loc[i], u_dev[i], outs[i][4])
-                if loss < best[i]:
-                    best[i] = loss
-                    if i in outs:
-                        bt_loc[i] = t_loc[i]
-                        bfq_loc[i] = fq_loc[i]
-            histories[i].append((step, loss, float(scal[i, 1]),
-                                 float(scal[i, 2]), accepted))
+                        fq_loc[i] = outs[i][3]
+                        t_loc[i] = _tree_update(t_loc[i], u_dev[i], outs[i][4])
+                    if loss < best[i]:
+                        best[i] = loss
+                        if i in outs:
+                            bt_loc[i] = t_loc[i]
+                            bfq_loc[i] = fq_loc[i]
+                histories[i].append((step, loss, float(scal[i, 1]),
+                                     float(scal[i, 2]), accepted))
         if migrate_every and n_islands > 1 and step % migrate_every == 0:
             t_st = gather_island_states(t_loc, mesh, n_islands)
             fq_st = gather_island_states(fq_loc, mesh, n_islands)

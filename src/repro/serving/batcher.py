@@ -158,7 +158,8 @@ class ContinuousBatcher:
                 "One jitted decode step (time per output token)"),
             "prefill": reg.histogram(
                 "serving_prefill_seconds",
-                "Chunked prefill latency per admitted request"),
+                "Chunked prefill latency per admitted request, to the "
+                "read of its first token"),
             "queue_depth": reg.gauge(
                 "serving_queue_depth", "Queued requests at the last step"),
             "pages_in_use": reg.gauge(
@@ -295,23 +296,26 @@ class ContinuousBatcher:
         page_ids = matched + fresh
         bt = jnp.asarray(self.cache.block_table_row(page_ids)[None])
         start = len(matched) * psz
+        # serve.prefill ends on the host's read of the first token, the
+        # prefill's sync; serve.prefill_dispatch is the chunks' enqueue
         with obs.trace_span("serve.prefill", tokens=plen - start,
                             hist=self.obs["prefill"]):
-            logits_row, self.cache.pools, n_chunks = run_prefill_chunks(
-                self._prefill_chunk, self.params, self.cache.pools, full, bt,
-                page_size=psz, chunk_pages=self.prefill_chunk_pages,
-                start=start)
-        self.stats["prefill_chunks"] += n_chunks
-        self.stats["prefill_tokens"] += plen - start
-        self.stats["prefill_tokens_saved"] += start
-        self.stats["aliased_pages"] += len(matched)
-        self.obs["prefill_tokens"].inc(plen - start)
-        self.obs["tokens_saved"].inc(start)
-        self.obs["aliased"].inc(len(matched))
-        if self.prefix is not None:
-            for i in range(len(matched), plen // psz):
-                self.prefix.insert(keys[i], page_ids[i])
-        nxt = self._first_token(req, logits_row)
+            with obs.trace_span("serve.prefill_dispatch"):
+                logits_row, self.cache.pools, n_chunks = run_prefill_chunks(
+                    self._prefill_chunk, self.params, self.cache.pools, full,
+                    bt, page_size=psz, chunk_pages=self.prefill_chunk_pages,
+                    start=start)
+            self.stats["prefill_chunks"] += n_chunks
+            self.stats["prefill_tokens"] += plen - start
+            self.stats["prefill_tokens_saved"] += start
+            self.stats["aliased_pages"] += len(matched)
+            self.obs["prefill_tokens"].inc(plen - start)
+            self.obs["tokens_saved"].inc(start)
+            self.obs["aliased"].inc(len(matched))
+            if self.prefix is not None:
+                for i in range(len(matched), plen // psz):
+                    self.prefix.insert(keys[i], page_ids[i])
+            nxt = self._first_token(req, logits_row)
         self.stats["prefills"] += 1
         self._ticket += 1
         slot = _Slot(req=req, page_ids=page_ids, seq_len=plen, last_tok=nxt,
