@@ -8,6 +8,10 @@
   fake-quant — the population search's per-proposal hot path; one VMEM pass
   instead of materialize-transformed-weights-then-quantize (two full HBM
   round trips).
+- ``flash_attention``: full-sequence causal or plain self-attention with
+  the score tiles kept in VMEM and the tiles above the causal diagonal never
+  computed — the no-cache forward's attention on a TPU (training, the
+  search's calibration forwards).
 - ``flash_decode`` / ``paged_decode``: fused one-token decode attention over
   a contiguous (flash) or block-table-paged (paged) KV cache; the paged
   variant scalar-prefetches the block table so continuous batching reads
@@ -17,8 +21,9 @@ Each kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` wraps them with
 jit + CPU interpret-mode fallback; tests sweep shapes/dtypes against the
 oracles.
 """
-from repro.kernels.ops import (quant_matmul, group_quant, flash_decode,
-                               paged_decode, transform_quant, on_tpu)
+from repro.kernels.ops import (quant_matmul, group_quant, flash_attention,
+                               flash_decode, paged_decode, transform_quant,
+                               on_tpu)
 
-__all__ = ["quant_matmul", "group_quant", "flash_decode", "paged_decode",
-           "transform_quant", "on_tpu"]
+__all__ = ["quant_matmul", "group_quant", "flash_attention", "flash_decode",
+           "paged_decode", "transform_quant", "on_tpu"]

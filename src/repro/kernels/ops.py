@@ -13,17 +13,20 @@ import functools
 import warnings
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.quant_matmul import quant_matmul_pallas
 from repro.kernels.group_quant import group_quant_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.paged_decode import (paged_decode_gqa_pallas,
                                         paged_decode_pallas)
 from repro.kernels.transform_quant import transform_quant_pallas
 
-__all__ = ["quant_matmul", "group_quant", "flash_decode", "paged_decode",
-           "transform_quant", "tq_plan", "TQPlan", "on_tpu"]
+__all__ = ["quant_matmul", "group_quant", "flash_attention",
+           "flash_attention_refusal", "one_device_program", "flash_decode",
+           "paged_decode", "transform_quant", "tq_plan", "TQPlan", "on_tpu"]
 
 # VMEM budget for one transform_quant full-F strip. The kernel holds an
 # input strip AND a same-size fq output strip, and both revolve per grid
@@ -86,6 +89,60 @@ def tq_plan(K: int, N: int, *, group: int, mode: str) -> TQPlan:
                   n_groups=n_groups, f=f, reason=reason)
 
 
+# Longest sequence the flash-attention kernel takes: one grid step holds a
+# whole sequence of q, k, v and output for one 128-lane block of heads
+# (about 2.5 MB of VMEM, double-buffered, at S = 1024), and its q-tile loop
+# is unrolled. Longer sequences keep ``blocked_attention``.
+_FA_MAX_SEQ = 1024
+
+
+def _fa_refusal(q_shape, k_shape) -> str:
+    _, sq, hq, dh = q_shape
+    sk, hkv = k_shape[1], k_shape[2]
+    if sq != sk:
+        return f"query length {sq} != key length {sk}"
+    if dh % 128 and 128 % dh:
+        return f"head_dim {dh} neither divides nor is a multiple of 128"
+    if (hq * dh) % max(128, dh):
+        return f"{hq} heads of {dh} do not fill whole 128-lane blocks"
+    if hq % hkv:
+        return f"{hq} query heads are not a multiple of {hkv} kv heads"
+    if hkv < hq and dh < 128:
+        return (f"GQA at head_dim {dh} < 128: a lane block would mix the kv "
+                f"heads of its query heads")
+    if sq % 16:
+        return f"sequence {sq} is not a multiple of 16"
+    if sq % min(128, sq):
+        return f"sequence {sq} is not a multiple of the 128-row q tile"
+    if sq > _FA_MAX_SEQ:
+        return f"sequence {sq} exceeds {_FA_MAX_SEQ}"
+    return ""
+
+
+def flash_attention_refusal(q_shape, k_shape) -> str:
+    """Why the flash-attention kernel cannot take q (B, S, Hq, Dh) and k
+    (B, S, Hkv, Dh); "" where it can. Its lane block is ``max(128, Dh)``
+    wide: 128 / Dh heads where Dh divides 128, one head where Dh is a
+    multiple of it; its q tile is 256 or 128 rows, or all of a shorter S.
+    A refusal warns once per shape on a TPU, with the reason."""
+    reason = _fa_refusal(q_shape, k_shape)
+    if reason:
+        _fallback("flash_attention", q_shape, reason)
+    return reason
+
+
+def one_device_program() -> bool:
+    """True where the program being traced runs on one device: the process
+    sees one device, or the trace is inside a ``shard_map`` over every axis
+    of its mesh. A Mosaic kernel cannot be partitioned by GSPMD, so a
+    program that may span devices must not hold one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and all(t == jax.sharding.AxisType.Manual
+                              for t in mesh.axis_types):
+        return True
+    return jax.device_count() == 1
+
+
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -136,6 +193,30 @@ def quant_matmul(x, packed, scale, zero, *, bits: int, group: int,
     bn = 256 if N % 256 == 0 else 128
     return quant_matmul_pallas(x, packed, scale, zero, bits=bits, group=group,
                                bm=bm, bk=bk, bn=bn, interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("causal",))
+def flash_attention(q, k, v, *, causal: bool):
+    """Full-sequence self-attention, q (B, S, Hq, Dh), k/v (B, S, Hkv, Dh)
+    -> (B, S, Hq, Dh): the no-cache forward's attention with its scores kept
+    in VMEM (``kernels/flash_attention.py``). The arithmetic is the float32
+    reference's at JAX's default matmul precision, one bfloat16 pass, up to
+    the output, which is rounded to bfloat16 as the output projection's pass
+    rounds it. Forward only; the caller checks ``flash_attention_refusal``
+    first (``layers.flash_attention`` adds the gradient)."""
+    reason = _fa_refusal(q.shape, k.shape)
+    if reason:
+        raise ValueError(f"flash_attention: shape {q.shape}: {reason}")
+    B, S, H, Dh = q.shape
+    # the MXU operands the float32 reference feeds at default precision; the
+    # output is rounded to bfloat16 as the output projection's one pass
+    # would round it, which halves its write and the projection's read
+    qs = (q.astype(jnp.float32) * Dh ** -0.5).astype(jnp.bfloat16)
+    out = flash_attention_pallas(
+        qs.reshape(B, S, H * Dh), k.astype(jnp.bfloat16).reshape(B, S, -1),
+        v.astype(jnp.bfloat16).reshape(B, S, -1), causal=causal,
+        head_dim=Dh, interpret=_interpret())
+    return out.reshape(B, S, H, Dh).astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_len", "chunk", "use_pallas"))

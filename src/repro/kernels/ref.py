@@ -9,7 +9,33 @@ from repro.core.quant import (QuantConfig, compute_qparams, quantize_codes,
                               dequantize_codes, unpack_codes)
 
 __all__ = ["quant_matmul_ref", "group_quant_ref", "dequant_ref",
-           "flash_decode_ref", "paged_decode_ref", "transform_quant_ref"]
+           "flash_attention_ref", "flash_decode_ref", "paged_decode_ref",
+           "transform_quant_ref"]
+
+
+def flash_attention_ref(q, k, v, *, causal: bool):
+    """Dense full-sequence attention oracle with the flash kernel's
+    arithmetic: q·Dh^-0.5, k, v and the probabilities rounded to bfloat16
+    (the one MXU pass of default precision), products summed in float32.
+    The output is not rounded to bfloat16 as ``ops.flash_attention``'s is.
+    q (B, S, Hq, Dh); k/v (B, S, Hkv, Dh) -> (B, S, Hq, Dh) in q's dtype."""
+    def bf16(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    B, S, Hq, Dh = q.shape
+    rep = Hq // k.shape[2]
+    kf = jnp.repeat(bf16(k), rep, axis=2)
+    vf = jnp.repeat(bf16(v), rep, axis=2)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bqhd,bkhd->bhqk", bf16(q.astype(jnp.float32) * Dh ** -0.5),
+                   kf, precision=hi)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", bf16(p), vf, precision=hi)
+    return (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)
 
 
 def flash_decode_ref(q, k, v, k_scale=None, v_scale=None, kv_len=None):

@@ -1,10 +1,12 @@
 """Shared neural building blocks: norms, RoPE, blocked attention, MLP, MoE."""
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.config import ModelConfig
 
 NEG_INF = -1e30
@@ -264,8 +266,56 @@ def self_attention(p, cfg: ModelConfig, x, positions, *, causal=True, cache=None
                                     causal=causal, q_offset=cache_index,
                                     kv_len=kv_len, **kw)
         return attn_out(p, out, cfg), new_cache
-    out = blocked_attention(q, k, v, causal=causal, **kw)
-    return attn_out(p, out, cfg), None
+    return attn_out(p, _full_attention(q, k, v, causal, kw), cfg), None
+
+
+# Matmul precisions at which a TPU matmul of float32 inputs takes one
+# bfloat16 pass: the arithmetic of the flash-attention kernel.
+_ONE_BF16_PASS = (None, "default", "bfloat16", "BF16_BF16_F32")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def flash_attention(q, k, v, causal):
+    """``blocked_attention`` of whole sequences (no offset, no cache) through
+    the Pallas kernel (``ops.flash_attention``), differentiable: the
+    backward is ``blocked_attention``'s VJP on the same inputs."""
+    from repro.kernels import ops  # local import: kernels are optional
+    return ops.flash_attention(q, k, v, causal=causal)
+
+
+def _flash_attention_fwd(q, k, v, causal):
+    return flash_attention(q, k, v, causal), (q, k, v)
+
+
+def _flash_attention_bwd(causal, res, g):
+    _, vjp = jax.vjp(
+        lambda q, k, v: blocked_attention(q, k, v, causal=causal), *res)
+    return vjp(g)
+
+
+flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
+def _full_attention(q, k, v, causal, kw):
+    """No-cache attention over whole sequences: ``flash_attention`` on a TPU
+    where the kernel can take it, else ``blocked_attention``. The kernel's
+    arithmetic is a float32 softmax over one bfloat16 pass per matmul, so it
+    is taken only at that softmax dtype and matmul precision; a Mosaic
+    kernel cannot be partitioned, so only in a program on one device
+    (``ops.one_device_program``); and only for a shape it can tile (a shape
+    it cannot warns once). ``attention_path_total{path}`` counts the choice
+    once per trace."""
+    from repro.kernels import ops  # local import: kernels are optional
+    flash = (ops.on_tpu() and kw["softmax_dtype"] == jnp.float32
+             and jax.config.jax_default_matmul_precision in _ONE_BF16_PASS
+             and ops.one_device_program()
+             and not ops.flash_attention_refusal(q.shape, k.shape))
+    obs.counter("attention_path_total",
+                "Traced no-cache attention calls, by kernel path").inc(
+                    path="flash" if flash else "blocked")
+    if flash:
+        return flash_attention(q, k, v, causal)
+    return blocked_attention(q, k, v, causal=causal, **kw)
 
 
 def _flash_decode_attention(cfg: ModelConfig, q, cache, kv_len):

@@ -1,11 +1,16 @@
-"""The paged-decode kernels compile for a TPU v5e chip at published widths.
+"""The paged-decode and flash-attention kernels compile for a TPU v5e chip at
+published widths.
 
 Every other kernel test runs in interpret mode on the CPU, which never meets
 the TPU compiler's rules (block shapes, tiling, VMEM). Here each kernel is
 lowered with ``interpret=False`` for a *described* v5e chip — no chip is
 attached; libtpu's compiler runs on the host — and the compiled program must
 contain the Mosaic kernel (``tpu_custom_call``). Shapes come from the configs
-the serving path runs: OPT-1.3B (MHA, f32) and Qwen3-4B (GQA, bf16).
+the serving path runs: OPT-1.3B (MHA, f32) and Qwen3-4B (GQA, bf16); the
+flash-attention kernel at the search's 512-token calibration rows. The
+no-cache forward compiles for one chip with the kernel, and for the four
+chips of a v5e:2x2 host with a batch-sharded input, where GSPMD could not
+split the kernel and the forward keeps ``blocked_attention``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load libtpu, and a test file that loaded it while being
@@ -13,12 +18,19 @@ collected would break the other test workers. JAX's persistent compilation
 cache is off around these tests (an entry compiled for a described chip
 cannot be read back without one).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro import obs
 from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.models import forward, init_params
 from repro.kernels.paged_decode import (paged_decode_gqa_pallas,
                                         paged_decode_pallas)
 
@@ -37,14 +49,18 @@ def no_compile_cache():
 
 
 @pytest.fixture(scope="module")
-def one_chip(no_compile_cache):
+def v5e_2x2(no_compile_cache):
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compiled_text(sharding, kernel, arch, *, int8=False, **kw):
@@ -88,3 +104,62 @@ def test_int8_pool_compiles_at_page_16(one_chip, arch, kernel):
     # legal only because it spans the pool's whole minor dims
     txt = _compiled_text(one_chip, kernel, arch, int8=True)
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("arch,S,causal", [
+    ("opt-1.3b", 512, True),        # two heads of 64 per lane block
+    ("opt-1.3b", 1024, False),
+    ("opt-1.3b", 1024, True),       # the longest sequence it takes
+    ("opt-1.3b", 384, True),        # 128-row q tiles
+    ("qwen3-4b", 512, True),        # GQA, one head of 128 per lane block
+])
+def test_flash_attention_compiles(one_chip, arch, S, causal):
+    cfg = get_config(arch)
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+
+    def spec(heads):
+        return jax.ShapeDtypeStruct((2, S, heads * Dh), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    fn = jax.jit(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=causal, head_dim=Dh))
+    txt = fn.lower(spec(H), spec(Hkv), spec(Hkv)).compile().as_text()
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("program,path", [
+    ("one_chip", "flash"),          # a host with one chip
+    ("batch_sharded", "blocked"),   # GSPMD over a host's four chips
+    ("shard_map", "flash"),         # one chip per shard_map body
+])
+def test_forward_compiles_for_its_devices(v5e_2x2, monkeypatch, program,
+                                          path):
+    # trace as a TPU host would: TPU routing, Mosaic lowering, its devices
+    n_chips = 1 if program == "one_chip" else len(v5e_2x2.devices)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "device_count", lambda: n_chips)
+    cfg = dataclasses.replace(get_config("opt-tiny"), n_layers=2)
+    mesh = jax.sharding.Mesh(np.array(v5e_2x2.devices), ("data",))
+    if program == "one_chip":
+        rows = whole = SingleDeviceSharding(v5e_2x2.devices[0])
+    else:
+        rows = NamedSharding(mesh, PartitionSpec("data"))
+        whole = NamedSharding(mesh, PartitionSpec())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32, sharding=rows)
+
+    def fwd(p, t):
+        return forward(p, cfg, t)
+
+    if program == "shard_map":
+        fwd = jax.shard_map(
+            fwd, mesh=mesh, in_specs=(PartitionSpec(), PartitionSpec("data")),
+            out_specs=PartitionSpec("data"), check_vma=False)
+    c = obs.counter("attention_path_total")
+    before = c.value(path=path)
+    txt = jax.jit(fwd).lower(params, toks).compile().as_text()
+    assert c.value(path=path) == before + 1
+    assert ("tpu_custom_call" in txt) == (path == "flash")
