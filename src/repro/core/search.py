@@ -151,20 +151,78 @@ class DenseFFNAdapter:
 
 
 class MoEAdapter:
-    """MoE blocks: unit = one expert's FFN. n_units = L * E (per-expert search
-    — under expert parallelism each shard searches its own experts)."""
+    """MoE stacks: every unit is one FFN of the expert width ``d_ff``, so the
+    engine stacks one shape (under expert parallelism each shard searches
+    its own experts).
+
+    Units, in this order: each leading dense FFN split into column blocks of
+    ``d_ff`` (``dense_d_ff / d_ff`` per layer); each shared expert (the
+    shared MLP's ``d_ff`` column blocks) of every MoE layer; each held routed
+    expert of every MoE layer. A unit's permutation stays inside its block,
+    a restriction of the paper's search space for the dense and shared
+    FFNs, which the paper would permute across their whole width."""
+
+    KEYS = ("up", "gate", "down")
 
     def __init__(self, cfg: ModelConfig):
+        from repro.models.model import n_lead_blocks
         self.cfg = cfg
-        self.E = cfg.moe.num_experts
-        self.n_units = cfg.n_layers * self.E
-        self.f_dim = cfg.d_ff
+        f = cfg.d_ff
+        self.n_lead = n_lead_blocks(cfg)
+        if self.n_lead and cfg.dense_d_ff % f:
+            raise ValueError(f"dense FFN width {cfg.dense_d_ff} is not a "
+                             f"multiple of the expert width {f}")
+        self.lead_split = cfg.dense_d_ff // f if self.n_lead else 0
+        self.n_moe = cfg.n_layers - self.n_lead
+        self.n_shared = cfg.moe.n_shared_experts
+        self.n_held = cfg.moe.held[1]
+        self.sizes = (self.n_lead * self.lead_split, self.n_moe * self.n_shared,
+                      self.n_moe * self.n_held)
+        self.n_units = sum(self.sizes)
+        self.f_dim = f
+
+    def _split(self, mlp, n):
+        """(L, D, n*F) / (L, n*F, D) leaves -> (L*n, D, F) / (L*n, F, D)."""
+        f = self.f_dim
+        out = {}
+        for k in self.KEYS:
+            if k not in mlp:
+                continue
+            x = mlp[k]
+            if k == "down":
+                out[k] = x.reshape((-1, f) + x.shape[2:])
+            else:
+                Lx, D = x.shape[:2]
+                out[k] = x.reshape(Lx, D, n, f).transpose(0, 2, 1, 3).reshape(
+                    Lx * n, D, f)
+        return out
+
+    def _join(self, units, n):
+        """Inverse of ``_split``."""
+        out = {}
+        for k, x in units.items():
+            if k == "down":
+                out[k] = x.reshape((-1, n * self.f_dim) + x.shape[2:])
+            else:
+                D = x.shape[1]
+                out[k] = x.reshape(-1, n, D, self.f_dim).transpose(
+                    0, 2, 1, 3).reshape(-1, D, n * self.f_dim)
+        return out
 
     def base_stack(self, params):
         moe = params["blocks"]["moe"]
+        parts = []
+        if self.n_lead:
+            parts.append(self._split(params["dense_blocks"]["mlp"],
+                                     self.lead_split))
+        if self.n_shared:
+            parts.append(self._split(moe["shared"], self.n_shared))
         # (L, E, ...) -> (L*E, ...) unit-major
-        return {k: moe[k].reshape((-1,) + moe[k].shape[2:])
-                for k in ("up", "down", "gate") if k in moe}
+        parts.append({k: moe[k].reshape((-1,) + moe[k].shape[2:])
+                      for k in self.KEYS if k in moe})
+        if len(parts) == 1:
+            return parts[0]
+        return {k: jnp.concatenate([p[k] for p in parts]) for k in parts[-1]}
 
     def transform_unit(self, base, t, u):
         b = _tree_slice(base, u)
@@ -183,12 +241,25 @@ class MoEAdapter:
     transform_quant_unit = DenseFFNAdapter.transform_quant_unit
 
     def install(self, params, fq_stack):
+        n_lead, n_shared, _ = self.sizes
         params = dict(params)
         blocks = dict(params["blocks"])
         moe = dict(blocks["moe"])
-        L = self.cfg.n_layers
+
+        def part(lo, hi):
+            return {k: v[lo:hi] for k, v in fq_stack.items()}
+        if self.n_lead:
+            lead = dict(params["dense_blocks"])
+            lead["mlp"] = {**lead["mlp"],
+                           **self._join(part(0, n_lead), self.lead_split)}
+            params["dense_blocks"] = lead
+        if self.n_shared:
+            moe["shared"] = {**moe["shared"], **self._join(
+                part(n_lead, n_lead + n_shared), self.n_shared)}
+        lo = n_lead + n_shared
         for k, v in fq_stack.items():
-            moe[k] = v.reshape((L, self.E) + v.shape[1:])
+            v = v[lo:] if lo else v
+            moe[k] = v.reshape((self.n_moe, self.n_held) + v.shape[1:])
         blocks["moe"] = moe
         params["blocks"] = blocks
         return params

@@ -111,7 +111,8 @@ def _map_tree(fn, tree, path=()):
 # ---------------------------------------------------------------------------
 
 _MLP_COL = ("up", "gate", "b_up", "b_gate")   # output (N) dim sharded
-_ATTN_Q = ("wq", "bq")                         # q-head-structured outputs
+_ATTN_Q = ("wq", "bq", "wkv_b")               # q-head-structured outputs
+                                               # (MLA's W_kvb: k_nope, v)
 _ATTN_KV = ("wk", "wv", "bk", "bv")            # kv-head-structured outputs
 
 
@@ -130,7 +131,7 @@ def _propose(rules: ShardingRules, path) -> int | None:
         if leaf == "wo" and rules.heads_ok:
             return -2
         return None
-    if parent == "mlp":
+    if parent in ("mlp", "shared"):   # MoE shared experts: one MLP
         if leaf in _MLP_COL:
             return -1
         if leaf == "down":
@@ -139,7 +140,7 @@ def _propose(rules: ShardingRules, path) -> int | None:
     if parent == "moe":
         if leaf in ("up", "down", "gate"):
             return -3  # expert dim of (..., E, D, F)
-        return None  # router stays replicated (tiny, feeds top_k)
+        return None  # router (and its bias) replicated: tiny, feeds top_k
     if parent == "ssm":
         if leaf in ("w_z", "w_x") and rules.ssm_heads_ok:
             return -1
@@ -244,6 +245,9 @@ def cache_specs(rules: ShardingRules, cfg, batch: int):
     h_ax = "model" if rules.kv_heads_ok else None
 
     def dense_cache():
+        if cfg.mla is not None:   # latent + shared roped key: no head axis
+            lat = P(None, b_ax, seq_ax, None)
+            return {"c_kv": lat, "k_pe": lat}
         kv = P(None, b_ax, seq_ax, h_ax, None)
         c = {"k": kv, "v": kv}
         if cfg.kv_cache_dtype == "int8":

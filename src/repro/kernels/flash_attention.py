@@ -30,6 +30,11 @@ written in the operands' dtype.
 GQA: with one head per lane block the kv block index is ``h // rep``; at
 Dh < 128 a q head's kv head would sit in other lanes, so the wrapper
 (``ops.flash_attention_refusal``) refuses GQA there.
+
+Values may be narrower than queries and keys (latent attention: q/k heads
+of 192, zero-padded to 256 by the wrapper, and v heads of 128). Then each
+lane block holds one head, of ``head_dim`` lanes in q and k and of
+``v_head_dim`` lanes in v and the output.
 """
 from __future__ import annotations
 
@@ -80,20 +85,22 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, causal, head_dim, block_q):
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool, head_dim: int,
-                           interpret: bool = False):
-    """q: (B, S, Hq*Dh) bf16, pre-scaled; k/v: (B, S, Hkv*Dh) bf16.
+                           v_head_dim: int = None, interpret: bool = False):
+    """q: (B, S, Hq*Dh) bf16, pre-scaled; k: (B, S, Hkv*Dh) bf16; v:
+    (B, S, Hkv*Dv) bf16, ``Dv = v_head_dim`` (default ``head_dim``).
 
-    Returns (B, S, Hq*Dh) in q's dtype. The lane block is
+    Returns (B, S, Hq*Dv) in q's dtype. The lane block is
     ``W = max(128, head_dim)``; the caller guarantees that ``W`` divides both
     feature axes, that S is a multiple of 16 and of ``min(128, S)``, and
-    (when Hkv < Hq) that ``W == head_dim``. The q tile is 256 rows where S
-    allows it: each MXU weight load then serves twice the rows, which beats
-    the extra masked work (0.485 against 0.541 ms at 16 x 32 x 512 x 64 on a
-    v5e).
+    (when Hkv < Hq) that ``W == head_dim``; where Dv differs from Dh, that
+    both are multiples of 128. The q tile is 256 rows where S allows it:
+    each MXU weight load then serves twice the rows, which beats the extra
+    masked work (0.485 against 0.541 ms at 16 x 32 x 512 x 64 on a v5e).
     """
     B, S, Fq = q.shape
     Fk = k.shape[2]
     W = max(128, head_dim)
+    Wv = W if v_head_dim is None else max(128, v_head_dim)
     rep = Fq // Fk                                      # GQA group size
     block_q = 256 if S % 256 == 0 else min(128, S)
 
@@ -109,9 +116,9 @@ def flash_attention_pallas(q, k, v, *, causal: bool, head_dim: int,
         grid=(B, Fq // W),
         in_specs=[pl.BlockSpec((None, S, W), q_map),
                   pl.BlockSpec((None, S, W), kv_map),
-                  pl.BlockSpec((None, S, W), kv_map)],
-        out_specs=pl.BlockSpec((None, S, W), q_map),
-        out_shape=jax.ShapeDtypeStruct((B, S, Fq), q.dtype),
+                  pl.BlockSpec((None, S, Wv), kv_map)],
+        out_specs=pl.BlockSpec((None, S, Wv), q_map),
+        out_shape=jax.ShapeDtypeStruct((B, S, Fq // W * Wv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,

@@ -96,11 +96,16 @@ def tq_plan(K: int, N: int, *, group: int, mode: str) -> TQPlan:
 _FA_MAX_SEQ = 1024
 
 
-def _fa_refusal(q_shape, k_shape) -> str:
+def _fa_refusal(q_shape, k_shape, v_shape=None) -> str:
     _, sq, hq, dh = q_shape
     sk, hkv = k_shape[1], k_shape[2]
+    dv = dh if v_shape is None else v_shape[3]
     if sq != sk:
         return f"query length {sq} != key length {sk}"
+    if dv != dh:
+        if dv % 128:
+            return f"value head_dim {dv} != {dh} and not a multiple of 128"
+        dh = -(-dh // 128) * 128        # q/k heads are zero-padded to lanes
     if dh % 128 and 128 % dh:
         return f"head_dim {dh} neither divides nor is a multiple of 128"
     if (hq * dh) % max(128, dh):
@@ -119,13 +124,15 @@ def _fa_refusal(q_shape, k_shape) -> str:
     return ""
 
 
-def flash_attention_refusal(q_shape, k_shape) -> str:
-    """Why the flash-attention kernel cannot take q (B, S, Hq, Dh) and k
-    (B, S, Hkv, Dh); "" where it can. Its lane block is ``max(128, Dh)``
-    wide: 128 / Dh heads where Dh divides 128, one head where Dh is a
-    multiple of it; its q tile is 256 or 128 rows, or all of a shorter S.
+def flash_attention_refusal(q_shape, k_shape, v_shape=None) -> str:
+    """Why the flash-attention kernel cannot take q (B, S, Hq, Dh), k
+    (B, S, Hkv, Dh) and v (B, S, Hkv, Dv; default Dv = Dh); "" where it can.
+    Its lane block is ``max(128, Dh)`` wide: 128 / Dh heads where Dh divides
+    128, one head where Dh is a multiple of it; where Dv differs, one head
+    of q/k zero-padded to a multiple of 128 lanes and of Dv (a multiple of
+    128) lanes of v; its q tile is 256 or 128 rows, or all of a shorter S.
     A refusal warns once per shape on a TPU, with the reason."""
-    reason = _fa_refusal(q_shape, k_shape)
+    reason = _fa_refusal(q_shape, k_shape, v_shape)
     if reason:
         _fallback("flash_attention", q_shape, reason)
     return reason
@@ -203,20 +210,31 @@ def flash_attention(q, k, v, *, causal: bool):
     reference's at JAX's default matmul precision, one bfloat16 pass, up to
     the output, which is rounded to bfloat16 as the output projection's pass
     rounds it. Forward only; the caller checks ``flash_attention_refusal``
-    first (``layers.flash_attention`` adds the gradient)."""
-    reason = _fa_refusal(q.shape, k.shape)
+    first (``layers.flash_attention`` adds the gradient).
+
+    Values narrower than queries (latent attention: Dh 192, Dv 128): q and k
+    are zero-padded to whole 128-lane blocks, which leaves every score as it
+    is, and the softmax scale stays Dh^-0.5 of the unpadded width."""
+    reason = _fa_refusal(q.shape, k.shape, v.shape)
     if reason:
         raise ValueError(f"flash_attention: shape {q.shape}: {reason}")
     B, S, H, Dh = q.shape
+    Dv = v.shape[3]
     # the MXU operands the float32 reference feeds at default precision; the
     # output is rounded to bfloat16 as the output projection's one pass
     # would round it, which halves its write and the projection's read
     qs = (q.astype(jnp.float32) * Dh ** -0.5).astype(jnp.bfloat16)
+    if Dv == Dh:
+        qs, ks = qs.reshape(B, S, -1), k.astype(jnp.bfloat16).reshape(B, S, -1)
+    else:
+        pad = ((0, 0),) * 3 + ((0, -Dh % 128),)
+        qs = jnp.pad(qs, pad).reshape(B, S, -1)
+        ks = jnp.pad(k.astype(jnp.bfloat16), pad).reshape(B, S, -1)
     out = flash_attention_pallas(
-        qs.reshape(B, S, H * Dh), k.astype(jnp.bfloat16).reshape(B, S, -1),
-        v.astype(jnp.bfloat16).reshape(B, S, -1), causal=causal,
-        head_dim=Dh, interpret=_interpret())
-    return out.reshape(B, S, H, Dh).astype(q.dtype)
+        qs, ks, v.astype(jnp.bfloat16).reshape(B, S, -1), causal=causal,
+        head_dim=qs.shape[2] // H, v_head_dim=None if Dv == Dh else Dv,
+        interpret=_interpret())
+    return out.reshape(B, S, H, Dv).astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_len", "chunk", "use_pallas"))

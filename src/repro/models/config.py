@@ -10,7 +10,7 @@ import dataclasses
 import math
 from typing import Optional, Tuple
 
-__all__ = ["MoEConfig", "SSMConfig", "ModelConfig"]
+__all__ = ["MoEConfig", "MLAConfig", "SSMConfig", "ModelConfig"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -18,12 +18,44 @@ class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25
+    # routing rule: "softmax_topk" (top-k logits, softmax over the chosen) or
+    # "sigmoid_noaux" (DeepSeek-V3 noaux_tc: sigmoid scores, a correction
+    # bias that chooses but does not weight, optional normalization, scaled)
+    router: str = "softmax_topk"
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0         # always-on experts, one MLP of n x d_ff
+    # (start, count) of the routed experts this device holds; None = all.
+    # The router still scores all ``num_experts``; tokens routed elsewhere
+    # add nothing here (expert parallelism without its exchange)
+    experts_held: Optional[Tuple[int, int]] = None
+    dispatch: str = "capacity"        # capacity (drops past the capacity) |
+                                      # grouped (dropless sort + ragged_dot)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
 
     def capacity(self, seq_len: int) -> int:
         """Per-batch-row expert capacity (cumsum/positions are computed per
         row so token dispatch never serializes across the data axis)."""
         c = math.ceil(seq_len * self.top_k / self.num_experts * self.capacity_factor)
         return max(1, c)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without q-LoRA: keys and
+    values are expanded from a normalized latent of ``kv_lora_rank`` plus one
+    shared roped key head of ``qk_rope_head_dim``."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,11 +92,15 @@ class ModelConfig:
     pos_emb: str = "rope"             # rope | learned | none
     rope_theta: float = 10000.0
     norm: str = "rmsnorm"             # rmsnorm | layernorm
+    norm_eps: Optional[float] = None  # None: 1e-6 (rmsnorm), 1e-5 (layernorm)
     tie_embeddings: bool = False
     max_seq_len: int = 8192           # for learned positions / cache default
 
     block_pattern: str = "dense"      # dense | moe | ssm | hybrid
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None   # latent attention in place of GQA
+    first_k_dense: int = 0            # moe: leading dense-FFN blocks (one
+    dense_d_ff: int = 0               # stack of them, FFN width dense_d_ff)
     ssm: Optional[SSMConfig] = None
     hybrid_period: int = 6            # hybrid: every Nth block = shared attn+mlp
 
@@ -119,19 +155,49 @@ class ModelConfig:
         n_attn = self.n_layers // self.hybrid_period
         return self.n_layers - n_attn, n_attn
 
+    def _attn_params(self) -> int:
+        d = self.d_model
+        if self.mla is not None:
+            m, h = self.mla, self.n_heads
+            return (d * h * m.qk_head_dim
+                    + d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank
+                    + m.kv_lora_rank * h * (m.qk_nope_head_dim + m.v_head_dim)
+                    + h * m.v_head_dim * d)
+        hd = self.resolved_head_dim
+        return (d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd)
+                + (self.n_heads * hd) * d)
+
+    def _mlp_params(self, f: int) -> int:
+        d = self.d_model
+        return d * f + f * d + (d * f if self.gated_mlp else 0)
+
+    def _moe_block_params(self, experts: int) -> int:
+        """One MoE block with ``experts`` routed experts counted."""
+        d, m = self.d_model, self.moe
+        router = d * m.num_experts + (m.num_experts if m.router == "sigmoid_noaux"
+                                      else 0)
+        return (self._attn_params() + experts * self._mlp_params(self.d_ff)
+                + self._mlp_params(m.n_shared_experts * self.d_ff)
+                + router + 2 * d)
+
+    def _dense_lead_params(self) -> int:
+        return self.first_k_dense * (self._attn_params()
+                                     + self._mlp_params(self.dense_d_ff)
+                                     + 2 * self.d_model)
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding included once if tied)."""
         d, f, v = self.d_model, self.d_ff, self.padded_vocab
-        hd = self.resolved_head_dim
-        attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) + (self.n_heads * hd) * d
-        mlp = d * f + f * d + (d * f if self.gated_mlp else 0)
+        attn = self._attn_params()
+        mlp = self._mlp_params(f)
         per_dense = attn + mlp + 2 * d
         n = 0
         if self.block_pattern == "dense":
             n += self.n_layers * per_dense
         elif self.block_pattern == "moe":
-            e = self.moe.num_experts
-            n += self.n_layers * (attn + e * mlp + d * e + 2 * d)
+            n += self._dense_lead_params()
+            n += (self.n_layers - self.first_k_dense) * self._moe_block_params(
+                self.moe.held[1])
         elif self.block_pattern == "ssm":
             n += self.n_layers * self._ssm_block_params()
         elif self.block_pattern == "hybrid":
@@ -150,12 +216,9 @@ class ModelConfig:
         """Activated params per token (= param_count for non-MoE)."""
         if self.block_pattern != "moe":
             return self.param_count()
-        d, f = self.d_model, self.d_ff
-        hd = self.resolved_head_dim
-        attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) + (self.n_heads * hd) * d
-        mlp = d * f + f * d + (d * f if self.gated_mlp else 0)
-        k = self.moe.top_k
-        act = self.n_layers * (attn + k * mlp + d * self.moe.num_experts + 2 * d)
+        act = self._dense_lead_params()
+        act += (self.n_layers - self.first_k_dense) * self._moe_block_params(
+            self.moe.top_k)
         act += self.padded_vocab * self.d_model * (1 if self.tie_embeddings else 2)
         return act
 
@@ -185,7 +248,13 @@ class ModelConfig:
             max_seq_len=256,
             frontend_len=8 if self.frontend != "none" else 0,
             encoder_layers=min(self.encoder_layers, 2),
-            moe=dataclasses.replace(self.moe, num_experts=4, top_k=2) if self.moe else None,
+            moe=(dataclasses.replace(self.moe, num_experts=4, top_k=2,
+                                     experts_held=None) if self.moe else None),
+            mla=(MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                           qk_rope_head_dim=8, v_head_dim=16)
+                 if self.mla else None),
+            first_k_dense=min(self.first_k_dense, 1),
+            dense_d_ff=256 if self.dense_d_ff else 0,
             ssm=(dataclasses.replace(self.ssm, d_state=16, head_dim=16,
                                      chunk=32) if self.ssm else None),
             remat=False,

@@ -1,4 +1,5 @@
-"""Shared neural building blocks: norms, RoPE, blocked attention, MLP, MoE."""
+"""Shared neural building blocks: norms, RoPE, blocked attention, latent
+attention (MLA), MLP, MoE."""
 from __future__ import annotations
 
 import functools
@@ -28,10 +29,11 @@ def layernorm(x, w, b, eps=1e-5):
     return (((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype)) * w + b
 
 
-def apply_norm(x, p, kind):
+def apply_norm(x, p, kind, eps=None):
+    """``eps`` None keeps each kind's default (1e-5 layernorm, 1e-6 rms)."""
     if kind == "layernorm":
-        return layernorm(x, p["w"], p["b"])
-    return rmsnorm(x, p["w"])
+        return layernorm(x, p["w"], p["b"], 1e-5 if eps is None else eps)
+    return rmsnorm(x, p["w"], 1e-6 if eps is None else eps)
 
 
 def init_norm(d, kind, dtype):
@@ -75,7 +77,7 @@ def blocked_attention(q, k, v, *, causal: bool, q_offset=0, kv_len=None,
                       chunk: int = 1024, unroll: bool = False,
                       softmax_dtype=jnp.float32, repeat_kv: bool = False,
                       k_scale=None, v_scale=None):
-    """q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh). Returns (B, Sq, Hq, Dh).
+    """q/k: (B, S, H, Dh); v: (B, Sk, Hkv, Dv). Returns (B, Sq, Hq, Dv).
 
     Streams KV in chunks with an online softmax (flash-attention recurrence),
     so the (Sq, Sk) logit matrix is never materialised — required for the
@@ -83,7 +85,7 @@ def blocked_attention(q, k, v, *, causal: bool, q_offset=0, kv_len=None,
     ``kv_len`` masks cache positions >= kv_len.
     """
     B, Sq, Hq, Dh = q.shape
-    Sk, Hkv = k.shape[1], k.shape[2]
+    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     if repeat_kv and Hkv < Hq:
         # §Perf: keep scores HEAD-SHARDED under TP — the (Hkv, rep) reshape of
         # a head-sharded q axis defeats GSPMD propagation; repeating KV to Hq
@@ -115,7 +117,7 @@ def blocked_attention(q, k, v, *, causal: bool, q_offset=0, kv_len=None,
     if kv_len is None:
         kv_len = Sk
     kc = k.reshape(B, n_chunks, chunk, Hkv, Dh).swapaxes(0, 1)
-    vc = v.reshape(B, n_chunks, chunk, Hkv, Dh).swapaxes(0, 1)
+    vc = v.reshape(B, n_chunks, chunk, Hkv, Dv).swapaxes(0, 1)
     scale_xs = None
     if k_scale is not None:  # int8 KV cache: per-(pos, head) absmax scales
         scale_xs = (k_scale.reshape(B, n_chunks, chunk, Hkv).swapaxes(0, 1),
@@ -152,12 +154,12 @@ def blocked_attention(q, k, v, *, causal: bool, q_offset=0, kv_len=None,
 
     m0 = jnp.full((B, Hkv, rep, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Hkv, rep, Sq), jnp.float32)
-    a0 = jnp.zeros((B, Hkv, rep, Sq, Dh), jnp.float32)
+    a0 = jnp.zeros((B, Hkv, rep, Sq, Dv), jnp.float32)
     starts = jnp.arange(n_chunks) * chunk
     xs = (kc, vc) + (scale_xs if scale_xs is not None else ()) + (starts,)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), xs, unroll=unroll)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, Dh).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, Dv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +311,7 @@ def _full_attention(q, k, v, causal, kw):
     flash = (ops.on_tpu() and kw["softmax_dtype"] == jnp.float32
              and jax.config.jax_default_matmul_precision in _ONE_BF16_PASS
              and ops.one_device_program()
-             and not ops.flash_attention_refusal(q.shape, k.shape))
+             and not ops.flash_attention_refusal(q.shape, k.shape, v.shape))
     obs.counter("attention_path_total",
                 "Traced no-cache attention calls, by kernel path").inc(
                     path="flash" if flash else "blocked")
@@ -352,6 +354,79 @@ def _flash_decode_attention(cfg: ModelConfig, q, cache, kv_len):
     out = flash_decode(q[:, 0], k, v, ks, vs, kv_len=kv_len_static,
                        chunk=min(512, k.shape[1]))
     return out[:, None].astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V3, no q-LoRA)
+# ---------------------------------------------------------------------------
+
+def init_mla(key, cfg: ModelConfig, dtype):
+    d, h, m = cfg.d_model, cfg.n_heads, cfg.mla
+    r = m.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": jax.random.normal(ks[0], (d, h * m.qk_head_dim), dtype) * d ** -0.5,
+        "wkv_a": jax.random.normal(
+            ks[1], (d, r + m.qk_rope_head_dim), dtype) * d ** -0.5,
+        "kv_norm": {"w": jnp.ones((r,), dtype)},
+        "wkv_b": jax.random.normal(
+            ks[2], (r, h * (m.qk_nope_head_dim + m.v_head_dim)), dtype) * r ** -0.5,
+        "wo": jax.random.normal(ks[3], (h * m.v_head_dim, d), dtype)
+        * (h * m.v_head_dim) ** -0.5,
+    }
+
+
+def mla_latent(p, cfg: ModelConfig, x, positions):
+    """What MLA caches per token: the normalized latent ``c_kv`` (B, S, R)
+    and the one roped key head ``k_pe`` (B, S, Dr) all heads share."""
+    m = cfg.mla
+    r = m.kv_lora_rank
+    kv_a = x @ p["wkv_a"]
+    c_kv = apply_norm(kv_a[..., :r], p["kv_norm"], "rmsnorm", cfg.norm_eps)
+    cos, sin = rope_cos_sin(positions, m.qk_rope_head_dim, cfg.rope_theta)
+    k_pe = apply_rope(kv_a[..., None, r:], cos, sin)[:, :, 0]
+    return c_kv, k_pe
+
+
+def mla_attention(p, cfg: ModelConfig, x, positions, *, causal=True,
+                  cache=None, cache_index=None):
+    """MLA block body (no norm / residual): q = x W_q (heads of nope + rope),
+    [c_kv, k_pe] = x W_kva, c_kv normalized, [k_nope, v] = c_kv W_kvb, rope
+    on q_pe and on the shared k_pe, softmax scale (nope + rope)^-0.5.
+
+    cache: optional {"c_kv": (B, Smax, R), "k_pe": (B, Smax, Dr)} written at
+    ``cache_index``; keys and values are expanded from the whole latent
+    cache through W_kvb."""
+    B, S, _ = x.shape
+    m, H = cfg.mla, cfg.n_heads
+    nope, rope = m.qk_nope_head_dim, m.qk_rope_head_dim
+    q = (x @ p["wq"]).reshape(B, S, H, m.qk_head_dim)
+    cos, sin = rope_cos_sin(positions, rope, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], apply_rope(q[..., nope:], cos, sin)],
+                        axis=-1)
+    c_kv, k_pe = mla_latent(p, cfg, x, positions)
+    new_cache = None
+    if cache is not None:
+        def upd(buf, val):
+            return jax.lax.dynamic_update_slice_in_dim(
+                buf, val.astype(buf.dtype), cache_index, axis=1)
+        new_cache = {"c_kv": upd(cache["c_kv"], c_kv),
+                     "k_pe": upd(cache["k_pe"], k_pe)}
+        c_kv, k_pe = new_cache["c_kv"], new_cache["k_pe"]
+    Sk = c_kv.shape[1]
+    kv = (c_kv @ p["wkv_b"]).reshape(B, Sk, H, nope + m.v_head_dim)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe[:, :, None], (B, Sk, H, rope))],
+        axis=-1)
+    v = kv[..., nope:]
+    kw = dict(chunk=cfg.attn_chunk, unroll=cfg.unroll_inner,
+              softmax_dtype=jnp.dtype(cfg.attn_softmax_dtype))
+    if cache is not None:
+        out = blocked_attention(q, k, v, causal=causal, q_offset=cache_index,
+                                kv_len=cache_index + S, **kw)
+    else:
+        out = _full_attention(q, k, v, causal, kw)
+    return attn_out(p, out, cfg), new_cache
 
 
 def cross_attention(p, cfg: ModelConfig, x, enc_kv):
@@ -417,62 +492,171 @@ def mlp(p, cfg: ModelConfig, x):
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k routing, per-row capacity, scatter dispatch -> EP all-to-all)
+# MoE: routing rules, capacity or dropless grouped dispatch, shared experts
 # ---------------------------------------------------------------------------
 
 def init_moe(key, cfg: ModelConfig, dtype):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
-    ks = jax.random.split(key, 4)
+    """Router over all ``num_experts``; expert weights only for the experts
+    held here (``moe.held``); the shared experts as one MLP."""
+    d, f, m = cfg.d_model, cfg.d_ff, cfg.moe
+    e = m.held[1]
+    ks = jax.random.split(key, 5 if m.n_shared_experts else 4)
     p = {
-        "router": jax.random.normal(ks[0], (d, e), dtype) * d ** -0.5,
+        "router": jax.random.normal(ks[0], (d, m.num_experts), dtype) * d ** -0.5,
         "up": jax.random.normal(ks[1], (e, d, f), dtype) * d ** -0.5,
         "down": jax.random.normal(ks[2], (e, f, d), dtype) * f ** -0.5,
     }
     if cfg.gated_mlp:
         p["gate"] = jax.random.normal(ks[3], (e, d, f), dtype) * d ** -0.5
+    if m.router == "sigmoid_noaux":   # e_score_correction_bias
+        p["router_bias"] = jnp.zeros((m.num_experts,), dtype)
+    if m.n_shared_experts:
+        p["shared"] = init_mlp(ks[4], cfg, dtype, d_ff=m.n_shared_experts * f)
     return p
 
 
+def moe_route(p, cfg: ModelConfig, x):
+    """(weights, expert ids), each (B, S, top_k), over all ``num_experts``.
+
+    ``softmax_topk``: the top-k router logits, softmaxed among themselves.
+    ``sigmoid_noaux`` (DeepSeek-V3 ``noaux_tc``, one group): float32 logits at
+    HIGHEST precision, scores s = sigmoid(logits); the experts are the top-k
+    of s + router_bias, weighted by their s alone, normalized to sum 1 where
+    ``norm_topk_prob``, times ``routed_scaling_factor``."""
+    m = cfg.moe
+    if m.router == "sigmoid_noaux":
+        logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32),
+                               m.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if m.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return (w * m.routed_scaling_factor).astype(x.dtype), idx
+    logits = x @ p["router"]                       # (B, S, E)
+    gate_w, gate_idx = jax.lax.top_k(logits, m.top_k)
+    gate_w = jax.nn.softmax(gate_w.astype(jnp.float32), axis=-1).astype(x.dtype)
+    return gate_w, gate_idx
+
+
 def moe_ffn(p, cfg: ModelConfig, x):
-    """x: (B, S, D). Positions/capacity computed PER ROW so the token cumsum
-    never crosses the data-sharded batch axis (no serializing collectives);
-    the (B, E, C, D) dispatch buffer resharded b:data -> e:model is the
-    all-to-all under expert parallelism.
-    """
+    """x: (B, S, D) -> the routed experts held here, weighted, plus the
+    shared experts. Scopes ``moe.router``, ``moe.dispatch``,
+    ``moe.experts``, ``moe.shared``; ``moe_dispatch_total{path}`` counts the
+    dispatch once per trace."""
+    with jax.named_scope("moe.router"):
+        gate_w, gate_idx = moe_route(p, cfg, x)
+    path = cfg.moe.dispatch
+    obs.counter("moe_dispatch_total",
+                "Traced MoE layer calls, by dispatch path").inc(path=path)
+    if path == "grouped":
+        out = _grouped_experts(p, cfg, x, gate_w, gate_idx)
+    else:
+        out = _capacity_experts(p, cfg, x, gate_w, gate_idx)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + mlp(p["shared"], cfg, x)
+    return out
+
+
+def _capacity_experts(p, cfg: ModelConfig, x, gate_w, gate_idx):
+    """Positions/capacity computed PER ROW so the token cumsum never crosses
+    the data-sharded batch axis (no serializing collectives); the
+    (B, E, C, D) dispatch buffer resharded b:data -> e:model is the
+    all-to-all under expert parallelism. Slots past the capacity drop."""
     B, S, D = x.shape
     mcfg = cfg.moe
     E, K = mcfg.num_experts, mcfg.top_k
+    if mcfg.held != (0, E):
+        raise ValueError("capacity dispatch computes every expert; a device "
+                         "holding a share of them needs dispatch='grouped'")
     C = mcfg.capacity(S)
     act = activation_fn(cfg.activation)
 
-    logits = x @ p["router"]                       # (B, S, E)
-    gate_w, gate_idx = jax.lax.top_k(logits, K)    # (B, S, K)
-    gate_w = jax.nn.softmax(gate_w.astype(jnp.float32), axis=-1).astype(x.dtype)
+    with jax.named_scope("moe.dispatch"):
+        # slot layout: (B, S*K)
+        e_idx = gate_idx.reshape(B, S * K)
+        onehot = jax.nn.one_hot(e_idx, E, dtype=jnp.int32)          # (B, S*K, E)
+        pos_all = jnp.cumsum(onehot, axis=1) - 1                     # (B, S*K, E)
+        pos = jnp.take_along_axis(pos_all, e_idx[..., None], axis=2)[..., 0]
+        keep = (pos < C)
+        pos_c = jnp.clip(pos, 0, C - 1)
 
-    # slot layout: (B, S*K)
-    e_idx = gate_idx.reshape(B, S * K)
-    onehot = jax.nn.one_hot(e_idx, E, dtype=jnp.int32)          # (B, S*K, E)
-    pos_all = jnp.cumsum(onehot, axis=1) - 1                     # (B, S*K, E)
-    pos = jnp.take_along_axis(pos_all, e_idx[..., None], axis=2)[..., 0]
-    keep = (pos < C)
-    pos_c = jnp.clip(pos, 0, C - 1)
+        x_slots = jnp.repeat(x, K, axis=1)                           # (B, S*K, D)
+        x_slots = x_slots * keep[..., None].astype(x.dtype)
+        b_iota = jnp.arange(B)[:, None] * jnp.ones((1, S * K), jnp.int32)
+        buf = jnp.zeros((B, E, C, D), x.dtype).at[b_iota, e_idx, pos_c].add(x_slots)
 
-    x_slots = jnp.repeat(x, K, axis=1)                           # (B, S*K, D)
-    x_slots = x_slots * keep[..., None].astype(x.dtype)
-    b_iota = jnp.arange(B)[:, None] * jnp.ones((1, S * K), jnp.int32)
-    buf = jnp.zeros((B, E, C, D), x.dtype).at[b_iota, e_idx, pos_c].add(x_slots)
+    with jax.named_scope("moe.experts"):
+        up = jnp.einsum("becd,edf->becf", buf, p["up"])
+        if cfg.gated_mlp:
+            g = jnp.einsum("becd,edf->becf", buf, p["gate"])
+            h = act(g) * up
+        else:
+            h = act(up)
+        out_buf = jnp.einsum("becf,efd->becd", h, p["down"])
 
-    up = jnp.einsum("becd,edf->becf", buf, p["up"])
-    if cfg.gated_mlp:
-        g = jnp.einsum("becd,edf->becf", buf, p["gate"])
-        h = act(g) * up
-    else:
-        h = act(up)
-    out_buf = jnp.einsum("becf,efd->becd", h, p["down"])
+    with jax.named_scope("moe.dispatch"):
+        out_slots = out_buf[b_iota, e_idx, pos_c] * keep[..., None].astype(x.dtype)
+        out = out_slots.reshape(B, S, K, D) * gate_w[..., None]
+        return jnp.sum(out, axis=2)
 
-    out_slots = out_buf[b_iota, e_idx, pos_c] * keep[..., None].astype(x.dtype)
-    out = out_slots.reshape(B, S, K, D) * gate_w[..., None]
-    return jnp.sum(out, axis=2)
+
+def _mxu_operands(*xs):
+    """The operands a float32 matmul feeds the MXU on a TPU at JAX's default
+    precision (one bfloat16 pass), made explicit for ``ragged_dot``, whose
+    kernel would otherwise multiply float32 in several passes. Unchanged
+    elsewhere and at higher precisions."""
+    from repro.kernels import ops  # local import: kernels are optional
+    if ops.on_tpu() and jax.config.jax_default_matmul_precision in _ONE_BF16_PASS:
+        return tuple(x.astype(jnp.bfloat16) for x in xs)
+    return xs
+
+
+def _dispatch_rows(tokens: int, top_k: int, held: int) -> int:
+    """Rows of the sorted slot buffer: the most slots ``held`` experts can be
+    sent (each token picks ``top_k`` distinct experts)."""
+    return tokens * min(top_k, held)
+
+
+def _grouped_experts(p, cfg: ModelConfig, x, gate_w, gate_idx):
+    """Dropless dispatch to the experts held here: the token slots routed to
+    them are sorted by expert and run through one grouped matmul
+    (``jax.lax.ragged_dot``) per projection; slots routed to other experts
+    add nothing. The sorted buffer holds ``T * min(top_k, held)`` rows, the
+    most the held experts can be sent, so no slot is ever dropped."""
+    B, S, D = x.shape
+    m = cfg.moe
+    K = m.top_k
+    start, count = m.held
+    T = B * S
+    M = _dispatch_rows(T, K, count)
+    act = activation_fn(cfg.activation)
+    with jax.named_scope("moe.dispatch"):
+        local = gate_idx.reshape(T * K) - start
+        held = (local >= 0) & (local < count)
+        group = jnp.where(held, local, count)         # others sort last
+        order = jnp.argsort(group, stable=True)[:M]
+        sizes = jnp.bincount(group, length=count + 1)[:count]
+        live = held[order]
+        tok = order // K
+        xs = jnp.take(x.reshape(T, D), tok, axis=0)
+        ws = jnp.where(live, gate_w.reshape(T * K)[order], 0)
+
+    def gmm(lhs, rhs):
+        lhs, rhs = _mxu_operands(lhs, rhs)
+        return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    with jax.named_scope("moe.experts"):
+        up = gmm(xs, p["up"])
+        h = act(gmm(xs, p["gate"])) * up if cfg.gated_mlp else act(up)
+        y = gmm(h, p["down"])
+    with jax.named_scope("moe.dispatch"):
+        y = jnp.where(live[:, None], y * ws[:, None], 0)
+        out = jnp.zeros((T, D), jnp.float32).at[tok].add(y)
+        return out.reshape(B, S, D).astype(x.dtype)
 
 
 def moe_aux_loss(p, cfg: ModelConfig, x):

@@ -19,6 +19,7 @@ from repro.models import ssm as SSM
 __all__ = [
     "init_params", "forward", "lm_loss", "init_cache", "decode_step",
     "prefill", "dequant_tree", "lm_head_logits", "quantizable_paths",
+    "n_lead_blocks",
 ]
 
 
@@ -40,17 +41,21 @@ def dequant_tree(tree, dtype=None):
 # Init
 # ---------------------------------------------------------------------------
 
-def _init_dense_block(key, cfg: ModelConfig, dt, cross=False):
+def _init_dense_block(key, cfg: ModelConfig, dt, cross=False, lead=False):
+    """One attention + FFN block; ``lead``: a leading dense block of a MoE
+    stack (FFN width ``dense_d_ff``)."""
     ks = jax.random.split(key, 4)
     p = {
         "ln1": L.init_norm(cfg.d_model, cfg.norm, dt),
-        "attn": L.init_attn(ks[0], cfg, dt),
+        "attn": (L.init_mla(ks[0], cfg, dt) if cfg.mla is not None
+                 else L.init_attn(ks[0], cfg, dt)),
         "ln2": L.init_norm(cfg.d_model, cfg.norm, dt),
     }
-    if cfg.block_pattern == "moe" and not cross:
+    if cfg.block_pattern == "moe" and not cross and not lead:
         p["moe"] = L.init_moe(ks[1], cfg, dt)
     else:
-        p["mlp"] = L.init_mlp(ks[1], cfg, dt)
+        p["mlp"] = L.init_mlp(ks[1], cfg, dt,
+                              d_ff=cfg.dense_d_ff if lead else None)
     if cross:
         p["ln_x"] = L.init_norm(cfg.d_model, cfg.norm, dt)
         p["xattn"] = L.init_attn(ks[2], cfg, dt)
@@ -65,6 +70,12 @@ def _stack_init(init_fn, key, n):
     return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
+def n_lead_blocks(cfg: ModelConfig) -> int:
+    """Leading dense blocks of a MoE stack (``params["dense_blocks"]``),
+    run before the scanned ``params["blocks"]``."""
+    return cfg.first_k_dense if cfg.block_pattern == "moe" else 0
+
+
 def init_params(key, cfg: ModelConfig):
     dt = _dtype(cfg)
     keys = jax.random.split(key, 8)
@@ -75,8 +86,14 @@ def init_params(key, cfg: ModelConfig):
 
     if cfg.block_pattern in ("dense", "moe"):
         cross = cfg.is_enc_dec
+        n_lead = n_lead_blocks(cfg)
         params["blocks"] = _stack_init(
-            lambda k: _init_dense_block(k, cfg, dt, cross=cross), keys[2], cfg.n_layers)
+            lambda k: _init_dense_block(k, cfg, dt, cross=cross), keys[2],
+            cfg.n_layers - n_lead)
+        if n_lead:
+            params["dense_blocks"] = _stack_init(
+                lambda k: _init_dense_block(k, cfg, dt, lead=True), keys[6],
+                n_lead)
     elif cfg.block_pattern == "ssm":
         params["blocks"] = _stack_init(lambda k: _init_ssm_block(k, cfg, dt), keys[2], cfg.n_layers)
     elif cfg.block_pattern == "hybrid":
@@ -105,18 +122,22 @@ def init_params(key, cfg: ModelConfig):
 def _dense_body(pl, cfg: ModelConfig, h, positions, cache=None, cache_index=0,
                 enc_h=None, causal=True):
     pl = dequant_tree(pl, jnp.dtype(cfg.compute_dtype))
-    a_in = L.apply_norm(h, pl["ln1"], cfg.norm)
+    a_in = L.apply_norm(h, pl["ln1"], cfg.norm, cfg.norm_eps)
+    attention = L.mla_attention if cfg.mla is not None else L.self_attention
     with jax.named_scope("attn"):
-        a, new_cache = L.self_attention(pl["attn"], cfg, a_in, positions, causal=causal,
-                                        cache=cache, cache_index=cache_index)
+        a, new_cache = attention(pl["attn"], cfg, a_in, positions, causal=causal,
+                                 cache=cache, cache_index=cache_index)
     h = h + a
     if "xattn" in pl and enc_h is not None:
         x_in = L.apply_norm(h, pl["ln_x"], cfg.norm)
         kv = L.cross_kv(pl["xattn"], cfg, enc_h)
         h = h + L.cross_attention(pl["xattn"], cfg, x_in, kv)
-    m_in = L.apply_norm(h, pl["ln2"], cfg.norm)
-    with jax.named_scope("ffn"):
-        f = L.moe_ffn(pl["moe"], cfg, m_in) if "moe" in pl else L.mlp(pl["mlp"], cfg, m_in)
+    m_in = L.apply_norm(h, pl["ln2"], cfg.norm, cfg.norm_eps)
+    if "moe" in pl:   # scopes moe.router / .dispatch / .experts / .shared
+        f = L.moe_ffn(pl["moe"], cfg, m_in)
+    else:
+        with jax.named_scope("ffn"):
+            f = L.mlp(pl["mlp"], cfg, m_in)
     return h + f, new_cache
 
 
@@ -191,7 +212,7 @@ def lm_head_logits(params, cfg: ModelConfig, h, *, mask_vocab: bool = False):
     ``mask_vocab=True`` sets padded-vocab columns to -inf (the serving steps'
     convention before argmax/sampling).
     """
-    h = L.apply_norm(h, params["final_norm"], cfg.norm)
+    h = L.apply_norm(h, params["final_norm"], cfg.norm, cfg.norm_eps)
     head = params["embed"]["tok"].T if cfg.tie_embeddings else params["lm_head"]
     if isinstance(head, QTensor):
         head = head.dequantize(h.dtype)
@@ -237,8 +258,13 @@ def forward(params, cfg: ModelConfig, tokens, *, enc_embeds=None, vision_embeds=
             def body(carry, pl):
                 out, _ = _dense_body(pl, cfg, carry, positions, enc_h=enc_h)
                 return out, out if collect_hidden else None
+            if n_lead_blocks(cfg):
+                h, hidden0 = jax.lax.scan(_maybe_remat(body, cfg), h,
+                                          params["dense_blocks"])
             h, hidden = jax.lax.scan(_maybe_remat(body, cfg), h, params["blocks"],
                                      unroll=cfg.unroll_layers)
+            if n_lead_blocks(cfg) and collect_hidden:
+                hidden = jnp.concatenate([hidden0, hidden])
         elif cfg.block_pattern == "ssm":
             def body(carry, pl):
                 out, _ = _ssm_body(pl, cfg, carry)
@@ -309,6 +335,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
     hd = cfg.resolved_head_dim
 
     def kv(n_l):
+        if cfg.mla is not None:   # MLA caches the latent and the roped key
+            m = cfg.mla
+            return {"c_kv": jnp.zeros((n_l, batch, max_len, m.kv_lora_rank), dt),
+                    "k_pe": jnp.zeros((n_l, batch, max_len, m.qk_rope_head_dim),
+                                      dt)}
         if cfg.kv_cache_dtype == "int8":
             return {
                 "k": jnp.zeros((n_l, batch, max_len, cfg.n_kv_heads, hd), jnp.int8),
@@ -363,9 +394,7 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, index):
                                      unroll=cfg.unroll_layers)
             new_cache = {**new_kv, "cross": cross}
         else:
-            h, new_kv = jax.lax.scan(body, h, (params["blocks"], kv_slices),
-                                     unroll=cfg.unroll_layers)
-            new_cache = new_kv
+            h, new_cache = _scan_cached(body, h, params, kv_slices, cfg)
     elif cfg.block_pattern == "ssm":
         def body(carry, xs):
             pl, st = xs
@@ -378,6 +407,22 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, index):
         raise ValueError(cfg.block_pattern)
 
     return lm_head_logits(params, cfg, h), new_cache
+
+
+def _scan_cached(body, h, params, cache, cfg: ModelConfig):
+    """Scan ``body(carry, (block params, block cache))`` over the stack; a MoE
+    stack's leading dense blocks take the first ``n_lead_blocks`` layers of
+    every cache leaf, the scanned blocks the rest."""
+    n = n_lead_blocks(cfg)
+    if not n:
+        return jax.lax.scan(body, h, (params["blocks"], cache),
+                            unroll=cfg.unroll_layers)
+    h, c0 = jax.lax.scan(body, h, (params["dense_blocks"],
+                                   jax.tree.map(lambda x: x[:n], cache)))
+    h, c1 = jax.lax.scan(body, h, (params["blocks"],
+                                   jax.tree.map(lambda x: x[n:], cache)),
+                         unroll=cfg.unroll_layers)
+    return h, jax.tree.map(lambda a, b: jnp.concatenate([a, b]), c0, c1)
 
 
 def _hybrid_decode(params, cfg: ModelConfig, h, positions, cache, index):
@@ -456,8 +501,7 @@ def prefill(params, cfg: ModelConfig, tokens, max_len: int, *, enc_embeds=None,
                 pl, c = xs
                 out, nc = _dense_body(pl, cfg, carry, positions, cache=c, cache_index=0)
                 return out, nc
-            h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache),
-                                        unroll=cfg.unroll_layers)
+            h, new_cache = _scan_cached(body, h, params, cache, cfg)
     elif cfg.block_pattern == "ssm":
         # full-sequence forward capturing each layer's final SSD + conv state
         def body(carry, xs):
@@ -523,7 +567,8 @@ def _hybrid_prefill(params, cfg: ModelConfig, h, positions, max_len: int):
 # Quantizable-leaf selection
 # ---------------------------------------------------------------------------
 
-_QUANT_KEYS = ("wq", "wk", "wv", "wo", "up", "gate", "down", "w_z", "w_x", "out_proj")
+_QUANT_KEYS = ("wq", "wk", "wv", "wo", "wkv_a", "wkv_b", "up", "gate", "down",
+               "w_z", "w_x", "out_proj")
 _SKIP_SUBSTR = ("embed", "ln", "norm", "router", "conv", "bias")
 
 

@@ -111,6 +111,17 @@ def _tree_update(tree, i, new):
     return jax.tree.map(lambda x, n: x.at[i].set(n), tree, new)
 
 
+def _elite_history(histories, best_loss):
+    """The history that reached ``best_loss``: the first of ``histories``
+    with an accepted entry at that loss. A migration can bring an island
+    the best state another island reached; the returned history is then
+    that island's, so it describes the state the search returns."""
+    for h in histories:
+        if any(acc and loss == best_loss for _, loss, _, _, acc in h):
+            return h
+    return histories[0]
+
+
 def _live_bytes() -> int:
     return int(sum(getattr(a, "nbytes", 0) for a in jax.live_arrays()))
 
@@ -202,6 +213,10 @@ def _run_engine(
         transforms0 = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (adapter.n_units,) + x.shape).copy(), t0)
         fq0 = jax.vmap(lambda b: adapter.quant_unit(b, qcfg))(base)
+        # an adapter may build the stack as a copy (MoEAdapter
+        # concatenates its unit kinds); the step programs rebuild it inside
+        # their own jit, so free it for the run
+        del base
 
         # reference forward (FP model) on the FULL calibration batch; per-island
         # slices view into these (batch axis 0 for tokens/logits, axis 1 for the
@@ -540,7 +555,8 @@ def _run_engine(
     return SearchResult(
         params_q=adapter.install(params_base, elite.best_fq),
         transforms=elite.best_transforms,
-        history=elite.history,
+        history=_elite_history([elite.history] + [s.history for s in islands],
+                               elite.best_loss),
         accept_rate=elite.n_accept / max(scfg.steps, 1),
         final_loss=elite.best_loss,
         initial_loss=loss0s[elite.index],
@@ -757,7 +773,7 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fn,
     return SearchResult(
         params_q=adapter.install(params_base, best_fq),
         transforms=best_t,
-        history=histories[elite],
+        history=_elite_history([histories[elite]] + histories, best[elite]),
         accept_rate=n_accept[elite] / max(scfg.steps, 1),
         final_loss=best[elite],
         initial_loss=loss0s[elite],

@@ -28,6 +28,7 @@ from repro.kernels import paged_decode
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.models.model import dequant_tree, embed_tokens, lm_head_logits
+from repro.serving.paged_cache import refuse_unpaged
 
 __all__ = ["make_paged_decode_step", "make_paged_logits_step",
            "paged_attention_block",
@@ -159,9 +160,7 @@ def make_paged_logits_step(cfg: ModelConfig, *, use_pallas: bool = True,
     """(params_q, tokens (B,1), pools, block_tables (B,P), seq_lens (B,))
     -> (logits (B, 1, V) vocab-masked, updated pools): the layer stack every
     decode step below runs before it selects a token."""
-    if cfg.block_pattern not in ("dense", "moe"):
-        raise ValueError(f"paged decode requires attention blocks, "
-                         f"got {cfg.block_pattern!r}")
+    refuse_unpaged(cfg, "paged decode")
     if cfg.is_enc_dec:
         raise ValueError("paged decode does not cover cross-attention caches")
 

@@ -237,6 +237,22 @@ class PrefixCache:
         self._runs.clear()
 
 
+def refuse_unpaged(cfg: ModelConfig, what: str) -> None:
+    """Raise where the paged serving path cannot hold ``cfg``'s cache: a
+    block pattern with no attention cache, or latent attention (MLA), whose
+    per-token cache is a latent (``kv_lora_rank`` + ``qk_rope_head_dim``)
+    that no paged pool here stores (the pools hold per-head K and V)."""
+    if cfg.block_pattern not in ("dense", "moe"):
+        raise ValueError(f"{what} requires attention blocks, "
+                         f"got {cfg.block_pattern!r}")
+    if cfg.mla is not None:
+        raise ValueError(
+            f"{what}: latent attention (MLA) needs a latent paged cache "
+            f"({cfg.mla.kv_lora_rank} + {cfg.mla.qk_rope_head_dim} values a "
+            f"token a layer), which the paged pools do not have; they hold "
+            f"per-head K/V pages")
+
+
 class PagedKVCache:
     """Device page pools for every layer plus the page allocator.
 
@@ -251,6 +267,7 @@ class PagedKVCache:
             raise ValueError(
                 f"paged KV cache requires an attention cache; "
                 f"block_pattern={cfg.block_pattern!r} keeps O(1) state")
+        refuse_unpaged(cfg, "paged KV cache")
         self.cfg = cfg
         self.page_size = page_size
         self.n_pages = n_pages

@@ -36,6 +36,7 @@ from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.models.model import embed_tokens, lm_head_logits
 from repro.serving.decode import paged_block_body
+from repro.serving.paged_cache import refuse_unpaged
 
 __all__ = ["paged_prefill_attention", "make_paged_prefill_step",
            "run_prefill_chunks"]
@@ -180,9 +181,7 @@ def make_paged_prefill_step(cfg: ModelConfig):
     positions write garbage into the chunk's own allocated pages (masked at
     every later read by causality / per-sequence lengths).
     """
-    if cfg.block_pattern not in ("dense", "moe"):
-        raise ValueError(f"paged prefill requires attention blocks, "
-                         f"got {cfg.block_pattern!r}")
+    refuse_unpaged(cfg, "paged prefill")
     if cfg.is_enc_dec:
         raise ValueError("paged prefill does not cover cross-attention caches")
 

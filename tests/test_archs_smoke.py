@@ -83,10 +83,9 @@ def test_param_count_sane():
         "internlm2-1.8b": 1.8e9, "qwen3-4b": 4e9, "yi-6b": 6e9,
         "command-r-35b": 35e9, "mamba2-2.7b": 2.7e9, "zamba2-7b": 7e9,
         "phi3.5-moe-42b-a6.6b": 42e9,
-        # the ASSIGNED moonshot config (48L x 64e x d_ff 1408) arithmetically
-        # holds ~28B total params — more than the 16B nameplate (the real
-        # Moonlight has 27 layers); we follow the assignment spec verbatim.
-        "moonshot-v1-16b-a3b": 28e9,
+        # the published Moonlight-16B-A3B: 27 layers (one dense, 26 MoE with
+        # 64 routed + 2 shared experts), MLA, vocab 163,840
+        "moonshot-v1-16b-a3b": 16e9,
     }
     for arch, n in expect.items():
         got = get_config(arch).param_count()
@@ -98,3 +97,7 @@ def test_moe_active_params():
     active = cfg.active_param_count()
     assert 4e9 < active < 9e9  # nameplate: 6.6B active
     assert active < cfg.param_count() / 3
+    # Moonlight-16B-A3B: about 3B active (6 of 64 routed experts a token,
+    # the 2 shared experts, MLA, the dense layer, embedding and head)
+    cfg = get_config("moonshot-v1-16b-a3b")
+    assert 2.5e9 < cfg.active_param_count() < 3.5e9
