@@ -620,42 +620,84 @@ def _dispatch_rows(tokens: int, top_k: int, held: int) -> int:
     return tokens * min(top_k, held)
 
 
+def _dispatch_ladder(tokens: int, top_k: int, held: int,
+                     num_experts: int) -> tuple[int, ...]:
+    """Static row bounds of the sorted slot buffer, smallest first: 1.25x
+    the held experts' even share of the slots rounded up to 512 rows, twice
+    that, and ``_dispatch_rows``, each clipped to the last, duplicates
+    dropped. The last bound drops no slot."""
+    top = _dispatch_rows(tokens, top_k, held)
+    even = -(-5 * tokens * top_k * held // (4 * num_experts))
+    low = -(-even // 512) * 512
+    return tuple(sorted({min(r, top) for r in (low, 2 * low, top)}))
+
+
 def _grouped_experts(p, cfg: ModelConfig, x, gate_w, gate_idx):
     """Dropless dispatch to the experts held here: the token slots routed to
     them are sorted by expert and run through one grouped matmul
     (``jax.lax.ragged_dot``) per projection; slots routed to other experts
-    add nothing. The sorted buffer holds ``T * min(top_k, held)`` rows, the
-    most the held experts can be sent, so no slot is ever dropped."""
+    add nothing.
+
+    The sorted buffer takes the smallest bound of ``_dispatch_ladder`` that
+    holds every live slot, picked on the device by ``lax.switch``, each
+    branch under the scope ``moe.rows_<M>``; rows past the live ones add
+    exact zeros, so every bound gives the same result. The largest bound
+    is the most the held experts can be sent, so no slot is ever dropped.
+    One bound (all experts held, or small shapes) emits no switch. Under
+    ``vmap`` the switch becomes a select that runs every branch; the search
+    evaluates its candidates under ``lax.map``, which keeps it a
+    conditional. ``moe_dispatch_rows_total{rows}`` counts each traced
+    bound."""
     B, S, D = x.shape
     m = cfg.moe
     K = m.top_k
     start, count = m.held
     T = B * S
-    M = _dispatch_rows(T, K, count)
+    rungs = _dispatch_ladder(T, K, count, m.num_experts)
     act = activation_fn(cfg.activation)
     with jax.named_scope("moe.dispatch"):
         local = gate_idx.reshape(T * K) - start
         held = (local >= 0) & (local < count)
         group = jnp.where(held, local, count)         # others sort last
-        order = jnp.argsort(group, stable=True)[:M]
+        order = jnp.argsort(group, stable=True)
         sizes = jnp.bincount(group, length=count + 1)[:count]
-        live = held[order]
-        tok = order // K
-        xs = jnp.take(x.reshape(T, D), tok, axis=0)
-        ws = jnp.where(live, gate_w.reshape(T * K)[order], 0)
 
     def gmm(lhs, rhs):
         lhs, rhs = _mxu_operands(lhs, rhs)
         return jax.lax.ragged_dot(lhs, rhs, sizes,
                                   preferred_element_type=jnp.float32)
 
-    with jax.named_scope("moe.experts"):
-        up = gmm(xs, p["up"])
-        h = act(gmm(xs, p["gate"])) * up if cfg.gated_mlp else act(up)
-        y = gmm(h, p["down"])
+    def dispatch(M):
+        obs.counter("moe_dispatch_rows_total",
+                    "Traced grouped-dispatch row bounds").inc(rows=M)
+        with jax.named_scope("moe.dispatch"):
+            rows = order[:M]
+            live = held[rows]
+            tok = rows // K
+            xs = jnp.take(x.reshape(T, D), tok, axis=0)
+            ws = jnp.where(live, gate_w.reshape(T * K)[rows], 0)
+        with jax.named_scope("moe.experts"):
+            up = gmm(xs, p["up"])
+            h = act(gmm(xs, p["gate"])) * up if cfg.gated_mlp else act(up)
+            y = gmm(h, p["down"])
+        with jax.named_scope("moe.dispatch"):
+            y = jnp.where(live[:, None], y * ws[:, None], 0)
+            return jnp.zeros((T, D), jnp.float32).at[tok].add(y)
+
+    def branch(M):
+        with jax.named_scope(f"moe.rows_{M}"):
+            return dispatch(M)
+
+    if len(rungs) == 1:
+        out = dispatch(rungs[0])
+    else:
+        # the switch itself lies outside ``moe.dispatch``: its branches hold
+        # ``moe.experts`` work too
+        with jax.named_scope("moe.dispatch"):
+            pick = jnp.sum(sizes.sum() > jnp.asarray(rungs[:-1]))
+        out = jax.lax.switch(
+            pick, [functools.partial(branch, M) for M in rungs])
     with jax.named_scope("moe.dispatch"):
-        y = jnp.where(live[:, None], y * ws[:, None], 0)
-        out = jnp.zeros((T, D), jnp.float32).at[tok].add(y)
         return out.reshape(B, S, D).astype(x.dtype)
 
 

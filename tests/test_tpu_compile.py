@@ -10,7 +10,8 @@ the serving path runs: OPT-1.3B (MHA, f32) and Qwen3-4B (GQA, bf16); the
 flash-attention kernel at the search's 512-token calibration rows. The
 no-cache forward compiles for one chip with the kernel, and for the four
 chips of a v5e:2x2 host with a batch-sharded input, where GSPMD could not
-split the kernel and the forward keeps ``blocked_attention``.
+split the kernel and the forward keeps ``blocked_attention``. Moonlight's
+grouped MoE layer compiles to a conditional over its dispatch's row bounds.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load libtpu, and a test file that loaded it while being
@@ -19,6 +20,7 @@ cache is off around these tests (an entry compiled for a described chip
 cannot be read back without one).
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,7 @@ from repro.configs import get_config
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.models import forward, init_params
+from repro.models import layers as L
 from repro.kernels.paged_decode import (paged_decode_gqa_pallas,
                                         paged_decode_pallas)
 
@@ -163,3 +166,31 @@ def test_forward_compiles_for_its_devices(v5e_2x2, monkeypatch, program,
     txt = jax.jit(fwd).lower(params, toks).compile().as_text()
     assert c.value(path=path) == before + 1
     assert ("tpu_custom_call" in txt) == (path == "flash")
+
+
+def test_moonlight_dispatch_compiles_to_a_ladder_of_bounds(one_chip,
+                                                           monkeypatch):
+    """Moonlight's grouped MoE layer at its widths (8 of 64 experts held,
+    top-6, 16 x 512 tokens) compiles to a conditional whose branches gather
+    7,680, 15,360 and 49,152 rows of 2,048: 1.25x the held experts' even
+    share of 6,144 slots rounded up to 512, twice that, and every slot."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    base = get_config("moonshot-v1-16b-a3b")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, experts_held=(0, 8), dispatch="grouped"))
+    p = jax.eval_shape(
+        lambda: L.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32))
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    experts = {k: spec(p[k].shape) for k in ("up", "gate", "down")}
+    assert experts["up"].shape == (8, 2048, 1408)
+    txt = jax.jit(lambda p, x, w, i: L._grouped_experts(
+        p, cfg, x, w, i)).lower(
+        experts, spec((16, 512, 2048)), spec((16, 512, 6)),
+        spec((16, 512, 6), jnp.int32)).compile().as_text()
+    assert " conditional(" in txt
+    rows = {int(r) for r in re.findall(r"= \w+\[(\d+),2048\]\S* gather\(",
+                                       txt)}
+    assert rows == {7680, 15360, 49152}
