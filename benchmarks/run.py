@@ -26,14 +26,12 @@ def main() -> None:
                             serving_bench)
 
     def search_mem_bench():
-        # K=8 candidate eval, O(unit) dynamic-slice install vs K full stacks:
-        # search_unit_install/ and search_stack_install/ rows with
-        # peak_live_bytes (jax.live_arrays() delta) in BENCH_search.json
+        # K=8 candidate eval through the O(unit) dynamic-slice install: a
+        # search_unit_install/ row with peak_live_bytes (jax.live_arrays()
+        # delta) in BENCH_search.json
         from repro.launch.search import run_search_bench
-        for mode in ("unit", "stack"):
-            run_search_bench(steps=4 if args.fast else 16, population=8,
-                             n_seqs=2, seq_len=64, install=mode,
-                             measure_mem=True)
+        run_search_bench(steps=4 if args.fast else 16, population=8,
+                         n_seqs=2, seq_len=64, measure_mem=True)
 
     jobs = {
         "table1": lambda: table1_main.run(search_steps=steps),

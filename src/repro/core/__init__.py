@@ -12,14 +12,13 @@ from repro.core.invariance import (
 __all__ = [
     "QuantConfig", "QTensor", "fake_quant", "quantize_tensor", "bits_per_param",
     "FFNTransform", "identity_transform", "apply_transform_ffn", "propose",
-    "ProposalConfig", "SearchConfig", "SearchResult", "run_search", "make_adapter",
+    "ProposalConfig", "SearchConfig", "SearchResult", "make_adapter",
     "quantize_model", "PTQResult",
 ]
 
 _LAZY = {
     "SearchConfig": "repro.core.search",
     "SearchResult": "repro.core.search",
-    "run_search": "repro.core.search",
     "make_adapter": "repro.core.search",
     "quantize_model": "repro.core.pipeline",
     "PTQResult": "repro.core.pipeline",

@@ -2,14 +2,16 @@
 
 The search loop itself lives in ``repro.search.engine`` — a population ×
 island annealed engine whose ``population=1, islands=1, temperature=0``
-defaults reproduce the original single-chain hill climb bit-for-bit. This
-module keeps what is model-family-specific: the *adapters* that expose a
-family's transformable units (dense FFN, MoE expert, Mamba block, shared
-hybrid FFN) plus the ``run_search`` entry point every caller already uses.
+defaults reproduce the original single-chain hill climb bit-for-bit; its one
+entry point is ``repro.search.run``. This module keeps what is
+model-family-specific: the *adapters* that expose a family's transformable
+units (dense FFN, MoE expert, Mamba block, shared hybrid FFN), plus the
+``SearchConfig`` / ``SearchResult`` dataclasses.
 
-TPU-native execution model (DESIGN.md §3): the whole proposal evaluation —
-transform → fake-quant → forward → loss — is ONE jitted function with the
-unit index as a traced scalar, so a single XLA program serves every step.
+TPU-native execution model (README.md, "Search framework"): each step's
+proposal evaluation — propose → transform → fake-quant → forward → loss —
+runs as the engine's four staged jitted programs with the unit index as a
+traced scalar, so one set of XLA programs serves every step.
 Proposals come from counter-based ``jax.random`` keys: in a multi-host
 setting every host replays the same proposal stream and the accept decision
 derives from the (all-reduced) scalar loss, so hosts stay in lock-step with
@@ -18,7 +20,6 @@ zero extra communication.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any, Optional
 
 import jax
@@ -29,7 +30,7 @@ from repro.core.quant import QuantConfig, fake_quant
 from repro.models.config import ModelConfig
 
 __all__ = ["SearchConfig", "SearchResult", "DenseFFNAdapter", "MoEAdapter",
-           "MambaAdapter", "run_search"]
+           "MambaAdapter"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +54,8 @@ class SearchConfig:
     mapped: bool = False           # one island per mesh shard (shard_map);
                                    # requires islands == global device count
     # --- v2 candidate-eval memory model + calibration sharding ---
-    install: str = "unit"          # "unit": stack + K×unit dynamic-slice
-                                   # install; "stack": v1 K full stacks
+    install: str = "unit"          # the only lane: stack + K×unit
+                                   # dynamic-slice install
     tabu: int = 0                  # tried-point memory capacity (0 = off;
                                    # sequential lane only)
     shard_calib: bool = False      # per-island calibration slices
@@ -267,7 +268,7 @@ class MoEAdapter:
 
 class MambaAdapter:
     """Mamba2 blocks: unit = one block; permutation-only, block-structured
-    within heads (exact invariance — DESIGN.md §Arch-applicability)."""
+    within heads (exact invariance — ``core/invariance.py``, README.md)."""
 
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -372,68 +373,9 @@ def make_adapter(cfg: ModelConfig, phase: str = None):
         return MambaAdapter(cfg)
     if cfg.block_pattern == "hybrid":
         # two-phase composite: "mamba" (within-head P, exact) then "shared"
-        # (full P/S/R on the shared block's FFN) — see run_search_hybrid.
+        # (full P/S/R on the shared block's FFN) — see api._run_hybrid.
         if phase == "shared":
             return SharedFFNAdapter(cfg)
         return MambaAdapter(cfg)
     raise NotImplementedError(f"no search adapter for pattern {cfg.block_pattern!r}")
 
-
-def _merge_phase_stats(s1, s2):
-    """Sum the counters of two engine stats dicts; rates recombine so the
-    merged proposals_per_sec reflects TOTAL proposals over TOTAL wall time."""
-    if s1 is None or s2 is None:
-        return s2 or s1
-    out = dict(s2)
-    for k in ("migrations", "uphill_accepts", "proposals"):
-        out[k] = s1.get(k, 0) + s2.get(k, 0)
-    t1 = s1.get("proposals", 0) / max(s1.get("proposals_per_sec", 0.0), 1e-9)
-    t2 = s2.get("proposals", 0) / max(s2.get("proposals_per_sec", 0.0), 1e-9)
-    out["proposals_per_sec"] = out["proposals"] / max(t1 + t2, 1e-9)
-    out["fused"] = s1.get("fused", False) or s2.get("fused", False)
-    return out
-
-
-def run_search_hybrid(params_fp, params_base, cfg, qcfg, calib_tokens,
-                      scfg: SearchConfig = SearchConfig(), forward_kwargs=None):
-    """Deprecated: ``repro.search.run`` dispatches hybrid block patterns to
-    the two-phase Mamba → shared-FFN composite automatically."""
-    warnings.warn(
-        "core.search.run_search_hybrid is deprecated; use "
-        "repro.search.run(...) (hybrid configs two-phase automatically)",
-        DeprecationWarning, stacklevel=2)
-    from repro.search import run
-    return run(params_fp, params_base, cfg, qcfg, calib_tokens, scfg,
-               forward_kwargs=forward_kwargs, hybrid=True)
-
-
-# ---------------------------------------------------------------------------
-# The search entry point (Algorithm 1) — deprecated shim over repro.search
-# ---------------------------------------------------------------------------
-
-def run_search(
-    params_fp: dict,
-    params_base: dict,
-    cfg: ModelConfig,
-    qcfg: QuantConfig,
-    calib_tokens: jnp.ndarray,
-    scfg: SearchConfig = SearchConfig(),
-    adapter=None,
-    forward_kwargs: Optional[dict] = None,
-) -> SearchResult:
-    """Deprecated: call ``repro.search.run`` (same signature, one front door
-    for single-phase, hybrid and population/island configurations).
-
-    This shim preserves the legacy single-phase semantics exactly — on a
-    hybrid config it searches only the Mamba blocks, as before (pass the
-    config to ``repro.search.run`` without an adapter to get the two-phase
-    composite instead). The default ``SearchConfig`` (population=1,
-    islands=1, temperature=0) reproduces the original single-chain hill
-    climb bit-for-bit.
-    """
-    warnings.warn(
-        "core.search.run_search is deprecated; use repro.search.run(...)",
-        DeprecationWarning, stacklevel=2)
-    from repro.search import run
-    return run(params_fp, params_base, cfg, qcfg, calib_tokens, scfg,
-               adapter=adapter, forward_kwargs=forward_kwargs, hybrid=False)

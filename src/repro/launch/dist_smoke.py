@@ -46,7 +46,7 @@ def _check_mapped_parity(steps: int, migrate_every: int, population: int):
     from repro.core.quant import QuantConfig
     from repro.core.search import SearchConfig
     from repro.models import init_params
-    from repro.search import run as run_search
+    from repro.search import run as search_run
 
     cfg = get_config("opt-tiny").reduced(
         n_layers=2, d_model=64, d_ff=128, vocab_size=256, n_heads=4,
@@ -60,8 +60,8 @@ def _check_mapped_parity(steps: int, migrate_every: int, population: int):
                         islands=n_islands, migrate_every=migrate_every,
                         population=population)
 
-    r_seq = run_search(params, params, cfg, qcfg, calib, scfg)
-    r_map = run_search(params, params, cfg, qcfg, calib,
+    r_seq = search_run(params, params, cfg, qcfg, calib, scfg)
+    r_map = search_run(params, params, cfg, qcfg, calib,
                        dataclasses.replace(scfg, mapped=True))
     if r_seq.island_histories != r_map.island_histories:
         for i, (a, b) in enumerate(zip(r_seq.island_histories,

@@ -12,8 +12,7 @@ With ``--mapped`` the islands run one-per-device-shard
 and the row lands under the ``search_mapped_islands/`` family — bench-smoke
 asserts both families are present. With ``--measure-mem`` the row carries
 ``peak_live_bytes`` (the ``jax.live_arrays()`` delta over the run) and lands
-under ``search_unit_install/`` or ``search_stack_install/`` per
-``--install``, so CI can assert the O(unit) memory model at K=8.
+under ``search_unit_install/``, the O(unit) memory model's rows.
 
 Configs run in their ``.reduced()`` form unless ``--full`` asks for the
 published widths; ``--layers N`` then cuts the depth to N blocks (a printed
@@ -79,7 +78,7 @@ def run_search_bench(arch: str = "opt-tiny", *, full: bool = False,
                      temperature: float = 0.0, anneal: str = "geometric",
                      migrate_every: int = 25, fused: bool = False,
                      mapped: bool = False, objective: str = "ce",
-                     install: str = "unit", tabu: int = 0,
+                     tabu: int = 0,
                      shard_calib: bool = False, measure_mem: bool = False,
                      bits: int = 2, group: int = 32, n_seqs: int = 4,
                      seq_len: int = 128, seed: int = 0,
@@ -102,9 +101,8 @@ def run_search_bench(arch: str = "opt-tiny", *, full: bool = False,
                         population=population, islands=islands,
                         temperature=temperature, anneal=anneal,
                         migrate_every=migrate_every, fused_kernel=fused,
-                        mapped=mapped, objective=objective, install=install,
-                        tabu=tabu, shard_calib=shard_calib,
-                        measure_memory=measure_mem)
+                        mapped=mapped, objective=objective, tabu=tabu,
+                        shard_calib=shard_calib, measure_memory=measure_mem)
     qcfg = QuantConfig(bits=bits, group_size=group)
 
     prop_before = obs.counter(
@@ -123,10 +121,8 @@ def run_search_bench(arch: str = "opt-tiny", *, full: bool = False,
             f"obs/stats divergence: search_proposals_total grew by "
             f"{prop_delta} but stats['proposals'] == {proposals}")
     if measure_mem:
-        # memory-model benchmark rows: bench-smoke asserts the unit-install
-        # peak live bytes stay below the K-full-stacks lane at the same K
-        family = ("search_unit_install" if install == "unit"
-                  else "search_stack_install")
+        # memory-model benchmark rows (peak live bytes of the O(unit) lane)
+        family = "search_unit_install"
     elif mapped:
         family = "search_mapped_islands"
     else:
@@ -142,7 +138,7 @@ def run_search_bench(arch: str = "opt-tiny", *, full: bool = False,
                     f"accept={sr.accept_rate:.2%} "
                     f"migrations={sr.stats['migrations'] if sr.stats else 0} "
                     f"objective={sr.stats.get('objective', objective)} "
-                    f"install={sr.stats.get('install', install)} "
+                    f"install={sr.stats.get('install', 'unit')} "
                     f"tabu_hits={sr.stats.get('tabu_hits', 0)}"),
     }
     if measure_mem and sr.stats and "peak_live_bytes" in sr.stats:
@@ -179,16 +175,13 @@ def main(argv=None) -> int:
     ap.add_argument("--objective", default="ce",
                     choices=["ce", "kl", "swd_actmatch", "saliency_ce"],
                     help="search objective (registry name)")
-    ap.add_argument("--install", default="unit", choices=["unit", "stack"],
-                    help="candidate install mode: 'unit' = stack + K x unit "
-                         "dynamic-slice buffers; 'stack' = K full stacks")
     ap.add_argument("--tabu", type=int, default=0,
                     help="tried-point memory capacity (0 disables)")
     ap.add_argument("--shard-calib", action="store_true",
                     help="each island climbs on its own calibration slice")
     ap.add_argument("--measure-mem", action="store_true",
                     help="sample jax.live_arrays() peaks; rows land under "
-                         "search_unit_install/search_stack_install")
+                         "search_unit_install")
     ap.add_argument("--bits", type=int, default=2)
     ap.add_argument("--group", type=int, default=32)
     ap.add_argument("--seqs", type=int, default=4)
@@ -208,8 +201,8 @@ def main(argv=None) -> int:
                      islands=args.islands, temperature=args.temperature,
                      anneal=args.anneal, migrate_every=args.migrate_every,
                      fused=args.fused, mapped=args.mapped,
-                     objective=args.objective, install=args.install,
-                     tabu=args.tabu, shard_calib=args.shard_calib,
+                     objective=args.objective, tabu=args.tabu,
+                     shard_calib=args.shard_calib,
                      measure_mem=args.measure_mem, bits=args.bits,
                      group=args.group, n_seqs=args.seqs,
                      seq_len=args.seq_len, seed=args.seed, out=args.out,

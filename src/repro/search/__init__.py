@@ -8,9 +8,7 @@ greedy hill-climb as an exact special case:
   dispatch, hybrid two-phase composites, objective resolution);
 - ``population.py`` — K candidate transforms per step for the sampled unit;
 - ``install.py``    — O(unit)-memory candidate install: ONE fake-quant
-  stack + K per-unit buffers via ``dynamic_update_slice`` tree surgery
-  (``install="unit"``, the default) or the v1 K-full-stacks ``vmap`` lane
-  (``install="stack"``);
+  stack + K per-unit buffers via ``dynamic_update_slice`` tree surgery;
 - ``anneal.py``     — temperature schedules + the Metropolis acceptance rule
   (T=0 reduces bit-for-bit to the legacy accept-iff-better);
 - ``islands.py``    — independent populations with counter-based per-island
@@ -18,7 +16,8 @@ greedy hill-climb as an exact special case:
   ``shard_calib=True`` each island climbs on its own calibration slice;
 - ``tabu.py``       — tried-point dedup memory replaying cached scalars for
   proposals already evaluated at the current chain state;
-- ``engine.py``     — the loop that composes all of the above.
+- ``engine.py``     — the loop that composes all of the above, one staged
+  propose/build/eval/pick step pipeline at every K.
 
 Objectives are pluggable (``repro.core.objective``): ``"ce"`` (the paper's
 Eqn. 23 default), ``"kl"``, ``"swd_actmatch"``, ``"saliency_ce"``, or any
@@ -26,12 +25,10 @@ registered/passed ``Objective`` instance.
 """
 from repro.search.anneal import accept, temperature_schedule
 from repro.search.api import run
-from repro.search.engine import run_population_search
 from repro.search.install import tree_install_unit
 from repro.search.islands import IslandState, migrate
 from repro.search.population import candidate_keys
 from repro.search.tabu import TabuMemory
 
-__all__ = ["run", "run_population_search", "temperature_schedule", "accept",
-           "IslandState", "migrate", "candidate_keys", "tree_install_unit",
-           "TabuMemory"]
+__all__ = ["run", "temperature_schedule", "accept", "IslandState", "migrate",
+           "candidate_keys", "tree_install_unit", "TabuMemory"]

@@ -1,17 +1,11 @@
-"""``repro.search.run`` — the ONE front door to the discrete search.
+"""``repro.search.run`` — the ONE entry point to the discrete search.
 
-Unifies what used to be three entry points:
-
-- ``core.search.run_search``            (single-phase, adapter-dispatched)
-- ``core.search.run_search_hybrid``     (Zamba2 two-phase Mamba → shared FFN)
-- ``search.engine.run_population_search`` (the raw engine loop)
-
-all of which remain as thin ``DeprecationWarning`` shims. The front door
-resolves the adapter from the model family, dispatches hybrid block
-patterns to the two-phase composite automatically, and accepts the
-objective either on the config (``SearchConfig(objective=...)``) or as the
-``objective=`` keyword (a registry name or an ``Objective`` instance — the
-keyword wins when both are given).
+The front door resolves the adapter from the model family, dispatches
+hybrid block patterns to the two-phase composite automatically (an explicit
+``adapter=`` runs one phase), and accepts the objective either on the
+config (``SearchConfig(objective=...)``) or as the ``objective=`` keyword
+(a registry name or an ``Objective`` instance — the keyword wins when both
+are given).
 
 The default configuration (population=1, islands=1, temperature=0, CE
 objective, replicated calibration) reproduces the paper's single-chain hill
@@ -39,7 +33,6 @@ def run(
     objective=None,
     adapter=None,
     forward_kwargs: Optional[dict] = None,
-    hybrid: Optional[bool] = None,
 ):
     """Run the InvarExplore search; returns a ``core.search.SearchResult``.
 
@@ -50,25 +43,36 @@ def run(
     scfg: ``core.search.SearchConfig`` (defaults reproduce the paper run).
     objective: registry name ("ce", "kl", "swd_actmatch", "saliency_ce") or
         an ``Objective`` instance; overrides ``scfg.objective``.
-    adapter: explicit unit adapter; disables hybrid auto-dispatch.
-    hybrid: force (True) or suppress (False) the two-phase hybrid runner;
-        None auto-detects from ``cfg.block_pattern`` when no adapter is
-        given (the legacy ``run_search`` shim passes False to keep its
-        single-phase semantics on hybrid configs).
+    adapter: explicit unit adapter. Without one, the adapter follows
+        ``cfg.block_pattern`` and a hybrid pattern runs the two-phase
+        Mamba → shared-FFN composite; with one, a single phase runs.
     """
     from repro.core.search import SearchConfig, make_adapter
 
     scfg = scfg if scfg is not None else SearchConfig()
     if objective is not None:
         scfg = dataclasses.replace(scfg, objective=objective)
-    if hybrid is None:
-        hybrid = cfg.block_pattern == "hybrid" and adapter is None
-    if hybrid:
+    if cfg.block_pattern == "hybrid" and adapter is None:
         return _run_hybrid(params_fp, params_base, cfg, qcfg, calib_tokens,
                            scfg, forward_kwargs)
     return _run_engine(params_fp, params_base, cfg, qcfg, calib_tokens,
                        scfg, adapter=adapter or make_adapter(cfg),
                        forward_kwargs=forward_kwargs)
+
+
+def _merge_phase_stats(s1, s2):
+    """Sum the counters of two engine stats dicts; rates recombine so the
+    merged proposals_per_sec reflects TOTAL proposals over TOTAL wall time."""
+    if s1 is None or s2 is None:
+        return s2 or s1
+    out = dict(s2)
+    for k in ("migrations", "uphill_accepts", "proposals"):
+        out[k] = s1.get(k, 0) + s2.get(k, 0)
+    t1 = s1.get("proposals", 0) / max(s1.get("proposals_per_sec", 0.0), 1e-9)
+    t2 = s2.get("proposals", 0) / max(s2.get("proposals_per_sec", 0.0), 1e-9)
+    out["proposals_per_sec"] = out["proposals"] / max(t1 + t2, 1e-9)
+    out["fused"] = s1.get("fused", False) or s2.get("fused", False)
+    return out
 
 
 def _run_hybrid(params_fp, params_base, cfg, qcfg, calib_tokens, scfg,
@@ -78,8 +82,7 @@ def _run_hybrid(params_fp, params_base, cfg, qcfg, calib_tokens, scfg,
     starting from phase 1's quantized model. Phase 2 runs the REMAINDER
     ``steps - steps // 2`` so an odd budget is spent in full, and the
     returned histories/stats merge both phases."""
-    from repro.core.search import (MambaAdapter, SharedFFNAdapter,
-                                   _merge_phase_stats)
+    from repro.core.search import MambaAdapter, SharedFFNAdapter
 
     n1 = scfg.steps // 2
     n2 = scfg.steps - n1

@@ -3,13 +3,11 @@
 One engine, three nested degrees of freedom, each defaulting to the paper's
 single-chain hill climb:
 
-- population K: K candidate transforms for the step's unit. v2 memory model
-  (``SearchConfig(install="unit")``, the default): the engine carries ONE
-  fake-quant stack plus a K × *unit* candidate buffer and installs only the
-  touched unit per evaluation via ``jax.lax.dynamic_update_slice`` tree
-  surgery (``repro.search.install``) — peak memory is stack + K × unit.
-  ``install="stack"`` keeps the v1 semantics (K full stacks through one
-  ``vmap``-batched program) for A/B benchmarking.
+- population K: K candidate transforms for the step's unit. The engine
+  carries ONE fake-quant stack plus a K × *unit* candidate buffer and
+  installs only the touched unit per evaluation via
+  ``jax.lax.dynamic_update_slice`` tree surgery (``repro.search.install``)
+  — peak memory is stack + K × unit.
 - temperature T: Metropolis acceptance of the chosen candidate under an
   annealing schedule; T=0 is the strict accept-iff-better rule.
 - islands: independent chains with per-island counter-based key streams and
@@ -18,6 +16,15 @@ single-chain hill climb:
   calibration batch (``data.calib.shard_calibration``) — true data-parallel
   calibration; islands exchange only scalar objective estimates at
   migration.
+
+Every step, at every K, runs one pipeline of four jitted programs:
+``propose_body`` (K candidate transforms for the unit), ``build_units_body``
+(transform + fake-quant of the K candidate units), ``eval_body`` (a
+``lax.map`` over the candidates, each installed into the stack and run
+through the objective forward ``eval_stack_fn``) and ``pick_body`` (the
+best candidate and its installed stack). The stages are separate programs
+so the K-candidate buffer is a real set of device arrays between them and
+``measure_memory=True`` samples the memory model honestly.
 
 The objective is pluggable (``core.objective``): ``SearchConfig.objective``
 takes a registry name ("ce", "kl", "swd_actmatch", "saliency_ce") or an
@@ -29,11 +36,10 @@ evaluated at the current chain state instead of paying the device forward.
 
 Bit-for-bit contract: at ``population=1, islands=1, temperature=0`` under
 the default objective (tabu off, calibration replicated) the engine's
-proposal keys, unit picks, jitted programs and accept decisions are EXACTLY
-the legacy ``core/search.py`` loop's, so the accepted-move trajectory
-reproduces the paper configuration unchanged (pinned by
-``tests/test_search_engine.py``, now through the ``repro.search.run`` front
-door).
+proposal keys, unit picks, losses and accept decisions are EXACTLY the
+legacy single-chain loop's, so the accepted-move trajectory reproduces the
+paper configuration unchanged (pinned by ``tests/test_search_engine.py``
+through the ``repro.search.run`` front door).
 
 Execution modes:
 
@@ -64,14 +70,12 @@ from repro.core import invariance as inv
 from repro.core import objective as obj
 from repro.models.model import forward
 from repro.search import anneal
-from repro.search.install import (stack_unit_batch, tree_bytes,
-                                  tree_install_unit)
+from repro.search.install import (eval_candidates_unit, stack_unit_batch,
+                                  tree_bytes, tree_install_unit)
 from repro.search.islands import (IslandState, make_island_streams, migrate,
                                   migrate_on_mesh)
 from repro.search.population import candidate_keys, stack_trees, take_tree
 from repro.search.tabu import TabuMemory, transform_bytes
-
-__all__ = ["run_population_search"]
 
 
 def _search_metrics():
@@ -128,32 +132,11 @@ def _live_bytes() -> int:
 
 def _resolve_install(scfg) -> str:
     mode = str(getattr(scfg, "install", "unit"))
-    if mode not in ("unit", "stack"):
+    if mode != "unit":
         raise ValueError(
-            f"SearchConfig.install must be 'unit' or 'stack', got {mode!r}")
+            f"SearchConfig.install must be 'unit' (the engine installs one "
+            f"unit per candidate; the 'stack' lane was removed), got {mode!r}")
     return mode
-
-
-def run_population_search(
-    params_fp: dict,
-    params_base: dict,
-    cfg,
-    qcfg,
-    calib_tokens: jnp.ndarray,
-    scfg,
-    adapter,
-    forward_kwargs: Optional[dict] = None,
-):
-    """Deprecated alias of the engine loop — call ``repro.search.run``.
-
-    Kept as a thin shim so pre-v2 callers keep working; the front door adds
-    objective resolution and hybrid two-phase dispatch on top of this loop.
-    """
-    warnings.warn(
-        "repro.search.engine.run_population_search is deprecated; use "
-        "repro.search.run(...)", DeprecationWarning, stacklevel=2)
-    return _run_engine(params_fp, params_base, cfg, qcfg, calib_tokens,
-                       scfg, adapter, forward_kwargs)
 
 
 def _run_engine(
@@ -168,7 +151,7 @@ def _run_engine(
 ):
     """Run the engine; returns a ``core.search.SearchResult``.
 
-    ``params_fp`` / ``params_base`` follow the ``core.search.run_search``
+    ``params_fp`` / ``params_base`` follow the ``repro.search.run``
     contract (FP reference model; base-method continuous-domain FFN weights
     with everything else already fake-quantized).
     """
@@ -275,28 +258,12 @@ def _run_engine(
             return adapter.quant_unit(unit, qcfg)
 
         # ---- step programs (shared by every island; the context is an arg) ---
-        # legacy single path: EXACTLY the pre-engine step — one proposal,
-        # unbatched evaluation in ONE jitted program. This is the bit-for-bit
-        # anchor; any K>1 / fused / tabu request takes the staged v2 pipeline.
-        staged = (K > 1) or fused or (tabu_cap > 0)
+        # propose / build / eval / pick are SEPARATE jitted stages so the
+        # K-candidate buffer is a real set of device arrays between stages —
+        # ``jax.live_arrays()`` then measures the memory model (stack + K ×
+        # unit) honestly.
         peak = {"bytes": 0, "batch_bytes": 0}
 
-        def step_body_single(ctx, key, transforms, fq_stack, u):
-            k_prop, _ = jax.random.split(key)
-            t_u = _tree_slice(transforms, u)
-            t_new = proposer(k_prop, inv.FFNTransform(*t_u), scfg.proposal)
-            unit = adapter.transform_unit(adapter.base_stack(ctx["params"]),
-                                          t_new, u)
-            unit_fq = adapter.quant_unit(unit, qcfg)
-            fq_new = _tree_update(fq_stack, u, unit_fq)
-            p, a = eval_stack_fn(ctx, fq_new)
-            loss = p + alpha * a
-            return loss, p, a, fq_new, t_new
-
-        # staged v2 pipeline: propose / build / eval / pick are SEPARATE jitted
-        # stages so the K-candidate buffer is a real set of device arrays between
-        # stages — ``jax.live_arrays()`` then measures the memory model honestly
-        # (stack + K × unit for install="unit", (K+1) × stack for "stack").
         def propose_body(key, transforms, u):
             keys = candidate_keys(key, K)
             t_u = inv.FFNTransform(*_tree_slice(transforms, u))
@@ -311,49 +278,26 @@ def _run_engine(
                 for i in range(K)]
             return stack_unit_batch(units)
 
-        def build_stacks_body(ctx, cands, fq_stack, u):
-            units = [quant_candidate(
-                ctx, inv.FFNTransform(*_tree_slice(cands, i)), u)
-                for i in range(K)]
-            return stack_trees([tree_install_unit(fq_stack, u, un)
-                                for un in units])
+        def eval_body(ctx, batch, fq_stack, u):
+            return eval_candidates_unit(batch, fq_stack, u,
+                                        lambda fq: eval_stack_fn(ctx, fq))
 
-        if install_mode == "unit":
-            def eval_body(ctx, batch, fq_stack, u):
-                def body(unit_fq):
-                    return eval_stack_fn(
-                        ctx, tree_install_unit(fq_stack, u, unit_fq))
-                return jax.lax.map(body, batch)
+        def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
+            loss = p_vec + alpha * a_vec
+            i = jnp.argmin(loss)
+            fq_new = tree_install_unit(fq_stack, u, take_tree(batch, i))
+            return (loss[i], p_vec[i], a_vec[i], fq_new,
+                    inv.FFNTransform(*_tree_slice(cands, i)), loss)
 
-            def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
-                loss = p_vec + alpha * a_vec
-                i = jnp.argmin(loss)
-                fq_new = tree_install_unit(fq_stack, u, take_tree(batch, i))
-                return (loss[i], p_vec[i], a_vec[i], fq_new,
-                        inv.FFNTransform(*_tree_slice(cands, i)), loss)
-        else:
-            def eval_body(ctx, batch, fq_stack, u):
-                del fq_stack, u
-                return jax.vmap(lambda fq: eval_stack_fn(ctx, fq))(batch)
-
-            def pick_body(cands, batch, fq_stack, u, p_vec, a_vec):
-                del fq_stack, u
-                loss = p_vec + alpha * a_vec
-                i = jnp.argmin(loss)
-                return (loss[i], p_vec[i], a_vec[i], take_tree(batch, i),
-                        inv.FFNTransform(*_tree_slice(cands, i)), loss)
-
-        build_fn = obs.scoped(jax.jit(build_units_body if install_mode == "unit"
-                                      else build_stacks_body))
+        build_fn = obs.scoped(jax.jit(build_units_body))
         eval_fn = obs.scoped(jax.jit(eval_body))
         pick_fn = obs.scoped(jax.jit(pick_body))
 
-        def staged_step(ctx, key, transforms, fq_stack, u):
+        # host-callable step: (ctx, key, transforms, fq_stack, u) ->
+        # (loss, primary, aux, fq_new, t_new, loss_vec, p_vec, a_vec)
+        def step_fn(ctx, key, transforms, fq_stack, u):
             cands = propose_fn(key, transforms, u)
-            if install_mode == "unit":
-                batch = build_fn(ctx, cands, u)
-            else:
-                batch = build_fn(ctx, cands, fq_stack, u)
+            batch = build_fn(ctx, cands, u)
             if measure:
                 jax.block_until_ready(batch)
                 peak["bytes"] = max(peak["bytes"], _live_bytes())
@@ -361,11 +305,6 @@ def _run_engine(
             p_vec, a_vec = eval_fn(ctx, batch, fq_stack, u)
             out = pick_fn(cands, batch, fq_stack, u, p_vec, a_vec)
             return out[:5] + (out[5], p_vec, a_vec)
-
-        # host-callable step: (ctx, key, transforms, fq_stack, u) ->
-        # (loss, primary, aux, fq_new, t_new[, loss_vec, p_vec, a_vec])
-        step_fn = staged_step if staged else obs.scoped(
-            jax.jit(step_body_single))
 
         if shard_calib:
             # per-island step-0 baselines on each island's OWN slice (1 island
@@ -578,12 +517,12 @@ def _run_mapped_islands(SearchResult, adapter, scfg, params_base, step_fn,
     is a property of the construction rather than a hope about the compiler:
 
     - the per-island STEP (propose → transform → fake-quant → forward → loss)
-      runs the SAME step program the sequential engine runs (the legacy
-      single-jit body, or the staged v2 propose/build/eval/pick stages),
-      with the island's state AND its context (base model, calibration
-      slice, FP reference) committed to its shard's device — XLA generates
-      identical code for identical programs, so the per-step scalars come
-      out bit-identical island by island. (Running the step
+      runs the SAME step programs the sequential engine runs (the staged
+      propose/build/eval/pick pipeline), with the island's state AND its
+      context (base model, calibration slice, FP reference) committed to
+      its shard's device — XLA generates identical code for identical
+      programs, so the per-step scalars come out bit-identical island by
+      island. (Running the step
       *inside* shard_map instead was measurably NOT bit-stable: the
       surrounding slice/gather graph perturbs how XLA fuses the loss
       reductions, and ``optimization_barrier`` does not fence it off.)

@@ -1,26 +1,19 @@
 """O(unit)-memory candidate install: dynamic-slice tree surgery.
 
-A proposal touches ONE unit of the fake-quant stack, yet the v1 population
-step materialized K *full* stacks (``_tree_update`` per candidate, then a
-``vmap`` over the K-stacked trees) — memory = K × stack, the ROADMAP item-2
-blocker. The v2 path keeps ONE stack plus K per-unit candidate buffers:
+A proposal touches ONE unit of the fake-quant stack, so the engine keeps ONE
+stack plus K per-unit candidate buffers instead of K full stacks:
 
 - :func:`tree_install_unit` writes one unit into the stacked tree via
   ``jax.lax.dynamic_update_slice`` (the generalized ``_tree_update``; for a
-  concrete integer index the two lower identically, and the property tests
-  pin install-mode equivalence bit-for-bit);
+  concrete integer index the two lower identically, pinned by the property
+  tests);
+- :func:`stack_unit_batch` stacks the K candidate units into the K × unit
+  candidate buffer (a REAL stage output in the engine's staged pipeline, so
+  ``jax.live_arrays()`` sees exactly the memory model being claimed);
 - :func:`eval_candidates_unit` folds a ``lax.map`` over the K unit buffers,
-  installing each into the (XLA-donated) stack one at a time — peak live
-  memory is stack + K × unit instead of (K+1) × stack;
-- :func:`eval_candidates_stack` is the v1 semantics behind the same
-  signature (``install="stack"``), kept for A/B benchmarking — the CI
-  bench-smoke lane asserts unit-install peak live bytes < stack-install
-  peak at K=8.
-
-Both entry points take the K×unit candidate batch (a REAL stage output in
-the engine's staged pipeline, so ``jax.live_arrays()`` sees exactly the
-memory model being claimed) and return ``(primary, aux)`` vectors of shape
-(K,).
+  installing each into the stack one at a time — peak live memory is
+  stack + K × unit, and it returns ``(primary, aux)`` vectors of shape (K,);
+- :func:`tree_bytes` sizes a pytree for the memory-model stats.
 """
 from __future__ import annotations
 
@@ -28,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["tree_install_unit", "stack_unit_batch", "eval_candidates_unit",
-           "eval_candidates_stack", "tree_bytes"]
+           "tree_bytes"]
 
 
 def tree_install_unit(tree, u, unit):
@@ -64,14 +57,6 @@ def eval_candidates_unit(unit_batch, fq_stack, u, eval_fn):
         return eval_fn(tree_install_unit(fq_stack, u, unit_fq))
 
     return jax.lax.map(body, unit_batch)
-
-
-def eval_candidates_stack(unit_batch, fq_stack, u, eval_fn):
-    """v1 semantics: materialize all K installed stacks and ``vmap`` the
-    objective across them (memory = K × stack; fastest when it fits)."""
-    fq_batch = jax.vmap(
-        lambda unit_fq: tree_install_unit(fq_stack, u, unit_fq))(unit_batch)
-    return jax.vmap(eval_fn)(fq_batch)
 
 
 def tree_bytes(tree) -> int:

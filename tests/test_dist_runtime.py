@@ -90,8 +90,9 @@ def test_mapped_requires_island_per_device(tiny_cfg):
     """islands != device count must fail fast with an actionable message
     (this pytest process has exactly 1 device by design)."""
     from repro.core.quant import QuantConfig
-    from repro.core.search import SearchConfig, run_search
+    from repro.core.search import SearchConfig
     from repro.models import init_params
+    from repro.search import run as search_run
 
     params = init_params(jax.random.PRNGKey(0), tiny_cfg)
     calib = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
@@ -99,7 +100,7 @@ def test_mapped_requires_island_per_device(tiny_cfg):
     scfg = SearchConfig(steps=1, islands=jax.device_count() + 1, mapped=True,
                         n_match_layers=2, log_every=0)
     with pytest.raises(ValueError, match="one island per device"):
-        run_search(params, params, tiny_cfg, QuantConfig(bits=2, group_size=32),
+        search_run(params, params, tiny_cfg, QuantConfig(bits=2, group_size=32),
                    calib, scfg)
 
 
@@ -109,16 +110,17 @@ def test_mapped_single_island_single_device(tiny_cfg):
     contract is pinned by tests/test_dist_smoke.py."""
     import dataclasses
     from repro.core.quant import QuantConfig
-    from repro.core.search import SearchConfig, run_search
+    from repro.core.search import SearchConfig
     from repro.models import init_params
+    from repro.search import run as search_run
 
     params = init_params(jax.random.PRNGKey(0), tiny_cfg)
     calib = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                                tiny_cfg.vocab_size)
     scfg = SearchConfig(steps=3, islands=1, n_match_layers=2, log_every=0)
     qcfg = QuantConfig(bits=2, group_size=32)
-    r_seq = run_search(params, params, tiny_cfg, qcfg, calib, scfg)
-    r_map = run_search(params, params, tiny_cfg, qcfg, calib,
+    r_seq = search_run(params, params, tiny_cfg, qcfg, calib, scfg)
+    r_map = search_run(params, params, tiny_cfg, qcfg, calib,
                        dataclasses.replace(scfg, mapped=True))
     assert r_seq.history == r_map.history
     assert r_seq.final_loss == r_map.final_loss

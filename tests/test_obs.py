@@ -313,8 +313,9 @@ def test_search_metrics_reconcile_with_stats():
     (the acceptance criterion the launch driver also asserts inline)."""
     from repro.configs import get_config
     from repro.core.quant import QuantConfig
-    from repro.core.search import SearchConfig, run_search
+    from repro.core.search import SearchConfig
     from repro.models import init_params
+    from repro.search import run as search_run
 
     reg = obs.get_registry()
     reg.reset()
@@ -326,7 +327,7 @@ def test_search_metrics_reconcile_with_stats():
                                cfg.vocab_size)
     scfg = SearchConfig(steps=4, seed=0, n_match_layers=1, log_every=0,
                         population=2, islands=2, migrate_every=2)
-    r = run_search(params, params, cfg, QuantConfig(bits=2, group_size=32),
+    r = search_run(params, params, cfg, QuantConfig(bits=2, group_size=32),
                    calib, scfg)
     assert reg.counter("search_proposals_total").total() == \
         r.stats["proposals"] == 4 * 2 * 2

@@ -6,10 +6,11 @@ import numpy as np
 import pytest
 
 from repro.core.quant import QuantConfig
-from repro.core.search import (SearchConfig, run_search, DenseFFNAdapter,
-                               MoEAdapter, make_adapter)
+from repro.core.search import (SearchConfig, DenseFFNAdapter, MoEAdapter,
+                               make_adapter)
 from repro.models import forward
 from repro.core.objective import calib_ce
+from repro.search import run as search_run
 
 
 @pytest.fixture(scope="module")
@@ -17,7 +18,7 @@ def searched(trained_tiny, calib):
     params, cfg = trained_tiny
     qcfg = QuantConfig(bits=2, group_size=32)
     scfg = SearchConfig(steps=120, n_match_layers=2, log_every=0, seed=0)
-    res = run_search(params, params, cfg, qcfg, calib, scfg)
+    res = search_run(params, params, cfg, qcfg, calib, scfg)
     return params, cfg, res
 
 

@@ -2,10 +2,11 @@
 reproducibility, annealing, migration, and the fused kernel path.
 
 The acceptance bar (ISSUE 3): at ``population=1, islands=1, temperature=0``
-the engine must reproduce the legacy single-chain ``run_search`` trajectory
-BIT-FOR-BIT on the OPT-paper-family config — ``_legacy_run_search`` below is
-a verbatim transcription of the pre-engine loop and the histories are
-compared exactly, not approximately.
+the engine must reproduce the legacy single-chain trajectory BIT-FOR-BIT on
+the OPT-paper-family config — ``_legacy_run_search`` below is a verbatim
+transcription of the pre-engine loop and the histories are compared
+exactly, not approximately. Every run goes through ``repro.search.run``,
+whose staged propose/build/eval/pick pipeline serves every K.
 """
 import dataclasses
 
@@ -18,10 +19,12 @@ from repro.configs import get_config
 from repro.core import invariance as inv
 from repro.core import objective as obj
 from repro.core.quant import QuantConfig
-from repro.core.search import (SearchConfig, run_search, make_adapter,
-                               DenseFFNAdapter, _tree_slice, _tree_update)
+from repro import obs
+from repro.core.search import (SearchConfig, make_adapter, DenseFFNAdapter,
+                               _tree_slice, _tree_update)
 from repro.models import forward, init_params
 from repro.search import anneal
+from repro.search import run as search_run
 from repro.search.islands import IslandState, make_island_streams, migrate
 from repro.search.population import candidate_keys
 
@@ -122,7 +125,7 @@ def test_engine_reproduces_legacy_bitwise(tiny_opt):
     assert (scfg.population, scfg.islands, scfg.temperature) == (1, 1, 0.0)
     h_legacy, t_legacy, best_legacy, n_acc = _legacy_run_search(
         params, params, cfg, QCFG, calib, scfg)
-    res = run_search(params, params, cfg, QCFG, calib, scfg)
+    res = search_run(params, params, cfg, QCFG, calib, scfg)
     # exact float equality on every (step, loss, ce, mse, accepted) entry
     assert res.history == h_legacy
     assert np.array_equal(np.asarray(res.transforms.pi), np.asarray(t_legacy.pi))
@@ -138,7 +141,7 @@ def test_population_batched_eval_improves(tiny_opt):
     hill climb (loss improves, permutations stay permutations)."""
     params, cfg, calib = tiny_opt
     scfg = SearchConfig(steps=20, n_match_layers=2, log_every=0, population=3)
-    res = run_search(params, params, cfg, QCFG, calib, scfg)
+    res = search_run(params, params, cfg, QCFG, calib, scfg)
     assert res.final_loss < res.initial_loss
     assert res.stats["proposals"] == 20 * 3
     pi = np.asarray(res.transforms.pi)
@@ -158,8 +161,8 @@ def test_island0_trajectory_invariant_to_island_count(tiny_opt):
     s1 = SearchConfig(steps=15, n_match_layers=0, log_every=0, population=2,
                       migrate_every=0)
     s2 = dataclasses.replace(s1, islands=2)
-    r1 = run_search(params, params, cfg, QCFG, calib, s1)
-    r2 = run_search(params, params, cfg, QCFG, calib, s2)
+    r1 = search_run(params, params, cfg, QCFG, calib, s1)
+    r2 = search_run(params, params, cfg, QCFG, calib, s2)
     assert len(r1.island_histories) == 1 and len(r2.island_histories) == 2
     assert r2.island_histories[0] == r1.island_histories[0]
     # the second island explores a genuinely different stream
@@ -170,8 +173,8 @@ def test_engine_rerun_is_deterministic(tiny_opt):
     params, cfg, calib = tiny_opt
     scfg = SearchConfig(steps=10, n_match_layers=0, log_every=0, population=2,
                         islands=2, migrate_every=4)
-    r1 = run_search(params, params, cfg, QCFG, calib, scfg)
-    r2 = run_search(params, params, cfg, QCFG, calib, scfg)
+    r1 = search_run(params, params, cfg, QCFG, calib, scfg)
+    r2 = search_run(params, params, cfg, QCFG, calib, scfg)
     assert r1.island_histories == r2.island_histories
     assert r1.final_loss == r2.final_loss
 
@@ -207,7 +210,7 @@ def test_annealed_search_takes_uphill_moves_keeps_elite(tiny_opt):
     params, cfg, calib = tiny_opt
     scfg = SearchConfig(steps=20, n_match_layers=0, log_every=0,
                         temperature=10.0, anneal="constant")
-    res = run_search(params, params, cfg, QCFG, calib, scfg)
+    res = search_run(params, params, cfg, QCFG, calib, scfg)
     assert res.stats["uphill_accepts"] >= 1
     accepted = [h[1] for h in res.history if h[4]]
     assert any(b > a for a, b in zip(accepted, accepted[1:])), \
@@ -302,7 +305,7 @@ def test_fused_engine_run_improves(tiny_opt):
     params, cfg, calib = tiny_opt
     scfg = SearchConfig(steps=10, n_match_layers=0, log_every=0, population=2,
                         fused_kernel=True)
-    res = run_search(params, params, cfg, QCFG, calib, scfg)
+    res = search_run(params, params, cfg, QCFG, calib, scfg)
     assert res.final_loss < res.initial_loss
     assert res.stats["fused"] is True
 
@@ -319,7 +322,7 @@ def test_fused_downgrade_warns_and_is_recorded():
     scfg = SearchConfig(steps=2, n_match_layers=0, log_every=0,
                         fused_kernel=True)
     with pytest.warns(UserWarning, match="transform_quant_unit"):
-        res = run_search(params, params, cfg, qcfg, calib, scfg)
+        res = search_run(params, params, cfg, qcfg, calib, scfg)
     assert res.stats["fused"] is False
 
 
@@ -363,7 +366,7 @@ def test_uphill_accepts_counts_strict_uphill_as_int(tiny_opt):
     params, cfg, calib = tiny_opt
     scfg = SearchConfig(steps=20, n_match_layers=0, log_every=0,
                         temperature=10.0, anneal="constant")
-    res = run_search(params, params, cfg, QCFG, calib, scfg)
+    res = search_run(params, params, cfg, QCFG, calib, scfg)
     assert type(res.stats["uphill_accepts"]) is int
     cur = res.history[0][1]
     strict_uphill = 0
@@ -373,7 +376,7 @@ def test_uphill_accepts_counts_strict_uphill_as_int(tiny_opt):
             cur = loss
     assert res.stats["uphill_accepts"] == strict_uphill
     # and a cold chain can never move uphill
-    cold = run_search(params, params, cfg, QCFG, calib,
+    cold = search_run(params, params, cfg, QCFG, calib,
                       SearchConfig(steps=10, n_match_layers=0, log_every=0))
     assert cold.stats["uphill_accepts"] == 0
 
@@ -384,8 +387,7 @@ def test_uphill_accepts_counts_strict_uphill_as_int(tiny_opt):
 
 def test_front_door_matches_legacy_bitwise(tiny_opt):
     """``repro.search.run`` at the default config reproduces the legacy
-    trajectory bit-for-bit, and the deprecated ``run_search`` shim returns
-    the identical result under a DeprecationWarning."""
+    trajectory bit-for-bit."""
     import repro.search as search
     params, cfg, calib = tiny_opt
     scfg = SearchConfig(steps=12, n_match_layers=2, log_every=0, seed=0)
@@ -398,10 +400,48 @@ def test_front_door_matches_legacy_bitwise(tiny_opt):
                           np.asarray(t_legacy.pi))
     assert res.stats["objective"] == "ce"
     assert res.stats["install"] == "unit"
-    with pytest.warns(DeprecationWarning, match="run_search is deprecated"):
-        res_shim = run_search(params, params, cfg, QCFG, calib, scfg)
-    assert res_shim.history == res.history
-    assert res_shim.final_loss == res.final_loss
+
+
+def test_kl_with_taps_matches_legacy_at_k1(tiny_opt):
+    """Objective "kl" with activation taps at K=1: the staged pipeline
+    combines ``p + alpha * a`` in ``pick_body``, its own program, where the
+    legacy loop fused it into the single step jit — the combined loss may
+    then differ by an ulp. Every accept decision and the final loss match
+    exactly; each step's losses agree to 1e-6 relative."""
+    params, cfg, calib = tiny_opt
+    scfg = SearchConfig(steps=24, n_match_layers=2, log_every=0, seed=0,
+                        objective="kl")
+    h_legacy, _, best_legacy, n_acc = _legacy_run_search(
+        params, params, cfg, QCFG, calib, scfg)
+    res = search_run(params, params, cfg, QCFG, calib, scfg)
+    assert [h[0] for h in res.history] == [h[0] for h in h_legacy]
+    assert [h[4] for h in res.history] == [h[4] for h in h_legacy]
+    assert res.final_loss == best_legacy
+    assert res.accept_rate == n_acc / scfg.steps
+    for got, want in zip(res.history, h_legacy):
+        np.testing.assert_allclose(got[1:4], want[1:4], rtol=1e-6, atol=0)
+
+
+def test_one_step_pipeline_at_every_k(tiny_opt):
+    """K=1 and K=3 dispatch the same step programs: one staged pipeline,
+    no separate K=1 step. Read from the compile listener's log."""
+    params, cfg, calib = tiny_opt
+    obs.install_compile_listener()
+
+    def step_programs(population):
+        n0 = len(obs.span_log())
+        search_run(params, params, cfg, QCFG, calib,
+                   SearchConfig(steps=2, n_match_layers=2, log_every=0,
+                                population=population))
+        log = obs.span_log()[n0:]
+        spans = {e["id"]: e["name"] for e in log if "id" in e}
+        return {e["fn"] for e in log if e["name"] == "jax.compile"
+                and spans.get(e["parent"]) == "search.dispatch"}
+
+    step_programs(2)   # compiles the host-side eager ops once
+    k1, k3 = step_programs(1), step_programs(3)
+    assert k1 == k3 == {"jit(propose_body)", "jit(build_units_body)",
+                        "jit(eval_body)", "jit(pick_body)"}
 
 
 def test_front_door_objective_kwarg_overrides_config(tiny_opt):
@@ -418,7 +458,7 @@ def test_front_door_objective_kwarg_overrides_config(tiny_opt):
 
 def test_front_door_auto_dispatches_hybrid():
     """A hybrid block pattern routes through the two-phase composite with no
-    explicit runner choice (the legacy run_search_hybrid semantics)."""
+    explicit runner choice."""
     import repro.search as search
     cfg = get_config("zamba2-7b").reduced()
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -432,30 +472,17 @@ def test_front_door_auto_dispatches_hybrid():
     assert res.stats["proposals"] == 5
 
 
-def test_run_population_search_shim_warns(tiny_opt):
-    params, cfg, calib = tiny_opt
-    from repro.core.search import make_adapter
-    from repro.search.engine import run_population_search
-    scfg = SearchConfig(steps=2, n_match_layers=0, log_every=0)
-    with pytest.warns(DeprecationWarning, match="run_population_search"):
-        res = run_population_search(params, params, cfg, QCFG, calib, scfg,
-                                    adapter=make_adapter(cfg))
-    assert res.final_loss <= res.initial_loss
-
-
 def test_hybrid_search_spends_odd_step_budgets_fully():
-    """Regression (ISSUE 4): ``run_search_hybrid`` with ODD steps must run
-    ``steps // 2`` + ``steps - steps // 2`` (not halve twice), and merge
+    """Regression (ISSUE 4): the hybrid two-phase search with ODD steps must
+    run ``steps // 2`` + ``steps - steps // 2`` (not halve twice), and merge
     histories/stats across both phases."""
-    from repro.core.search import run_search_hybrid
     cfg = get_config("zamba2-7b").reduced()
     params = init_params(jax.random.PRNGKey(0), cfg)
     calib = jax.random.randint(jax.random.PRNGKey(1), (1, 32), 0,
                                cfg.vocab_size)
     qcfg = QuantConfig(bits=2, group_size=16)
-    res = run_search_hybrid(params, params, cfg, qcfg, calib,
-                            SearchConfig(steps=7, n_match_layers=0,
-                                         log_every=0))
+    res = search_run(params, params, cfg, qcfg, calib,
+                     SearchConfig(steps=7, n_match_layers=0, log_every=0))
     # two phases, each history = steps + 1 (the step-0 entry): 3+1 + 4+1
     assert len(res.history) == 7 + 2
     assert res.stats["proposals"] == 7, "odd budgets must be spent in full"

@@ -3,9 +3,9 @@ pluggable objectives, tried-point tabu memory, sharded per-island
 calibration.
 
 Property bars:
-  * dynamic-slice install == full-stack install — exact on Dense AND MoE
-    unit stacks, and through the engine (bit-for-bit at K=1 where both
-    modes route the legacy single-jit step; <= 1e-5 at K>1);
+  * dynamic-slice install == indexed-update install — exact on Dense AND
+    MoE unit stacks, and the ``lax.map`` candidate eval scores each
+    candidate as its own full-stack install would;
   * objective registry round-trips strings and instances;
   * sharded-vs-replicated calibration is bitwise identical at 1 island;
   * the tabu memory never blocks an improving move and never perturbs the
@@ -26,9 +26,8 @@ from repro.core.search import (DenseFFNAdapter, MoEAdapter, SearchConfig,
                                _tree_update)
 from repro.models import init_params
 from repro.search import run as search_run
-from repro.search.install import (eval_candidates_stack, eval_candidates_unit,
-                                  stack_unit_batch, tree_bytes,
-                                  tree_install_unit)
+from repro.search.install import (eval_candidates_unit, stack_unit_batch,
+                                  tree_bytes, tree_install_unit)
 from repro.search.tabu import TabuMemory, transform_bytes
 
 QCFG = QuantConfig(bits=2, group_size=32)
@@ -83,8 +82,9 @@ def test_install_unit_equals_tree_update_moe():
 
 
 def test_eval_candidates_unit_matches_stack(tiny_opt):
-    """The two install lanes score identical candidates identically (the
-    eval here is a cheap deterministic reduction, so equality is exact)."""
+    """The ``lax.map`` lane scores each candidate as installing it into the
+    full stack and evaluating that stack alone does (the eval here is a
+    cheap deterministic reduction, so equality is exact)."""
     params, cfg, _ = tiny_opt
     adapter = DenseFFNAdapter(cfg)
     base = adapter.base_stack(params)
@@ -100,44 +100,13 @@ def test_eval_candidates_unit_matches_stack(tiny_opt):
 
     p_u, a_u = jax.jit(
         lambda b: eval_candidates_unit(b, fq, u, eval_fn))(batch)
-    p_s, a_s = jax.jit(
-        lambda b: eval_candidates_stack(b, fq, u, eval_fn))(batch)
+    one = jax.jit(lambda unit: eval_fn(_tree_update(fq, u, unit)))
+    p_s, a_s = map(np.asarray, zip(*[one(unit) for unit in units]))
     assert p_u.shape == (K,)
-    np.testing.assert_allclose(np.asarray(p_u), np.asarray(p_s), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(a_u), np.asarray(a_s), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(p_u), p_s, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(a_u), a_s, rtol=1e-6)
     # the candidate buffer really is K x unit, not K x stack
     assert tree_bytes(batch) * adapter.n_units == tree_bytes(fq) * K
-
-
-def test_engine_k1_install_modes_bitwise(tiny_opt):
-    """K=1 routes BOTH install modes through the legacy single-jit step:
-    trajectories are bit-for-bit identical by construction."""
-    params, cfg, calib = tiny_opt
-    s = SearchConfig(steps=8, n_match_layers=2, log_every=0)
-    r_u = search_run(params, params, cfg, QCFG, calib,
-                     dataclasses.replace(s, install="unit"))
-    r_s = search_run(params, params, cfg, QCFG, calib,
-                     dataclasses.replace(s, install="stack"))
-    assert r_u.history == r_s.history
-    assert r_u.final_loss == r_s.final_loss
-
-
-def test_engine_k3_install_modes_close(tiny_opt):
-    """K>1: unit-install (lax.map over per-unit buffers) and stack-install
-    (vmap over K stacks) run different XLA programs over the same math —
-    same accept decisions, losses within 1e-5."""
-    params, cfg, calib = tiny_opt
-    s = SearchConfig(steps=8, n_match_layers=2, log_every=0, population=3)
-    r_u = search_run(params, params, cfg, QCFG, calib,
-                     dataclasses.replace(s, install="unit"))
-    r_s = search_run(params, params, cfg, QCFG, calib,
-                     dataclasses.replace(s, install="stack"))
-    assert r_u.stats["install"] == "unit"
-    assert r_s.stats["install"] == "stack"
-    assert len(r_u.history) == len(r_s.history)
-    for hu, hs in zip(r_u.history, r_s.history):
-        assert hu[0] == hs[0] and hu[4] == hs[4]   # step, accepted
-        np.testing.assert_allclose(hu[1:4], hs[1:4], rtol=0, atol=1e-5)
 
 
 @pytest.mark.parametrize("population,tabu", [(1, 0), (3, 0), (2, 8)])
@@ -181,31 +150,28 @@ def test_engine_programs_take_weights_as_arguments(tiny_opt, monkeypatch,
     assert largest < 64 * 64 * 4, f"{largest}-byte constant in a program"
 
 
-def test_engine_rejects_unknown_install(tiny_opt):
+@pytest.mark.parametrize("install", ["bogus", "stack"])
+def test_engine_rejects_unknown_install(tiny_opt, install):
     params, cfg, calib = tiny_opt
     with pytest.raises(ValueError, match="install"):
         search_run(params, params, cfg, QCFG, calib,
-                   SearchConfig(steps=1, log_every=0, install="bogus"))
+                   SearchConfig(steps=1, log_every=0, install=install))
 
 
 def test_measure_memory_unit_batch_smaller_than_stack(tiny_opt):
     """``measure_memory=True`` reports the memory model: the candidate
-    buffer is K x unit under install='unit' vs K x stack under 'stack'."""
+    buffer is K x unit, smaller than K full stacks by the unit count."""
     params, cfg, calib = tiny_opt
-    s = SearchConfig(steps=4, n_match_layers=0, log_every=0, population=4,
-                     measure_memory=True)
-    r_u = search_run(params, params, cfg, QCFG, calib,
-                     dataclasses.replace(s, install="unit"))
-    r_s = search_run(params, params, cfg, QCFG, calib,
-                     dataclasses.replace(s, install="stack"))
-    for r in (r_u, r_s):
-        assert {"peak_live_bytes", "stack_bytes",
-                "candidate_batch_bytes"} <= set(r.stats)
-    assert r_u.stats["stack_bytes"] == r_s.stats["stack_bytes"]
-    # K x unit  vs  K x stack: smaller by exactly the unit count
+    K = 4
+    r = search_run(params, params, cfg, QCFG, calib,
+                   SearchConfig(steps=4, n_match_layers=0, log_every=0,
+                                population=K, measure_memory=True))
+    assert {"peak_live_bytes", "stack_bytes",
+            "candidate_batch_bytes"} <= set(r.stats)
+    assert r.stats["install"] == "unit"
     n_units = DenseFFNAdapter(cfg).n_units
-    assert (r_u.stats["candidate_batch_bytes"] * n_units
-            == r_s.stats["candidate_batch_bytes"])
+    assert (r.stats["candidate_batch_bytes"] * n_units
+            == r.stats["stack_bytes"] * K)
 
 
 # ---------------------------------------------------------------------------
